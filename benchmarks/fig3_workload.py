@@ -126,6 +126,9 @@ def check_smoke(rows) -> None:
 
 
 def main(argv=None):
+    from repro.runtime.cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--scale", type=float, default=0.6)
     ap.add_argument("--out", default="BENCH_fig3.json")

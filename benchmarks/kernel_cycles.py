@@ -1,14 +1,12 @@
 """Per-cycle cost of every push-relabel step mode -> BENCH_kernels.json.
 
-Measures, for each mode in ``vc | tc | vc_kernel | vc_kernel_bsearch |
-vc_fused`` on the paper graph family:
+Measures, for each mode in ``vc | tc | vc_kernel | vc_kernel_bsearch``
+on the paper graph family:
 
 * **us_per_cycle** — wall time of one warmed ``run_cycles`` dispatch
   divided by the cycles it executed (the solver hot-loop unit cost);
 * **ops_per_cycle** — device-op count per cycle: primitive equations in
-  the traced jaxpr of one bulk-synchronous step (for ``vc_fused``: of one
-  K-cycle launch, divided by K) — the "~10-op XLA chain vs one
-  ``pallas_call``" claim made measurable;
+  the traced jaxpr of one bulk-synchronous step;
 * **pallas_calls** — kernel launches appearing in that trace;
 * **compile_ms** — wall time of the cold first ``run_cycles`` dispatch
   (trace + XLA compile + execute), the compile latency the scan-chunked
@@ -22,11 +20,13 @@ vc_fused`` on the paper graph family:
   one exists, else probed once) — NOT re-derived per benchmark graph:
   the counts are a property of the step trace, not of the graph.
 
-``--smoke`` runs one tiny graph and asserts the fusion contract: the
-fused launch contains exactly ONE ``pallas_call`` and amortises to at most
-2 device ops per cycle, against a ``vc`` chain of ~10+ — plus the engine
+``--smoke`` runs one tiny graph and asserts the launch contract (one
+``pallas_call`` per cycle for ``vc_kernel``, two for
+``vc_kernel_bsearch``, against a ``vc`` chain of ~10+ ops) and the engine
 contract that the scan-chunked trace is strictly smaller than its
-unrolled equivalent.  Emits ``BENCH_kernels.json`` next to the repo root
+unrolled equivalent.  Times here are host wall clock on whatever backend
+runs the script (CPU: XLA's CPU backend and the Pallas interpreter), not
+device numbers.  Emits ``BENCH_kernels.json`` next to the repo root
 (or ``--out``) so successive PRs can track the per-cycle trajectory.
 """
 from __future__ import annotations
@@ -55,7 +55,6 @@ def bench_graph(r, s, t, modes=MODES, cycles=24, repeats=3,
                 graph_name: str = "anon", baselines=None):
     """Per-mode stats for one ResidualCSR instance."""
     from repro.core import globalrelabel, pushrelabel as pr
-    from repro.kernels import discharge
 
     g, meta, res0 = pr.to_device(r)
     state0 = pr.preflow(g, meta, res0, s)
@@ -74,29 +73,11 @@ def bench_graph(r, s, t, modes=MODES, cycles=24, repeats=3,
         _, ncyc = run()  # warmup: trace + XLA compile + first execute
         cold_s = time.perf_counter() - t0
         best = min(_timed(run) for _ in range(repeats))
-        # per-cycle device ops: one step's trace (one K-launch / K for fused)
-        if mode == "vc_fused":
-            kk = discharge.K_DEFAULT
-            # the steady-state launch run_cycles issues: loop-invariant
-            # terminals/indptr/padded arcs hoisted, state rides 1-lifted
-            import jax.numpy as jnp
-
-            s_b = jnp.full((1,), s, jnp.int32)
-            t_b = jnp.full((1,), t, jnp.int32)
-            indptr_b = g.indptr[None]
-            heads_p = discharge.pad_arcs(g.heads[None])
-            rev_p = discharge.pad_arcs(g.rev[None])
-            ops, pallas = _trace_counts(
-                lambda res, h, e: discharge.fused_discharge_batched(
-                    s_b, t_b, indptr_b, heads_p, rev_p, res, h, e,
-                    n=meta.n, k=kk),
-                state0.res[None], state0.h[None], state0.e[None])
-            ops_per_cycle = ops / kk
-        else:
-            step = pr._make_step(mode)
-            ops, pallas = _trace_counts(
-                lambda st: step(g, meta, st, s, t), state0)
-            ops_per_cycle = float(ops)
+        # per-cycle device ops: one step's trace
+        step = pr._make_step(mode)
+        ops, pallas = _trace_counts(
+            lambda st: step(g, meta, st, s, t), state0)
+        ops_per_cycle = float(ops)
         out[mode] = {
             "us_per_cycle": best * 1e6 / max(ncyc, 1),
             "cycles_timed": ncyc,
@@ -160,9 +141,12 @@ def run(scale: float = 1.0, smoke: bool = False):
 
 
 def main() -> None:
+    from repro.runtime.cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
-                    help="tiny graph + fusion-contract assertions")
+                    help="tiny graph + launch-contract assertions")
     ap.add_argument("--scale", type=float, default=1.0)
     ap.add_argument("--out", default="BENCH_kernels.json")
     args = ap.parse_args()
@@ -176,18 +160,13 @@ def main() -> None:
     print(f"wrote {args.out}")
 
     if args.smoke:
-        from repro.kernels.discharge import K_DEFAULT
-
         per = rows[0]["modes"]
-        fused, vc = per["vc_fused"], per["vc"]
-        if fused["pallas_calls"] != 1:
-            raise SystemExit(
-                f"fused launch must be ONE pallas_call, saw "
-                f"{fused['pallas_calls']}")
-        if fused["ops_per_cycle"] > 2:
-            raise SystemExit(
-                f"fused dispatch exceeds 2 device ops/cycle: "
-                f"{fused['ops_per_cycle']}")
+        vc = per["vc"]
+        for mode, want in (("vc_kernel", 1), ("vc_kernel_bsearch", 2)):
+            if per[mode]["pallas_calls"] != want:
+                raise SystemExit(
+                    f"{mode} must launch {want} pallas_call(s) per cycle, "
+                    f"saw {per[mode]['pallas_calls']}")
         if vc["ops_per_cycle"] < 8:
             raise SystemExit(
                 f"expected the ~10-op XLA chain in 'vc', saw "
@@ -200,9 +179,7 @@ def main() -> None:
                     f"scan-chunked trace of {mode!r} must be strictly "
                     f"smaller than its unrolled equivalent, saw "
                     f"{st['scanned_eqns']} vs {st['unrolled_eqns']}")
-        print(f"smoke OK: vc_fused {fused['ops_per_cycle']} ops/cyc "
-              f"(1 pallas_call per {K_DEFAULT} cycles) "
-              f"vs vc {vc['ops_per_cycle']} ops/cyc; scan-chunked "
+        print(f"smoke OK: vc {vc['ops_per_cycle']} ops/cyc; scan-chunked "
               f"vc trace {per['vc']['scanned_eqns']} eqns vs "
               f"{per['vc']['unrolled_eqns']} unrolled")
 

@@ -26,7 +26,7 @@ c = jax.jit(lambda x, w: x @ w).lower(
     jax.ShapeDtypeStruct((M, M), jnp.float32, sharding=sh(P("d", None))),
     jax.ShapeDtypeStruct((M, M), jnp.float32, sharding=sh(P(None, None)))
 ).compile()
-got = compat.cost_analysis(c)["flops"]
+got = c.cost_analysis()["flops"]
 assert abs(got - 2 * M**3 / 4) / (2 * M**3 / 4) < 0.01, got
 print(f"probe1 OK: sharded matmul flops {got:.3g} == global/4")
 """
@@ -41,7 +41,7 @@ def g(x):
     y, _ = jax.lax.scan(body, jnp.eye(M, dtype=jnp.float32), None, length=7)
     return y
 c = jax.jit(g).lower(jax.ShapeDtypeStruct((M, M), jnp.float32)).compile()
-got = compat.cost_analysis(c)["flops"]
+got = c.cost_analysis()["flops"]
 assert got < 1.5 * 2 * M**3, got  # 7x body would be ~1.5e10
 print(f"probe2 OK: scan-of-7 flops {got:.3g} ~= one body (trip count ignored)")
 """

@@ -10,6 +10,9 @@ import time
 
 
 def main() -> None:
+    from repro.runtime.cache import enable_compile_cache
+
+    enable_compile_cache()
     t0 = time.time()
     csv = []
 

@@ -350,6 +350,9 @@ def check_smoke(out: dict) -> None:
 
 
 def main(argv=None):
+    from repro.runtime.cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--requests", type=int, default=64)
     ap.add_argument("--max-batch", type=int, default=8)

@@ -134,6 +134,9 @@ def check_smoke(out: dict) -> None:
 
 
 def main(argv=None):
+    from repro.runtime.cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=120)
     ap.add_argument("--batches", type=int, default=12)

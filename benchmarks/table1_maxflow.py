@@ -45,4 +45,7 @@ def run(scale: float = 1.0, verbose: bool = True):
 
 
 if __name__ == "__main__":
+    from repro.runtime.cache import enable_compile_cache
+
+    enable_compile_cache()
     run()

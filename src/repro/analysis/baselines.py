@@ -30,9 +30,7 @@ _PROBE_GRAPH = (60, 240, 7)  # (n, m, seed)
 def scan_chunk_baselines(modes: tuple[str, ...] | None = None,
                          chunk: int | None = None) -> dict:
     """mode -> ``{"scan_chunk", "scanned_eqns", "unrolled_eqns"}``,
-    probed fresh via ``engine.scan_chunk_eqns``.  ``vc_fused`` is
-    excluded: its cycle loop is the fused K-launch, not a scanned chunk
-    of single steps, so the probe does not apply."""
+    probed fresh via ``engine.scan_chunk_eqns``."""
     import jax.numpy as jnp
 
     from repro.core import engine, globalrelabel
@@ -41,7 +39,7 @@ def scan_chunk_baselines(modes: tuple[str, ...] | None = None,
     from repro.graphs import generators as G
 
     if modes is None:
-        modes = tuple(m for m in pr.ALL_MODES if m != "vc_fused")
+        modes = pr.ALL_MODES
     chunk = engine.DEFAULT_CHUNK if chunk is None else int(chunk)
 
     n, m, seed = _PROBE_GRAPH
@@ -53,8 +51,6 @@ def scan_chunk_baselines(modes: tuple[str, ...] | None = None,
 
     out = {}
     for mode in modes:
-        if mode == "vc_fused":
-            continue
         step = pr._make_step(mode)
         scanned, unrolled = engine.scan_chunk_eqns(
             lambda c, _step=step: (_step(g, meta, c[0], s, t), c[1] + 1),

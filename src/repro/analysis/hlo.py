@@ -26,7 +26,6 @@ import re
 import warnings
 from collections import defaultdict
 
-from repro import compat
 
 __all__ = ["DTYPE_BYTES", "ReplicaGroupParseError", "collective_bytes",
            "cost_summary"]
@@ -128,7 +127,7 @@ def cost_summary(compiled, strict: bool = False) -> dict:
     executable.  Collective parsing is lenient here by default — a cost
     *estimate* should degrade, not crash, on an exotic HLO line; the
     analyzer CLI runs :func:`collective_bytes` strictly."""
-    ca = compat.cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     ma = compiled.memory_analysis()
     mem = {}
     if ma is not None:

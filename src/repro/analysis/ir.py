@@ -32,7 +32,7 @@ from typing import Any, Callable, Iterator, Mapping
 
 import jax
 
-from repro.compat import ClosedJaxpr, Jaxpr
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 __all__ = [
     "STRUCTURAL_PRIMS", "HOST_CALLBACK_PRIMS", "TRANSFER_PRIMS",

@@ -52,13 +52,12 @@ _MAX_CYCLES = 32
 #: "one workload-balanced kernel launch per cycle" claim, per mode
 #: ('vc_kernel_bsearch' adds the reverse-arc binary-search launch)
 _LAUNCHES_PER_STEP = {"vc": 0, "tc": 0, "vc_kernel": 1,
-                      "vc_kernel_bsearch": 2, "vc_fused": 1}
+                      "vc_kernel_bsearch": 2}
 
 #: inner scan count of the cycle loop's steady state: ONE scanned chunk
 #: body — except 'tc', whose per-arc masked segment walk is a
 #: ``fori_loop`` that itself lowers to a second, step-internal scan
-_CYCLE_SCANS = {"vc": 1, "tc": 2, "vc_kernel": 1, "vc_kernel_bsearch": 1,
-                "vc_fused": 1}
+_CYCLE_SCANS = {"vc": 1, "tc": 2, "vc_kernel": 1, "vc_kernel_bsearch": 1}
 
 #: per-surface equation-count ceilings (trace size ~= compile latency).
 #: Seeded from the measured steady-state counts in BENCH_kernels.json
@@ -69,9 +68,9 @@ _CYCLE_SCANS = {"vc": 1, "tc": 2, "vc_kernel": 1, "vc_kernel_bsearch": 1,
 #: :func:`trace_budget_for`).
 _TRACE_CEILINGS = {
     "run_cycles": {"vc": 700, "tc": 450, "vc_kernel": 500,
-                   "vc_kernel_bsearch": 520, "vc_fused": 250},
+                   "vc_kernel_bsearch": 520},
     "batched_run_cycles": {"vc": 800, "tc": 550, "vc_kernel": 600,
-                           "vc_kernel_bsearch": 650, "vc_fused": 350},
+                           "vc_kernel_bsearch": 650},
     "global_relabel": 300,
     "phase2": 900,
     "streaming": 1800,
@@ -271,6 +270,8 @@ def _build_streaming_drain(kernel: bool):
 
 
 def _build_distributed_superstep():
+    import jax
+
     from repro import compat
     from repro.core import distributed as D
     from repro.core.csr import build_residual
@@ -289,7 +290,7 @@ def _build_distributed_superstep():
     e = jnp.zeros(meta.n, jnp.int32)
 
     def fn(res, h, e):
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             return superstep(g, res, h, e)
 
     return fn, (res, h, e)

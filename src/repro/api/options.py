@@ -10,12 +10,6 @@ from repro.core.pushrelabel import ALL_MODES as MODES
 LAYOUTS = ("bcsr", "rcsr")
 BACKENDS = ("single", "batched", "distributed")
 
-#: modes the batched core supports — all of them since the Pallas kernels
-#: gained a leading batch grid axis.  Kept as a (now equal) alias of MODES
-#: for callers written against the era when the kernels were
-#: single-instance only.
-BATCHED_MODES = MODES
-
 
 @dataclasses.dataclass(frozen=True)
 class SolverOptions:
@@ -23,10 +17,8 @@ class SolverOptions:
 
     ``mode``
         Push-relabel step strategy: ``vc`` (the paper's workload-balanced
-        vertex-centric), ``tc`` (thread-centric baseline), the faithful
-        Pallas tile variants ``vc_kernel`` / ``vc_kernel_bsearch``, or
-        ``vc_fused`` (the fused-discharge Pallas kernel: K whole cycles —
-        min search + push/relabel decision + state update — per launch).
+        vertex-centric), ``tc`` (thread-centric baseline), or the faithful
+        Pallas tile variants ``vc_kernel`` / ``vc_kernel_bsearch``.
     ``layout``
         Residual-graph layout, ``bcsr`` or ``rcsr`` (paper §3.2).
     ``backend``
@@ -43,8 +35,7 @@ class SolverOptions:
         effectively-unbounded default.  The budget is exact: the core
         threads the remaining allowance into every dispatch as a traced
         scalar, so a budget that is not a multiple of the dispatch
-        cadence is still honored to the cycle (``vc_fused`` may overshoot
-        by < K, its launch granularity).
+        cadence is still honored to the cycle.
     ``scan_chunk``
         Steps per scan-compiled chunk inside the sweep engine's device
         loops (``repro.core.engine.run_bulk_loop``).  ``None`` picks

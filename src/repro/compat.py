@@ -1,80 +1,15 @@
-"""Compatibility helpers for jax API drift.
+"""Mesh construction shared by the sharded paths.
 
-The sharding helpers were written against newer jax
-(``jax.sharding.get_abstract_mesh`` / ``AxisType``, added after 0.4.37);
-these wrappers degrade gracefully on older versions, where "no ambient
-mesh" is the only possible answer and meshes carry no axis types.
+``jax.make_mesh`` defaults to explicit-sharding axis types; every mesh in
+this repo is built for ``shard_map`` regions and sharding constraints
+under the automatic (GSPMD) mode, so it asks for ``AxisType.Auto`` axes.
 """
 from __future__ import annotations
 
 import jax
 
-# jax >= 0.6 removed these from jax.core (the jaxpr census in
-# repro.analysis.ir uses them); jax.extend.core exists on the whole
-# supported range (>= 0.4.35), so no fallback is needed.  The historical
-# ``count_jaxpr_eqns`` walker moved to ``repro.analysis.ir.count_eqns``.
-from jax.extend.core import ClosedJaxpr, Jaxpr  # noqa: F401
-
-
-def get_abstract_mesh():
-    """The ambient abstract mesh, or None when unset/unsupported."""
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    if fn is None:
-        return None
-    return fn()
-
-
-def shard_map(f, *, mesh=None, in_specs, out_specs, check_vma=None, **kw):
-    """``jax.shard_map`` (new location) or ``jax.experimental.shard_map``
-    (older jax, where ``mesh`` is required and ``check_vma`` is spelled
-    ``check_rep``)."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        if check_vma is not None:
-            kw["check_vma"] = check_vma
-        return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  **kw)
-    from jax.experimental.shard_map import shard_map as old_fn
-    if mesh is None:
-        mesh = _ambient_mesh()
-    if check_vma is not None:
-        kw["check_rep"] = check_vma
-    return old_fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  **kw)
-
-
-def _ambient_mesh():
-    """Best-effort stand-in for the implicit mesh newer jax infers."""
-    from jax._src import mesh as mesh_lib
-
-    m = mesh_lib.thread_resources.env.physical_mesh
-    if m.empty:
-        raise ValueError("shard_map with mesh=None needs an ambient mesh")
-    return m
-
-
-def cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` as a dict (older jax returns a
-    one-entry list of per-program dicts)."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
-
-
-def set_mesh(mesh):
-    """``jax.set_mesh`` context on newer jax; older jax enters the Mesh
-    itself (which binds ``thread_resources`` for shard_map/pjit)."""
-    fn = getattr(jax, "set_mesh", None)
-    if fn is not None:
-        return fn(mesh)
-    return mesh
-
 
 def make_mesh(shape, axes):
-    """``jax.make_mesh`` with Auto axis types where supported."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with ``Auto`` axis types on every axis."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))

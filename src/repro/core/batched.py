@@ -8,9 +8,8 @@ flat-arc arrays — one leading batch axis over the same ``DeviceGraph`` /
 ``PRState`` layout — and ``jax.vmap`` the unmodified per-instance step,
 preflow and global-relabel functions over it.  The Pallas modes do NOT
 vmap their kernels: the kernels natively carry a leading batch *grid*
-dimension, so each cycle's min search (and each K-cycle fused discharge)
-is ONE launch spanning the whole microbatch (``_kernel_batch_step`` /
-``repro.kernels.discharge``).  One compiled executable then
+dimension, so each cycle's min search is ONE launch spanning the whole
+microbatch (``_kernel_batch_step``).  One compiled executable then
 advances every instance of a shape bucket at once:
 
 * ``pack_instances`` pads B ``ResidualCSR``s to a common ``(n_pad, A_pad)``
@@ -302,7 +301,7 @@ def _kernel_batch_step(bg: BatchedDeviceGraph, meta, state: BatchedPRState,
                                                  minh)
         push_arc = jnp.where(do_push, arc_c, jnp.int32(A))
         rev_rows = bcsr_rev_search(push_arc, bg.indptr, bg.heads, bg.tails,
-                                   deg_max=meta.deg_max, interpret=interpret)
+                                   interpret=interpret)
 
         def one_apply(indptr, heads, tails, rev, res, h, e, q, qv, mh, aa,
                       rr):
@@ -352,15 +351,11 @@ def batched_run_cycles(bg: BatchedDeviceGraph, meta, state: BatchedPRState,
 
     Every solver mode (``pushrelabel.ALL_MODES``) is batchable: 'vc'/'tc'
     vmap the XLA step, 'vc_kernel'/'vc_kernel_bsearch' run the batched
-    Pallas tile kernels (one launch per cycle spanning the whole batch),
-    and 'vc_fused' runs the fused discharge kernel — one launch per K
-    cycles, its per-instance live-cycle counts keeping ``cycles[b]``
-    exact.
+    Pallas tile kernels (one launch per cycle spanning the whole batch).
 
     ``telemetry=True`` (static) folds per-instance ``(B,)`` int32
     push/relabel/active/frontier totals into the carry
-    (``repro.obs.solvercounters``; the fused mode reads them off the
-    kernel's counter outputs) and returns them as a third element —
+    (``repro.obs.solvercounters``) and returns them as a third element —
     a ``CycleTelemetry`` with ``None`` histories.  ``telemetry=False``
     traces exactly the historical two-result loop.
     """
@@ -385,18 +380,7 @@ def batched_run_cycles(bg: BatchedDeviceGraph, meta, state: BatchedPRState,
     cap = jnp.int32(max_cycles)
     if budget is not None:
         cap = jnp.minimum(cap, jnp.asarray(budget, jnp.int32))
-    steps_bound = max_cycles
 
-    # step(state, nact) -> (new_state, cycle-budget spent, per-instance
-    # live-cycle counts, pushed flag or None, counter increments or
-    # None); one bulk-synchronous cycle for every mode except 'vc_fused',
-    # which spends K cycles per fused launch.  ``pushed=None`` means
-    # "infer from e-equality", which is only sound for single-cycle
-    # steps — across a K-cycle fused launch a push/relabel ping-pong can
-    # restore ``e`` bitwise, so the fused kernel reports its own any-push
-    # flag.  Likewise ``inc=None`` means "derive counters from the
-    # state diff" (single-cycle steps); the fused step sums the kernel's
-    # own per-cycle counter outputs.
     if mode in ("vc", "tc"):
         step_fn = pr._make_step(mode)
 
@@ -407,39 +391,11 @@ def batched_run_cycles(bg: BatchedDeviceGraph, meta, state: BatchedPRState,
 
         vstep = jax.vmap(one_step)
 
-        def step(state, nact):
-            new = BatchedPRState(*vstep(*_rows(bg), *state, bg.s, bg.t))
-            return new, 1, (nact > 0).astype(jnp.int32), None, None
-    elif mode == "vc_fused":
-        from repro.kernels import discharge
-
-        kk = max(1, min(discharge.K_DEFAULT, max_cycles))
-        steps_bound = -(-max_cycles // kk)  # K cycles per engine step
-        # loop-invariant graph rows padded once, outside the engine loop
-        heads_p = discharge.pad_arcs(bg.heads)
-        rev_p = discharge.pad_arcs(bg.rev)
-
-        def step(state, nact):
-            if telemetry:
-                res, h, e, live, pushed, cnt = \
-                    discharge.fused_discharge_batched(
-                        bg.s, bg.t, bg.indptr, heads_p, rev_p, *state,
-                        n=meta.n, k=kk, interpret=interpret, counters=True)
-                acts, pushs, frs, _ = cnt
-                a_tot = jnp.sum(acts, axis=1)
-                p_tot = jnp.sum(pushs, axis=1)
-                inc = (p_tot, a_tot - p_tot, a_tot, jnp.sum(frs, axis=1))
-            else:
-                res, h, e, live, pushed = discharge.fused_discharge_batched(
-                    bg.s, bg.t, bg.indptr, heads_p, rev_p, *state,
-                    n=meta.n, k=kk, interpret=interpret)
-                inc = None
-            return (BatchedPRState(res=res, h=h, e=e), kk, live,
-                    jnp.any(pushed > 0), inc)
+        def step(state):
+            return BatchedPRState(*vstep(*_rows(bg), *state, bg.s, bg.t))
     else:
-        def step(state, nact):
-            new = _kernel_batch_step(bg, meta, state, mode, interpret)
-            return new, 1, (nact > 0).astype(jnp.int32), None, None
+        def step(state):
+            return _kernel_batch_step(bg, meta, state, mode, interpret)
 
     def cond(carry):
         nact, cycle, pushed = carry[1], carry[2], carry[4]
@@ -447,24 +403,22 @@ def batched_run_cycles(bg: BatchedDeviceGraph, meta, state: BatchedPRState,
 
     def body(carry):
         state, nact, cycle, cycles_per, _ = carry[:5]
-        new_state, spent, live, pushed, inc = step(state, nact)
-        if pushed is None:  # any excess moved this (single) cycle?
-            pushed = jnp.any(new_state.e != state.e)
+        new_state = step(state)
+        pushed = jnp.any(new_state.e != state.e)  # any excess moved?
         new_nact = vnact(new_state.h, new_state.e, bg.s, bg.t)
-        out = (new_state, new_nact, cycle + spent, cycles_per + live,
-               pushed)
+        out = (new_state, new_nact, cycle + 1,
+               cycles_per + (nact > 0).astype(jnp.int32), pushed)
         if telemetry:
             tel = carry[5]
-            if inc is None:
-                # single-cycle modes: every valid active vertex pushed or
-                # relabelled exactly once; relabels are the h changes
-                relab = sc.count_relabels(state.h, new_state.h)
-                _, fr, _ = sc.cycle_stats(pr.DeviceGraph(*_rows(bg)),
-                                          meta, state, bg.s, bg.t)
-                inc = (nact - relab, relab, nact, fr)
+            # every valid active vertex pushed or relabelled exactly
+            # once; relabels are the h changes
+            relab = sc.count_relabels(state.h, new_state.h)
+            _, fr, _ = sc.cycle_stats(pr.DeviceGraph(*_rows(bg)), meta,
+                                      state, bg.s, bg.t)
             tel = sc.CycleTelemetry(
-                pushes=tel.pushes + inc[0], relabels=tel.relabels + inc[1],
-                active=tel.active + inc[2], frontier=tel.frontier + inc[3])
+                pushes=tel.pushes + nact - relab,
+                relabels=tel.relabels + relab,
+                active=tel.active + nact, frontier=tel.frontier + fr)
             out = out + (tel,)
         return out
 
@@ -475,7 +429,7 @@ def batched_run_cycles(bg: BatchedDeviceGraph, meta, state: BatchedPRState,
         init = init + (sc.telemetry_init(batch=bg.batch),)
     out = engine.run_bulk_loop(body, init, cond_fn=cond,
                                chunk=engine.normalize_chunk(chunk,
-                                                            steps_bound))
+                                                            max_cycles))
     if telemetry:
         return out[0], out[3], out[5]
     return out[0], out[3]
@@ -647,8 +601,8 @@ def batched_solve_impl(instances: list[tuple[ResidualCSR, int, int]],
     behind ``repro.api.Solver.solve_many``.
 
     Every mode is batchable — the Pallas modes run their kernels with a
-    leading batch grid axis (one launch per cycle, or per K cycles for
-    'vc_fused', spanning the whole microbatch).  ``vc_kernel_bsearch``
+    leading batch grid axis (one launch per cycle spanning the whole
+    microbatch).  ``vc_kernel_bsearch``
     requires head-sorted (bcsr) instances.
 
     ``phase2=True`` additionally converts every final preflow to a genuine

@@ -28,8 +28,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
-
 from repro.core import engine
 from repro.core.csr import ResidualCSR
 
@@ -222,7 +220,7 @@ def make_dist_step(meta: DistMeta, axes, mesh=None):
         return res, h, e
 
     res_spec = P(axes) if meta.mode in ("sharded", "sparse") else P()
-    return compat.shard_map(
+    return jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(P(axes), P(axes), P(axes), res_spec, P(), P()),
         out_specs=(res_spec, P(), P()),
@@ -262,7 +260,7 @@ def make_dist_global_relabel(meta: DistMeta, axes, mesh=None):
         return hn, nact
 
     res_spec = P(axes) if meta.mode in ("sharded", "sparse") else P()
-    return compat.shard_map(
+    return jax.shard_map(
         local_gr, mesh=mesh,
         in_specs=(P(axes), P(axes), P(axes), P(axes), res_spec, P(), P()),
         out_specs=(P(), P()),
@@ -292,7 +290,7 @@ def make_gr_sweep(meta: DistMeta, axes, mesh=None):
         return jnp.minimum(dist, cand).at[meta.t].set(0)
 
     res_spec = P(axes) if meta.mode in ("sharded", "sparse") else P()
-    return compat.shard_map(
+    return jax.shard_map(
         local_sweep, mesh=mesh,
         in_specs=(P(axes), P(axes), P(axes), P(axes), res_spec, P()),
         out_specs=P(),
@@ -333,7 +331,7 @@ def solve_distributed(r: ResidualCSR, s: int, t: int, mesh, axes,
     n = meta.n
     superstep = make_superstep(meta, axes, cycles, mesh)
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         # preflow (host-side, simple)
         res = np.asarray(res0).copy()
         heads = np.asarray(g.heads).reshape(-1)

@@ -222,10 +222,9 @@ def tc_step(g: DeviceGraph, meta: GraphMeta, state: PRState, s: int,
     return _decide_apply(g, meta, state, u, act, minh, argarc)
 
 
-#: modes whose hot loops execute the Pallas kernels ('vc_fused' runs the
-#: whole discharge in one kernel; the others route the min search / reverse
-#: lookup through the tile kernels)
-KERNEL_MODES = ("vc_kernel", "vc_kernel_bsearch", "vc_fused")
+#: modes whose hot loops execute the Pallas kernels (the min search, and
+#: for 'vc_kernel_bsearch' the reverse lookup, run in the tile kernels)
+KERNEL_MODES = ("vc_kernel", "vc_kernel_bsearch")
 
 #: every step strategy — THE mode tuple; the facade (``repro.api.options``),
 #: the batched core and the benchmarks all import it rather than copying it
@@ -235,9 +234,7 @@ ALL_MODES = ("vc", "tc") + KERNEL_MODES
 def _make_step(mode: str, interpret: bool | None = None) -> Callable:
     """Step factory: 'vc' (flat frontier, beyond-paper), 'tc' (baseline),
     'vc_kernel' (faithful tile-per-vertex Pallas), 'vc_kernel_bsearch'
-    (faithful BCSR: Pallas tiles + binary-search reverse lookup).
-    'vc_fused' is not a per-cycle step — ``run_cycles`` drives it as K
-    cycles per launch (``repro.kernels.discharge``)."""
+    (faithful BCSR: Pallas tiles + binary-search reverse lookup)."""
     if mode == "tc":
         return tc_step
     if mode == "vc":
@@ -279,13 +276,6 @@ def run_cycles(g: DeviceGraph, meta: GraphMeta, state: PRState, s: int, t: int,
     the total is honored exactly even when it is not a multiple of the
     per-dispatch chunk.
 
-    ``mode='vc_fused'`` replaces the per-cycle XLA chain with the fused
-    discharge kernel: each loop iteration is ONE ``pallas_call`` executing
-    up to ``K_DEFAULT`` full cycles, and the kernel's live-cycle count
-    keeps ``cycles`` accounting identical to the unfused loop (the budget
-    may overshoot by at most K-1 when ``max_cycles``/``budget`` is not a
-    multiple).
-
     ``telemetry=True`` (static) folds the workload counters of
     ``repro.obs.solvercounters`` into the loop carry and returns a third
     element, a ``CycleTelemetry`` with push/relabel/active/frontier
@@ -302,81 +292,34 @@ def run_cycles(g: DeviceGraph, meta: GraphMeta, state: PRState, s: int, t: int,
         nact = jnp.sum(active_mask(state, meta.n, s, t))
         return (cycle < cap) & (nact > 0)
 
-    hist = max_cycles
-    steps_bound = max_cycles
-    if mode == "vc_fused":
-        from repro.kernels import discharge
+    step = _make_step(mode, interpret)
 
-        kk = max(1, min(discharge.K_DEFAULT, max_cycles))
-        # the last launch may start at cycle max_cycles-1 and write kk
-        # per-cycle history slots past it
-        hist = max_cycles + kk
-        steps_bound = -(-max_cycles // kk)  # K cycles per engine step
-        # loop-invariant launch inputs, built once: the steady-state body
-        # is [pad(res) -> ONE pallas_call -> slice(res)]
-        s_b = jnp.full((1,), s, jnp.int32)
-        t_b = jnp.full((1,), t, jnp.int32)
-        indptr_b = g.indptr[None]
-        heads_p = discharge.pad_arcs(g.heads[None])
-        rev_p = discharge.pad_arcs(g.rev[None])
-
-        if telemetry:
-            def body(carry):
-                state, cycle, tel = carry
-                res, h, e, live, _, cnt = discharge.fused_discharge_batched(
-                    s_b, t_b, indptr_b, heads_p, rev_p, state.res[None],
-                    state.h[None], state.e[None], n=meta.n, k=kk,
-                    interpret=interpret, counters=True)
-                acts, pushs, frs, mds = (c[0] for c in cnt)
-                upd = functools.partial(jax.lax.dynamic_update_slice,
-                                        start_indices=(cycle,))
-                tel = sc.CycleTelemetry(
-                    pushes=tel.pushes + jnp.sum(pushs),
-                    relabels=tel.relabels + jnp.sum(acts) - jnp.sum(pushs),
-                    active=tel.active + jnp.sum(acts),
-                    frontier=tel.frontier + jnp.sum(frs),
-                    active_hist=upd(tel.active_hist, acts),
-                    frontier_hist=upd(tel.frontier_hist, frs),
-                    maxdeg_hist=upd(tel.maxdeg_hist, mds))
-                return (PRState(res=res[0], h=h[0], e=e[0]),
-                        cycle + live[0], tel)
-        else:
-            def body(carry):
-                state, cycle = carry
-                res, h, e, live, _ = discharge.fused_discharge_batched(
-                    s_b, t_b, indptr_b, heads_p, rev_p, state.res[None],
-                    state.h[None], state.e[None], n=meta.n, k=kk,
-                    interpret=interpret)
-                return PRState(res=res[0], h=h[0], e=e[0]), cycle + live[0]
+    if telemetry:
+        def body(carry):
+            state, cycle, tel = carry
+            nact, fr, md = sc.cycle_stats(g, meta, state, s, t)
+            new = step(g, meta, state, s, t)
+            relab = sc.count_relabels(state.h, new.h)
+            upd = functools.partial(jax.lax.dynamic_update_slice,
+                                    start_indices=(cycle,))
+            tel = sc.CycleTelemetry(
+                pushes=tel.pushes + (nact - relab),
+                relabels=tel.relabels + relab,
+                active=tel.active + nact,
+                frontier=tel.frontier + fr,
+                active_hist=upd(tel.active_hist, nact[None]),
+                frontier_hist=upd(tel.frontier_hist, fr[None]),
+                maxdeg_hist=upd(tel.maxdeg_hist, md[None]))
+            return new, cycle + 1, tel
     else:
-        step = _make_step(mode, interpret)
+        def body(carry):
+            state, cycle = carry
+            return step(g, meta, state, s, t), cycle + 1
 
-        if telemetry:
-            def body(carry):
-                state, cycle, tel = carry
-                nact, fr, md = sc.cycle_stats(g, meta, state, s, t)
-                new = step(g, meta, state, s, t)
-                relab = sc.count_relabels(state.h, new.h)
-                upd = functools.partial(jax.lax.dynamic_update_slice,
-                                        start_indices=(cycle,))
-                tel = sc.CycleTelemetry(
-                    pushes=tel.pushes + (nact - relab),
-                    relabels=tel.relabels + relab,
-                    active=tel.active + nact,
-                    frontier=tel.frontier + fr,
-                    active_hist=upd(tel.active_hist, nact[None]),
-                    frontier_hist=upd(tel.frontier_hist, fr[None]),
-                    maxdeg_hist=upd(tel.maxdeg_hist, md[None]))
-                return new, cycle + 1, tel
-        else:
-            def body(carry):
-                state, cycle = carry
-                return step(g, meta, state, s, t), cycle + 1
-
-    scan_chunk = engine.normalize_chunk(chunk, steps_bound)
+    scan_chunk = engine.normalize_chunk(chunk, max_cycles)
     if telemetry:
         state, cycles, tel = engine.run_bulk_loop(
-            body, (state, jnp.int32(0), sc.telemetry_init(hist=hist)),
+            body, (state, jnp.int32(0), sc.telemetry_init(hist=max_cycles)),
             cond_fn=cond, chunk=scan_chunk)
         return state, cycles, tel
     state, cycles = engine.run_bulk_loop(body, (state, jnp.int32(0)),
@@ -429,8 +372,7 @@ def solve_impl(r: ResidualCSR, s: int, t: int, mode: str = "vc",
     remaining allowance rides into every ``run_cycles`` dispatch as the
     traced ``budget`` scalar, so the solve executes exactly
     ``max_cycles`` cycles before raising — even when the budget is not a
-    multiple of ``cycle_chunk`` — without a recompile per round
-    (``vc_fused`` may overshoot by < K, its documented launch granularity).
+    multiple of ``cycle_chunk`` — without a recompile per round.
     ``scan_chunk`` sets the engine's scanned steps-per-chunk
     (``repro.core.engine.DEFAULT_CHUNK`` when ``None``).
 

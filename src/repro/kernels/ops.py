@@ -60,7 +60,7 @@ def rev_lookup_bsearch(g, meta, arcs, *, interpret=None):
             f"binary search requires head-sorted (bcsr) segments, got "
             f"layout {meta.layout!r}")
     return bcsr_rev_search(arcs, g.indptr, g.heads, g.tails,
-                           deg_max=meta.deg_max, interpret=interpret)
+                           interpret=interpret)
 
 
 def rev_lookup_table(g, meta, arcs):

@@ -1,24 +1,23 @@
-"""Pallas kernel: BCSR backward-arc lookup by binary search (paper §3.2).
+"""Pallas TPU kernel: BCSR backward-arc lookup (paper §3.2).
 
-BCSR aggregates in/out arcs per vertex sorted by head id; the reverse arc of
-a push (u -> v) is found by binary-searching u inside v's segment —
-O(log d(v)) instead of O(d(v)).  The kernel vectorises the search across a
-128-lane tile of pushes: all lanes run the same ``ceil(log2(deg_max))``
-halving steps (lock-step, no divergence), with per-lane gathers of the probe
-heads.
+BCSR aggregates in/out arcs per vertex sorted by head id; the reverse arc
+of a push (u -> v) is the position of u inside v's head-sorted segment.
+The paper binary-searches it with O(log d(v)) dependent scalar probes.
+On a TPU core the dependent probes would each be a scalar load through
+HBM, so the kernel computes the same lower bound as a vectorised rank
+instead: it streams v's segment in 128-lane rows through the shared VMEM
+window (``repro.kernels.window``) and counts the heads below u, which
+in a sorted segment IS the binary search's result — a 128-lane compare
+per row replaces ``log2(128) = 7`` serial probes.  A second reduction
+confirms the arc exists (coalesced residuals hold one arc per direction
+per vertex pair).
 
-The grid carries a leading batch dimension — ``grid = (B, tiles)`` over
-per-instance ``indptr``/``heads``/``tails`` rows — so one launch resolves
-the reverse arcs of a whole bucketed microbatch's pushes (docs/DESIGN.md
+The grid carries a leading batch dimension — one launch resolves the
+reverse arcs of a whole bucketed microbatch's pushes (docs/DESIGN.md
 §6.3); the 1-D single-instance form is the ``B == 1`` special case.
 
-TPU note: per-lane gathers from an HBM-resident ``heads`` array are the
-GPU-ism here; on TPU the array is staged through VMEM (fine up to ~MB-scale
-segments) — the beyond-paper alternative is the precomputed ``rev[]`` index
-(see docs/DESIGN.md §6.3 and the §Perf log), which removes the search
-entirely.
-
-Validated in interpret mode against the build-time ``rev`` ground truth.
+Validated against the build-time ``rev`` ground truth (interpret mode on
+CPU, compiled on TPU).
 """
 from __future__ import annotations
 
@@ -26,46 +25,28 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.runtime import resolve_interpret
-
-LANES = 128
+from repro.kernels import window
 
 
-def _kernel(arcs_ref, indptr_ref, heads_ref, tails_ref, out_ref, *,
-            a_sent: int, steps: int):
-    b = pl.program_id(0)
-    heads = pl.load(heads_ref, (b, pl.ds(0, a_sent)))
-    tails = pl.load(tails_ref, (b, pl.ds(0, a_sent)))
-    indptr = indptr_ref[b, :]
-    arcs = arcs_ref[0, :]
-    valid = arcs < a_sent
-    arc_c = jnp.where(valid, arcs, 0)
-    u = tails[arc_c]  # push tail
-    v = heads[arc_c]  # push head; reverse arc lives in v's segment
-    lo = indptr[v]
-    hi = indptr[v + 1]
-
-    def body(_, carry):
-        lo, hi = carry
-        mid = (lo + hi) // 2
-        probe = heads[jnp.minimum(mid, a_sent - 1)]
-        go_right = probe < u
-        lo = jnp.where(go_right, mid + 1, lo)
-        hi = jnp.where(go_right, hi, mid)
-        return lo, hi
-
-    lo, hi = jax.lax.fori_loop(0, steps, body, (lo, hi))
-    found = valid & (lo < indptr[v + 1]) & \
-        (heads[jnp.minimum(lo, a_sent - 1)] == u)
-    out_ref[0, :] = jnp.where(found, lo, jnp.int32(a_sent))
+def _row_rank(v, idx, ok, u, acc):
+    """Fold one heads row into (#heads < u, #heads == u) in the window."""
+    below, hit = acc
+    below = below + jnp.sum(jnp.where(ok & (v < u), 1, 0), axis=1,
+                            keepdims=True)
+    hit = hit + jnp.sum(jnp.where(ok & (v == u), 1, 0), axis=1,
+                        keepdims=True)
+    return below, hit
 
 
-@functools.partial(jax.jit, static_argnames=("deg_max", "interpret"))
+def _finish(a, acc, lo):
+    below, hit = acc
+    return (jnp.where(hit > 0, lo + below, jnp.int32(a)),)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def bcsr_rev_search(arcs: jax.Array, indptr: jax.Array, heads: jax.Array,
-                    tails: jax.Array, *, deg_max: int,
+                    tails: jax.Array, *,
                     interpret: bool | None = None) -> jax.Array:
     """For each push arc a=(u->v) find the arc (v->u) in v's sorted segment.
 
@@ -73,37 +54,22 @@ def bcsr_rev_search(arcs: jax.Array, indptr: jax.Array, heads: jax.Array,
     (A,)``.  Batched: ``arcs (B, P)`` with ``(B, ·)`` graph rows — one
     launch, leading batch grid axis.  Sentinel ``>= A`` marks inactive
     lanes; returns reverse-arc ids with sentinel ``A`` where not
-    found/inactive.  ``interpret=None`` sniffs the backend.
+    found/inactive.  ``interpret=None``: compiled on TPU, interpreted on
+    CPU.
     """
-    interpret = resolve_interpret(interpret)
     single = arcs.ndim == 1
     if single:
         arcs, indptr = arcs[None], indptr[None]
         heads, tails = heads[None], tails[None]
-    bsz, p = arcs.shape
     a = heads.shape[1]
-    p_pad = max(LANES, -(-p // LANES) * LANES)
-    if p_pad != p:
-        arcs = jnp.concatenate(
-            [arcs, jnp.full((bsz, p_pad - p), a, jnp.int32)], axis=1)
-    steps = max(1, int(deg_max).bit_length())
-
-    kernel = functools.partial(_kernel, a_sent=a, steps=steps)
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=0,
-            grid=(bsz, p_pad // LANES),
-            in_specs=[
-                pl.BlockSpec((1, LANES), lambda b, i: (b, i)),
-                pl.BlockSpec(memory_space=pltpu.ANY),  # indptr
-                pl.BlockSpec(memory_space=pltpu.ANY),  # heads
-                pl.BlockSpec(memory_space=pltpu.ANY),  # tails
-            ],
-            out_specs=pl.BlockSpec((1, LANES), lambda b, i: (b, i)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((bsz, p_pad), jnp.int32),
-        interpret=interpret,
-    )(arcs, indptr, heads, tails)
-    out = out[:, :p]
+    valid = arcs < a
+    arc_c = jnp.where(valid, arcs, 0)
+    u = jnp.take_along_axis(tails, arc_c, axis=1)  # push tail
+    v = jnp.take_along_axis(heads, arc_c, axis=1)  # reverse arc lives here
+    lo = jnp.where(valid, jnp.take_along_axis(indptr, v, axis=1), 0)
+    hi = jnp.where(valid, jnp.take_along_axis(indptr, v + 1, axis=1), 0)
+    (out,) = window.windowed_reduce(
+        lo, hi, heads, u, row_fn=_row_rank,
+        finish=functools.partial(_finish, a), init=(0, 0), fills=(a,),
+        pad=0, interpret=interpret)
     return out[0] if single else out

@@ -3,29 +3,27 @@
 This is the paper's two-level parallelism hot spot (Alg. 2, second level):
 the CUDA version assigns a 32-lane warp per AVQ entry and runs Harris'
 parallel reduction over the vertex's CSR segment.  The TPU adaptation
-assigns a *128-lane tile* per AVQ entry: each grid program owns ``TILE_Q``
-active vertices and, for each, walks its contiguous arc window in 128-wide
-vector chunks held in VMEM, reducing (min, argmin).
+walks each AVQ entry's contiguous arc window in 128-lane rows streamed
+from HBM through a VMEM window (``repro.kernels.window``), reducing
+(min, argmin) per row and carrying it across rows.
 
 TPU-native structure:
-* ``avq`` and ``indptr`` arrive via **scalar prefetch** (SMEM) — they drive
-  the dynamic windows, exactly like sparse-kernel row pointers.
-* the arc *key* array (``h[heads[a]]`` masked by ``res[a] > 0``) is computed
-  by XLA before the call (gathers are XLA-native on TPU) and streamed from
-  HBM through dynamic 128-slices — the coalesced access the paper's BCSR is
-  designed for.
-* the reduction is a dense 128-lane vector min + iota-select argmin; no
-  shared-memory tree is needed on TPU (noted in docs/DESIGN.md §2).
-* the grid carries a **leading batch dimension**: ``grid = (B, tiles)``
-  with per-instance ``avq``/``indptr`` rows scalar-prefetched, so one
-  launch serves a whole bucketed microbatch (docs/DESIGN.md §2.4).  The
-  1-D single-instance form is the ``B == 1`` special case.
-* ``avq=None`` selects the **dense** kernel: every vertex is its own
-  queue entry, derived from the grid position — the Bellman-Ford sweep
-  shape used by the (batched) global relabel and phase 2, where an
-  all-vertices AVQ array would be pure overhead (docs/DESIGN.md §2.5).
+* the arc *key* array (``h[heads[a]]`` masked by ``res[a] > 0``) and the
+  per-entry windows ``indptr[u]:indptr[u+1]`` are computed by XLA before
+  the call (gathers are XLA-native on TPU); the kernel reads the windows
+  from SMEM blocks and the key rows by DMA.
+* the reduction is a 128-lane vector min + iota-select argmin; no
+  shared-memory tree is needed on TPU (docs/DESIGN.md §2).
+* the grid carries a **leading batch dimension** — one launch serves a
+  whole bucketed microbatch (docs/DESIGN.md §2.4).  The 1-D
+  single-instance form is the ``B == 1`` special case.
+* ``avq=None`` selects the **dense** form: every vertex is its own queue
+  entry — the Bellman-Ford sweep shape used by the (batched) global
+  relabel and phase 2, where an all-vertices AVQ array would be pure
+  overhead (docs/DESIGN.md §2.5).
 
-Validated in interpret mode against ``repro.kernels.ref.min_neighbor_ref``.
+Validated against ``repro.kernels.ref.min_neighbor_ref`` (interpret mode
+on CPU, compiled on TPU).
 """
 from __future__ import annotations
 
@@ -33,72 +31,30 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
-
 import numpy as np
 
-from repro.kernels.runtime import resolve_interpret
+from repro.kernels import window
 
 INF = np.int32(2**30)  # plain numpy scalar: becomes a literal inside kernels
-LANES = 128
-TILE_Q = 8
+_BIG = np.int32(2**31 - 1)
 
 
-def _reduce_segment(indptr_ref, key_ref, b, u, valid_u, *, n, a_pad):
-    """(min key, smallest argmin arc) over vertex ``u``'s arc window —
-    the shared body of the AVQ-driven and dense kernels."""
-    uc = jnp.minimum(u, n - 1)
-    start = indptr_ref[b, uc]
-    end = indptr_ref[b, uc + 1]
-    nchunks = jnp.where(valid_u, (end - start + LANES - 1) // LANES, 0)
-
-    def body(c, carry):
-        m, arg = carry
-        off = start + c * LANES
-        w = pl.load(key_ref, (b, pl.ds(off, LANES)))
-        idx = off + jax.lax.broadcasted_iota(jnp.int32, (LANES,), 0)
-        w = jnp.where(idx < end, w, INF)
-        lm = jnp.min(w)
-        # smallest arc index attaining the tile minimum
-        la = jnp.min(jnp.where(w == lm, idx, jnp.int32(a_pad)))
-        better = lm < m
-        m = jnp.where(better, lm, m)
-        arg = jnp.where(better & (lm < INF), la, arg)
-        return m, arg
-
-    return jax.lax.fori_loop(0, nchunks, body, (INF, jnp.int32(a_pad)))
+def _row_min(v, idx, ok, u, acc):
+    """Fold one key row into (min key, smallest argmin arc)."""
+    m, arg = acc
+    w = jnp.where(ok, v, INF)
+    lm = jnp.min(w, axis=1, keepdims=True)
+    la = jnp.min(jnp.where(w == lm, idx, _BIG), axis=1, keepdims=True)
+    better = lm < m
+    return jnp.where(better, lm, m), jnp.where(better & (lm < INF), la, arg)
 
 
-def _kernel(avq_ref, indptr_ref, key_ref, minh_ref, argarc_ref, *, n, a,
-            a_pad):
-    b = pl.program_id(0)
-    q0 = pl.program_id(1) * TILE_Q
-    for i in range(TILE_Q):
-        u = avq_ref[b, q0 + i]
-        valid_u = u < n
-        m, arg = _reduce_segment(indptr_ref, key_ref, b, u, valid_u, n=n,
-                                 a_pad=a_pad)
-        # normalize the no-eligible-arc sentinel to ``a`` — the same
-        # sentinel the flat-frontier XLA path uses, so downstream consumers
-        # compare against one value
-        minh_ref[0, i] = jnp.where(valid_u, m, INF)
-        argarc_ref[0, i] = jnp.where(valid_u & (m < INF), arg, jnp.int32(a))
-
-
-def _dense_kernel(indptr_ref, key_ref, minh_ref, argarc_ref, *, n, a, a_pad):
-    """Every vertex is its own queue entry (``avq == arange(n)``): the
-    Bellman-Ford sweep shape, where materialising and prefetching an
-    all-vertices AVQ per sweep would be pure overhead."""
-    b = pl.program_id(0)
-    q0 = pl.program_id(1) * TILE_Q
-    for i in range(TILE_Q):
-        u = jnp.int32(q0 + i)
-        valid_u = u < n
-        m, arg = _reduce_segment(indptr_ref, key_ref, b, u, valid_u, n=n,
-                                 a_pad=a_pad)
-        minh_ref[0, i] = jnp.where(valid_u, m, INF)
-        argarc_ref[0, i] = jnp.where(valid_u & (m < INF), arg, jnp.int32(a))
+def _finish(a, acc, lo):
+    m, arg = acc
+    # the no-eligible-arc sentinel is ``a`` — the same sentinel the
+    # flat-frontier XLA path uses, so downstream consumers compare
+    # against one value
+    return m, jnp.where(m < INF, arg, jnp.int32(a))
 
 
 @functools.partial(jax.jit, static_argnames=("n", "interpret"))
@@ -118,57 +74,29 @@ def tile_min_neighbor(avq: jax.Array | None, indptr: jax.Array,
         avq: (B, Q), indptr: (B, n+1), key: (B, A)
 
     ``avq=None`` is the **dense** form: every vertex is its own queue
-    entry (equivalent to ``avq == arange(n)`` rows, bit-for-bit) with no
-    AVQ array materialised or prefetched — the shape of the Bellman-Ford
-    distance sweeps, which visit all vertices every step.
+    entry (equivalent to ``avq == arange(n)`` rows, bit-for-bit).
 
     Returns ``(minh, argarc)`` of shape ``(Q,)`` / ``(B, Q)`` with
     ``argarc == A`` sentinel when no eligible arc exists (the flat-frontier
-    sentinel).  ``interpret=None`` sniffs the backend (compiled on TPU,
-    interpreted elsewhere).
+    sentinel).  ``interpret=None``: compiled on TPU, interpreted on CPU.
     """
-    interpret = resolve_interpret(interpret)
     single = key.ndim == 1
     if single:
         indptr, key = indptr[None], key[None]
         if avq is not None:
             avq = avq[None]
-    bsz = key.shape[0]
-    q = n if avq is None else avq.shape[1]
-    q_pad = -(-q // TILE_Q) * TILE_Q
-    if avq is not None and q_pad != q:
-        avq = jnp.concatenate(
-            [avq, jnp.full((bsz, q_pad - q), n, jnp.int32)], axis=1)
-    a = key.shape[1]
-    a_pad = a + LANES  # safe tail for the last dynamic 128-window
-    key_p = jnp.concatenate(
-        [key, jnp.full((bsz, LANES), INF, jnp.int32)], axis=1)
-
-    grid = (bsz, q_pad // TILE_Q)
     if avq is None:
-        kernel = functools.partial(_dense_kernel, n=n, a=a, a_pad=a_pad)
-        prefetch, operands = 1, (indptr, key_p)  # indptr -> SMEM
+        lo, hi = indptr[:, :n], indptr[:, 1:n + 1]
     else:
-        kernel = functools.partial(_kernel, n=n, a=a, a_pad=a_pad)
-        prefetch, operands = 2, (avq, indptr, key_p)  # avq, indptr -> SMEM
-    minh, argarc = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=prefetch,
-            grid=grid,
-            in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],  # key stays in HBM
-            out_specs=[
-                pl.BlockSpec((1, TILE_Q), lambda b, i, *_: (b, i)),
-                pl.BlockSpec((1, TILE_Q), lambda b, i, *_: (b, i)),
-            ],
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((bsz, q_pad), jnp.int32),
-            jax.ShapeDtypeStruct((bsz, q_pad), jnp.int32),
-        ],
-        interpret=interpret,
-    )(*operands)
-    minh, argarc = minh[:, :q], argarc[:, :q]
+        valid = avq < n
+        u = jnp.minimum(avq, n - 1)
+        lo = jnp.where(valid, jnp.take_along_axis(indptr, u, axis=1), 0)
+        hi = jnp.where(valid, jnp.take_along_axis(indptr, u + 1, axis=1), 0)
+    a = key.shape[1]
+    minh, argarc = window.windowed_reduce(
+        lo, hi, key.astype(jnp.int32), None, row_fn=_row_min,
+        finish=functools.partial(_finish, a), init=(int(INF), a),
+        fills=(int(INF), a), pad=int(INF), interpret=interpret)
     if single:
         minh, argarc = minh[0], argarc[0]
     return minh, argarc

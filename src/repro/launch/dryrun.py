@@ -27,7 +27,6 @@ import sys
 import time
 
 import jax
-from repro import compat
 import numpy as np
 
 REPO = pathlib.Path(__file__).resolve().parents[3]
@@ -71,7 +70,7 @@ def _lower_lm(cfg, cell, mesh):
     else:
         fn = make_decode_step(cfg)
         donate = (1,)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         return jax.jit(fn, donate_argnums=donate).lower(*args)
 
 
@@ -108,7 +107,7 @@ def _lower_graph(cell, mesh, mode, cycles=64):
     axes = tuple(mesh.axis_names)
     meta, g, res, h, e = _graph_specs(cell, mesh, axes, mode)
     superstep = D.make_superstep(meta, axes, cycles=cycles, mesh=mesh)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         full = jax.jit(superstep, donate_argnums=(1, 2, 3)).lower(g, res, h, e)
         step = D.make_dist_step(meta, axes, mesh)
         step_l = jax.jit(step).lower(g.indptr, g.heads, g.rev, res, h, e)

@@ -13,6 +13,9 @@ from repro.api.options import MODES
 
 
 def main(argv=None):
+    from repro.runtime.cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--generator", default="powerlaw",
                     choices=["powerlaw", "washington", "genrmf", "grid",

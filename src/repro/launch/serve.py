@@ -8,7 +8,6 @@ import argparse
 import time
 
 import jax
-from repro import compat
 import jax.numpy as jnp
 
 
@@ -31,7 +30,7 @@ def main(argv=None):
            else registry.get_config(args.arch))
     mesh = make_host_mesh()
     key = jax.random.PRNGKey(0)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = T.init_params(cfg, key)
         toks = jax.random.randint(key, (args.batch, args.prompt_len), 0,
                                   cfg.vocab)

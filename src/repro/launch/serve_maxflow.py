@@ -99,6 +99,9 @@ def check_smoke(snap: dict, trace_path: str | None, overhead: dict,
 
 
 def main(argv=None):
+    from repro.runtime.cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--requests", type=int, default=64)
     ap.add_argument("--rate", type=float, default=200.0,

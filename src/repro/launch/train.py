@@ -10,7 +10,6 @@ import argparse
 import dataclasses
 
 import jax
-from repro import compat
 import numpy as np
 
 
@@ -69,7 +68,7 @@ def main(argv=None):
                          ext_embed_len=(cfg.enc_len if cfg.is_encoder_decoder
                                         else cfg.img_tokens),
                          d_model=cfg.d_model)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         report = run_loop(ckpt_dir=args.ckpt_dir, total_steps=args.steps,
                           make_state=make_state, step_fn=step_fn,
                           pipeline=pipe, ckpt_every=args.ckpt_every)

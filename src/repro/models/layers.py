@@ -11,8 +11,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
-
 NEG_INF = -1e30
 
 
@@ -278,7 +276,7 @@ def tp_matmul_bf16reduce(x, w, *, batch_axes):
     Falls back to a plain matmul when no 'model' axis is present."""
     import jax
     from jax.sharding import PartitionSpec as P
-    m = compat.get_abstract_mesh()
+    m = jax.sharding.get_abstract_mesh()
     if m is None or "model" not in m.axis_names:
         return x @ w
     ba = tuple(a for a in (batch_axes or ()) if a in m.axis_names) or None
@@ -291,7 +289,7 @@ def tp_matmul_bf16reduce(x, w, *, batch_axes):
     in_x = P(*((ba,) + (None,) * (nd - 2) + ("model",)))
     in_w = P("model", None)
     out = P(*((ba,) + (None,) * (nd - 1)))
-    return compat.shard_map(local, mesh=None, in_specs=(in_x, in_w),
+    return jax.shard_map(local, mesh=None, in_specs=(in_x, in_w),
                          out_specs=out, check_vma=False)(x, w)
 
 

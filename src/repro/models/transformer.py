@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.models import layers as L
 from repro.models import mamba as M
 from repro.models import moe as MOE
@@ -188,7 +187,7 @@ def cache_shape_tree(cfg, mesh, batch, cache_len, rules=None, **kw) -> dict:
 
 def _batch_axes():
     """Mesh axes carrying the batch dim, from the ambient mesh (if any)."""
-    m = compat.get_abstract_mesh()
+    m = jax.sharding.get_abstract_mesh()
     names = m.axis_names if m is not None else ()
     ax = tuple(a for a in ("pod", "data") if a in names)
     return ax if ax else None
@@ -196,7 +195,7 @@ def _batch_axes():
 
 def _constrain(x, *axes):
     """with_sharding_constraint that degrades to a no-op off-mesh."""
-    m = compat.get_abstract_mesh()
+    m = jax.sharding.get_abstract_mesh()
     if m is None or not m.axis_names:
         return x
     names = set(m.axis_names)

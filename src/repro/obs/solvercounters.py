@@ -6,8 +6,7 @@ and scanned-arc counts; fetching them with host round-trips per cycle
 (the old ``SolveStats.frontier_history`` list-append path) serialises
 the solve.  Instead the counters are folded into the existing
 ``while_loop`` carries of ``pushrelabel.run_cycles`` /
-``batched.batched_run_cycles`` (and, for ``vc_fused``, into the fused
-discharge kernel's own outputs) so they are computed on device and
+``batched.batched_run_cycles`` so they are computed on device and
 fetched ONCE per dispatch.
 
 Counter definitions (identical across every mode, because the state

@@ -66,7 +66,7 @@ class FaultPlan:
       dispatches running one of these solver modes fail with probability
       ``fail_mode_rate`` (1.0 = always), until ``fail_mode_limit`` total
       injections.  This is how a test forces the ladder to demote
-      ``vc_fused -> vc_kernel -> vc`` (or to the host reference when
+      ``vc_kernel_bsearch -> vc_kernel -> vc`` (or to the host reference when
       ``'vc'`` is included).
     * ``corrupt_handle_rate`` — chance a freshly cached warm-start handle
       has its residual/excess arrays poisoned in place (see

@@ -49,8 +49,8 @@ retry-after hint once full — after shedding expired work first), requests
 may carry a ``deadline_s`` (expired work is shed *before* dispatch and
 fails with ``DeadlineExceeded``; a near-deadline queue flushes early),
 dispatch failures walk a graceful degradation ladder (retry with
-exponential backoff + jitter at each rung, demote ``vc_fused ->
-vc_kernel -> vc``, bottom out on the sequential host reference solver),
+exponential backoff + jitter at each rung, demote ``vc_kernel_bsearch
+-> vc_kernel -> vc``, bottom out on the sequential host reference solver),
 and cached warm-start handles are validated before every reuse —
 corrupted state is quarantined and rebuilt cold, never warm-started
 from.  A seed-deterministic ``repro.runtime.fault.FaultPlan`` injects
@@ -66,6 +66,7 @@ import time
 import weakref
 from collections import deque
 
+import jax
 import numpy as np
 
 from repro.api.solution import WarmStartHandle
@@ -76,6 +77,7 @@ from repro.errors import (BudgetExhausted, DeadlineExceeded, DispatchFailed,
                           HandleCorrupted, Overloaded)
 from repro.graphs.generators import BipartiteProblem
 from repro.obs import REGISTRY, TRACER, counter, histogram, span, to_jsonable
+from repro.runtime.fault import InjectedFault
 from repro.serving.cache import (CacheEntry, ExecutableCache, ResultCache,
                                  canonical_graph_key)
 from repro.serving.policy import (HOST_REF, BucketLadder, BucketModePolicy,
@@ -99,12 +101,23 @@ def _pooled_correction(svc_ref, handle_ref) -> None:
         svc._correct_batch(handle)
 
 
+def _is_dispatch_fault(exc: Exception, compiled_before: bool) -> bool:
+    """Whether the degradation ladder may absorb ``exc``: an injected
+    fault, an exhausted cycle budget, or a runtime error from an
+    executable that had already compiled.  Anything else was raised
+    while tracing, lowering or compiling the mode — a program fault that
+    a lower rung would only hide."""
+    if isinstance(exc, (InjectedFault, BudgetExhausted)):
+        return True
+    return compiled_before and isinstance(exc, jax.errors.JaxRuntimeError)
+
+
 @dataclasses.dataclass(frozen=True)
 class ServiceConfig:
     # "auto" (default): measured per-bucket mode policy — each shape
     # bucket trials the candidate modes on its first flushes and pins the
     # measured winner (see repro.serving.policy).  Any fixed solver mode
-    # ('vc' | 'tc' | 'vc_kernel' | 'vc_kernel_bsearch' | 'vc_fused') is
+    # ('vc' | 'tc' | 'vc_kernel' | 'vc_kernel_bsearch') is
     # the escape hatch: every bucket runs exactly that mode, no trials.
     mode: str = "auto"
     layout: str = "bcsr"  # 'bcsr' | 'rcsr'
@@ -583,9 +596,7 @@ class MaxflowService:
             ladder = self._ladders[key] = BucketLadder(
                 demote_after=self.config.demote_after, label=key.label)
 
-        def dispatch(m):
-            compiled_before = self.executables.note(
-                (key, B, m, self.config.cycle_chunk))
+        def dispatch(m, compiled_before):
             t0 = time.perf_counter()
             with span("serve.solve", bucket=key.label, mode=m, batch=B,
                       live=live, compiled=compiled_before):
@@ -593,17 +604,20 @@ class MaxflowService:
                     bg, meta, state0, trivial=trivial, mode=m,
                     cycle_chunk=self.config.cycle_chunk,
                     telemetry=self.config.telemetry)
-            return out, time.perf_counter() - t0, compiled_before
+            return out, time.perf_counter() - t0
 
         # graceful degradation ladder: retry each rung with exponential
         # backoff + jitter, then demote one mode down; the bottom rung is
         # the sequential host reference solver.  A rung that fails
         # repeatedly across flushes drops the bucket's ceiling for good
-        # (BucketLadder).
+        # (BucketLadder).  Only dispatch-time faults walk the ladder: an
+        # error from tracing, lowering or compiling a mode is a program
+        # fault and propagates (see ``_is_dispatch_fault``).
         cur = ladder.clamp(mode0)
         attempts = 0
         tries_at_rung = 0
         while True:
+            compiled_before = True
             try:
                 if cur == HOST_REF:
                     if self.faults is not None:
@@ -613,9 +627,13 @@ class MaxflowService:
                 if self.faults is not None:
                     self.faults.before_dispatch(
                         cur, where=f"flush:{key.label}")
-                out, secs, compiled_before = dispatch(cur)
+                compiled_before = self.executables.note(
+                    (key, B, cur, self.config.cycle_chunk))
+                out, secs = dispatch(cur, compiled_before)
                 break
             except Exception as exc:
+                if not _is_dispatch_fault(exc, compiled_before):
+                    raise
                 attempts += 1
                 if isinstance(exc, BudgetExhausted):
                     self.n_budget_exhausted += 1
@@ -652,7 +670,7 @@ class MaxflowService:
                 # first dispatch under this (bucket, mode) paid XLA
                 # compilation: re-run the identical pure solve warm so the
                 # recorded sample measures execution, not tracing
-                out, secs, _ = dispatch(cur)
+                out, secs = dispatch(cur, True)
             policy.record(cur, secs, int(out.cycles.sum()))
         self.sweep_time_s += out.gr_time_s
         self.gr_sweeps += int(out.gr_sweeps)

@@ -1,18 +1,16 @@
 """Measured per-bucket kernel-mode policy for the serving tier.
 
 Which push-relabel step strategy is fastest is a *per-shape-class*
-question: the fused discharge kernel amortises launch overhead on small
-padded buckets but serialises over vertices, the tile kernel wins where
-the min search dominates, and the pure-XLA ``vc`` chain wins wherever
-Pallas runs interpreted (CPU) or the scatter stages dominate.  Pinning
+question: the tile kernel wins where the min search dominates, and the
+pure-XLA ``vc`` chain wins wherever Pallas runs interpreted (CPU) or the
+scatter stages dominate.  Pinning
 one global mode therefore leaves throughput behind on every bucket the
 pin is wrong for.
 
 ``BucketModePolicy`` turns the choice into a measurement: under
 ``ServiceConfig(mode="auto")`` each shape bucket spends its first few
-flushes trialling the candidate modes (``vc``, ``vc_kernel``,
-``vc_fused``, plus ``vc_kernel_bsearch`` when the packed layout is
-head-sorted), records the **per-cycle** cost of each (normalising by the
+flushes trialling the candidate modes (``vc``, ``vc_kernel``, plus
+``vc_kernel_bsearch`` when the packed layout is head-sorted), records the **per-cycle** cost of each (normalising by the
 work the flush happened to carry, so trials on different microbatches
 compare fairly), and pins the winner for every later flush.  Samples
 polluted by XLA compilation are excluded — the service re-dispatches a
@@ -34,7 +32,7 @@ from repro.obs import metrics
 #: modes the auto policy trials, in trial order.  'tc' is excluded by
 #: design: it is the paper's imbalance baseline, strictly dominated on
 #: every workload the serving tier targets.
-CANDIDATE_MODES = ("vc", "vc_kernel", "vc_fused")
+CANDIDATE_MODES = ("vc", "vc_kernel")
 
 
 def candidate_modes(layout: str) -> tuple[str, ...]:
@@ -156,7 +154,7 @@ HOST_REF = "host_ref"
 #: demotion order, most- to least-specialised.  A dispatch failure at one
 #: rung retries at the next; 'tc' (not listed) demotes straight to 'vc''s
 #: rung since both are pure-XLA chains of equivalent generality.
-LADDER = ("vc_fused", "vc_kernel_bsearch", "vc_kernel", "vc", HOST_REF)
+LADDER = ("vc_kernel_bsearch", "vc_kernel", "vc", HOST_REF)
 
 
 def ladder_rank(mode: str) -> int:

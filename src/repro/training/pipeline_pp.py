@@ -23,8 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
-
 from repro.models import layers as L
 from repro.models import transformer as T
 
@@ -103,8 +101,8 @@ def make_pp_loss(cfg, mesh, *, stages: int, microbatches: int):
                                is_leaf=lambda x: isinstance(x, L.PSpec))
     in_specs = ({"embed": P(), "final_norm": P(), "blocks": blocks_spec},
                 P(), P())
-    pp = compat.shard_map(local_loss, mesh=mesh, in_specs=in_specs,
-                          out_specs=P("pod"), check_vma=False)
+    pp = jax.shard_map(local_loss, mesh=mesh, in_specs=in_specs,
+                       out_specs=P("pod"), check_vma=False)
 
     def loss_fn(params, batch):
         tokens, labels = batch["tokens"], batch["labels"]

@@ -246,7 +246,7 @@ def test_int32_lattice_passes_int32_program():
 
 
 def test_int32_lattice_fires_on_stray_int64_widening():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         def bad(x):
             return x.astype(jnp.int64) + 1
 

@@ -90,7 +90,7 @@ def test_solve_many_accepts_kernel_modes(rng):
     gs = [random_graph(rng, n_lo=6, n_hi=20) for _ in range(3)]
     probs = [MaxflowProblem(g, 0, g.n - 1) for g in gs]
     want = [s.value for s in Solver(backend="batched").solve_many(probs)]
-    for mode in ("vc_kernel", "vc_kernel_bsearch", "vc_fused"):
+    for mode in ("vc_kernel", "vc_kernel_bsearch"):
         sols = Solver(backend="batched", mode=mode).solve_many(probs)
         assert [s.value for s in sols] == want
         assert all(s.stats.mode == mode for s in sols)

@@ -305,8 +305,7 @@ def test_batched_sweeps_one_pallas_call_per_step(rng):
 
 @pytest.mark.parametrize("mode,layout", [
     ("vc_kernel", "bcsr"), ("vc_kernel", "rcsr"),
-    ("vc_kernel_bsearch", "bcsr"), ("vc_fused", "bcsr"),
-    ("vc_fused", "rcsr"),
+    ("vc_kernel_bsearch", "bcsr"),
 ])
 def test_batched_kernel_modes_match_vc(mode, layout, rng):
     """Bucketed microbatches through the batch-grid Pallas kernels: same

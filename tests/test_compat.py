@@ -1,55 +1,24 @@
-"""``repro.compat`` shims verified against the installed jax pin.
-
-Every helper here exists to paper over jax API drift; these tests pin
-down that each one still returns something sane on the version the
-container actually ships, so a dead fallback (or a newly broken live
-one) fails loudly instead of rotting.
-"""
+"""``repro.compat.make_mesh`` on the installed jax: Auto axis types, usable
+by ``jax.set_mesh`` and ``jax.shard_map``."""
 import jax
 import jax.numpy as jnp
 
 from repro import compat
 
 
-def test_jaxpr_symbols_importable():
-    # the 0.4.35 floor guarantees jax.extend.core; the old jax.core
-    # fallback was removed — this would catch a pin that breaks it
-    assert compat.ClosedJaxpr is not None
-    assert compat.Jaxpr is not None
-
-
-def test_count_jaxpr_eqns_moved_to_analysis_ir():
-    # the walker lives in repro.analysis.ir now (as count_eqns, plus the
-    # full census); compat must NOT quietly regrow a duplicate
-    assert not hasattr(compat, "count_jaxpr_eqns")
-    from repro.analysis import ir
-    assert callable(ir.count_eqns)
-
-
-def test_get_abstract_mesh_does_not_raise():
-    # on jax without the API this is None; with it, whatever is ambient
-    compat.get_abstract_mesh()
-
-
 def test_make_and_set_mesh_single_device():
     mesh = compat.make_mesh((1,), ("shard",))
     assert mesh.devices.size == 1
-    ctx = compat.set_mesh(mesh)
-    with ctx:
-        pass  # both spellings yield a context manager
+    assert mesh.axis_types == (jax.sharding.AxisType.Auto,)
+    with jax.set_mesh(mesh):
+        pass
 
 
 def test_shard_map_identity_roundtrip():
     from jax.sharding import PartitionSpec as P
 
     mesh = compat.make_mesh((1,), ("shard",))
-    f = compat.shard_map(lambda x: x * 2, mesh=mesh,
-                         in_specs=P("shard"), out_specs=P("shard"))
+    f = jax.shard_map(lambda x: x * 2, mesh=mesh,
+                      in_specs=P("shard"), out_specs=P("shard"))
     x = jnp.arange(4, dtype=jnp.int32)
     assert (f(x) == x * 2).all()
-
-
-def test_cost_analysis_returns_dict():
-    compiled = jax.jit(lambda x: x + 1).lower(jnp.arange(8)).compile()
-    ca = compat.cost_analysis(compiled)
-    assert isinstance(ca, dict)

@@ -92,7 +92,7 @@ def _prepped(mode, layout="bcsr", n=40, m=160, seed=3):
 
 @pytest.mark.parametrize("mode,layout", [
     ("vc", "bcsr"), ("vc", "rcsr"), ("tc", "bcsr"),
-    ("vc_kernel", "bcsr"), ("vc_fused", "bcsr"),
+    ("vc_kernel", "bcsr"), ("vc_kernel_bsearch", "bcsr"),
 ])
 def test_run_cycles_chunk_invariant(mode, layout):
     """chunk=1 runs the engine's bare while_loop path — the pre-engine
@@ -164,11 +164,11 @@ def test_global_relabel_and_solve_chunk_invariant():
 _loop_counts = ir.loop_counts
 
 
-@pytest.mark.parametrize("mode", ["vc", "vc_kernel", "vc_fused"])
+@pytest.mark.parametrize("mode", ["vc", "vc_kernel", "vc_kernel_bsearch"])
 def test_run_cycles_steady_state_is_one_scanned_body(mode):
     """The cycle loop compiles to ONE outer while over ONE scanned chunk
-    body — not max_cycles step replicas; kernel modes hold exactly one
-    pallas_call per sweep step inside it.  ('tc' is excluded: its
+    body — not max_cycles step replicas; kernel modes hold one
+    pallas_call per kernel inside it (two for the reverse-arc search).  ('tc' is excluded: its
     per-arc segment scan is itself a fori_loop and lowers to a second,
     step-internal scan.)"""
     g, meta, state, s, t = _prepped(mode)
@@ -178,9 +178,8 @@ def test_run_cycles_steady_state_is_one_scanned_body(mode):
         state.res, state.h, state.e)
     assert nwhile == 1, f"expected one outer while, saw {nwhile}"
     assert nscan == 1, f"expected one scanned chunk body, saw {nscan}"
-    if mode in pr.KERNEL_MODES:
-        assert npallas == 1, \
-            f"expected one pallas_call per sweep step, saw {npallas}"
+    want = {"vc": 0, "vc_kernel": 1, "vc_kernel_bsearch": 2}[mode]
+    assert npallas == want, f"expected {want} pallas_calls, saw {npallas}"
 
 
 def test_batched_run_cycles_steady_state_is_one_scanned_body():

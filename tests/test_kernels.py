@@ -1,7 +1,5 @@
-"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps (interpret mode),
-batch-grid-axis parity, and the fused-discharge kernel's bit-for-bit
-equivalence with ``vc_step``."""
-import jax
+"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps (interpret mode)
+and batch-grid-axis parity."""
 import numpy as np
 import jax.numpy as jnp
 import pytest
@@ -9,7 +7,6 @@ from hypothesis import given, settings, strategies as st
 
 from repro.core import pushrelabel as pr
 from repro.core.csr import build_residual
-from repro.kernels import discharge
 from repro.kernels import ref as kref
 from repro.kernels.revsearch import bcsr_rev_search
 from repro.kernels.segmin import tile_min_neighbor
@@ -48,10 +45,11 @@ def test_segmin_empty_avq():
     assert np.all(np.asarray(km) == int(kref.INF))
 
 
-def test_segmin_large_degree_vertex():
-    """Star graph: one vertex with degree >> 128 exercises the chunk loop."""
+@pytest.mark.parametrize("n", [600, 5000])
+def test_segmin_large_degree_vertex(n):
+    """Star graph: one vertex with degree >> 128 exercises the row loop;
+    at 5000 its window spans several VMEM window refills."""
     from repro.core.csr import Graph
-    n = 600
     edges = np.array([[0, i] for i in range(1, n)], np.int64)
     g = Graph(n, edges, np.ones(n - 1, np.int64))
     r = build_residual(g, "bcsr")
@@ -74,8 +72,7 @@ def test_revsearch_matches_rev_table(trial):
     r, dg, meta, _ = _graph_state(rng)
     a = meta.num_arcs
     arcs = jnp.asarray(rng.integers(0, a + 4, size=2 * a), jnp.int32)
-    got = bcsr_rev_search(arcs, dg.indptr, dg.heads, dg.tails,
-                          deg_max=meta.deg_max)
+    got = bcsr_rev_search(arcs, dg.indptr, dg.heads, dg.tails)
     want = kref.rev_search_ref(arcs, dg.rev, a)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
@@ -191,12 +188,11 @@ def test_revsearch_batch_axis_matches_single_rows():
     # unfindable by construction (empty segments) and never pushed
     arcs = jnp.asarray(rng.integers(0, a + 4, size=(b, 2 * a)), jnp.int32)
     arcs = jnp.where(arcs < bg.num_arcs[:, None], arcs, jnp.int32(a))
-    got = bcsr_rev_search(arcs, bg.indptr, bg.heads, bg.tails,
-                          deg_max=meta.deg_max)
+    got = bcsr_rev_search(arcs, bg.indptr, bg.heads, bg.tails)
     assert got.shape == arcs.shape
     for i in range(b):
         single = bcsr_rev_search(arcs[i], bg.indptr[i], bg.heads[i],
-                                 bg.tails[i], deg_max=meta.deg_max)
+                                 bg.tails[i])
         np.testing.assert_array_equal(np.asarray(got[i]), np.asarray(single))
         want = kref.rev_search_ref(arcs[i], bg.rev[i], a)
         np.testing.assert_array_equal(np.asarray(single), np.asarray(want))
@@ -222,123 +218,41 @@ def test_segmin_dense_matches_arange_avq():
     np.testing.assert_array_equal(np.asarray(sa), np.asarray(da[0]))
 
 
-# -- fused discharge kernel -------------------------------------------------
+def test_kernels_span_several_tiles():
+    """Queues longer than one tile of entries: tiles past a compacted
+    AVQ's valid prefix only keep their sentinels, and every tile streams
+    its windows through its own VMEM refills."""
+    from repro.graphs import generators as G
+
+    rng = np.random.default_rng(24)
+    g0, _, _ = G.random_sparse(2500, 9000, seed=5)
+    r = build_residual(g0, "bcsr")
+    dg, meta, res0 = pr.to_device(r)
+    n, a = meta.n, meta.num_arcs
+    h = jnp.asarray(rng.integers(0, n, size=n), jnp.int32)
+    key = jnp.where(res0 > 0, h[dg.heads], kref.INF).astype(jnp.int32)
+    some = np.sort(rng.choice(n, size=1100, replace=False))
+    avq = jnp.asarray(np.concatenate([some, np.full(n - 1100, n)]),
+                      jnp.int32)
+    for q in (avq, jnp.arange(n, dtype=jnp.int32)):
+        km, ka = tile_min_neighbor(q, dg.indptr, key, n=n)
+        rm, ra = kref.min_neighbor_ref(q, dg.indptr, key, n=n)
+        np.testing.assert_array_equal(np.asarray(km), np.asarray(rm))
+        np.testing.assert_array_equal(np.asarray(ka), np.asarray(ra))
+    dm, da = tile_min_neighbor(None, dg.indptr, key, n=n)
+    np.testing.assert_array_equal(np.asarray(dm), np.asarray(rm))
+    np.testing.assert_array_equal(np.asarray(da), np.asarray(ra))
+    arcs = jnp.asarray(rng.integers(0, a + 4, size=3000), jnp.int32)
+    got = bcsr_rev_search(arcs, dg.indptr, dg.heads, dg.tails)
+    want = kref.rev_search_ref(arcs, dg.rev, a)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
 
 def _device_instance(rng, **kw):
     g0 = random_graph(rng, **kw)
     r = build_residual(g0, "bcsr")
     g, meta, res0 = pr.to_device(r)
     return g, meta, res0
-
-
-@pytest.mark.parametrize("k", [1, 3, 8])
-def test_fused_discharge_bit_for_bit_vs_vc_step(k):
-    """K fused cycles == K sequential ``vc_step`` applications, exactly —
-    including the post-preflow all-relabel cycles (heights all zero, so no
-    push is admissible) and push-heavy cycles after a global relabel."""
-    from repro.core import globalrelabel
-
-    rng = np.random.default_rng(31)
-    g, meta, res0 = _device_instance(rng, n_lo=10, n_hi=30)
-    s, t = 0, meta.n - 1
-    for state in (pr.preflow(g, meta, res0, s),  # all-relabel first cycles
-                  globalrelabel.global_relabel(
-                      g, meta, pr.preflow(g, meta, res0, s), s, t)[0]):
-        want = state
-        for _ in range(k):
-            want = pr.vc_step(g, meta, want, s, t)
-        res, h, e, live, _ = discharge.fused_discharge(g, meta, state, s, t,
-                                                       k=k)
-        np.testing.assert_array_equal(np.asarray(res), np.asarray(want.res))
-        np.testing.assert_array_equal(np.asarray(h), np.asarray(want.h))
-        np.testing.assert_array_equal(np.asarray(e), np.asarray(want.e))
-
-
-def test_fused_discharge_empty_avq_is_noop():
-    """A converged (or never-started) state passes through unchanged and
-    reports zero live cycles."""
-    rng = np.random.default_rng(32)
-    g, meta, res0 = _device_instance(rng)
-    idle = pr.PRState(res=res0, h=jnp.zeros(meta.n, jnp.int32),
-                      e=jnp.zeros(meta.n, jnp.int32))
-    res, h, e, live, pushed = discharge.fused_discharge(g, meta, idle, 0,
-                                                        meta.n - 1, k=4)
-    assert int(live) == 0
-    assert int(pushed) == 0
-    np.testing.assert_array_equal(np.asarray(res), np.asarray(res0))
-    np.testing.assert_array_equal(np.asarray(e), np.zeros(meta.n))
-
-
-def test_fused_discharge_live_cycle_accounting():
-    """``live`` counts exactly the cycles that began with an active vertex,
-    so driver cycle stats match the unfused loop."""
-    from repro.core import globalrelabel
-
-    rng = np.random.default_rng(33)
-    g, meta, res0 = _device_instance(rng, n_lo=8, n_hi=16)
-    s, t = 0, meta.n - 1
-    state, _, _ = globalrelabel.global_relabel(g, meta,
-                                               pr.preflow(g, meta, res0, s),
-                                               s, t)
-    # count live cycles by stepping the reference until the AVQ empties
-    want_live, ref = 0, state
-    for _ in range(64):
-        if int(jnp.sum(pr.active_mask(ref, meta.n, s, t))) == 0:
-            break
-        ref = pr.vc_step(g, meta, ref, s, t)
-        want_live += 1
-    *_, live, _ = discharge.fused_discharge(g, meta, state, s, t, k=64)
-    assert int(live) == want_live
-
-
-def test_fused_discharge_pushed_flag():
-    """``pushed`` reflects actual pushes, not e-movement: the first
-    post-preflow cycle is all-relabel (every height is 0, nothing is
-    admissible) -> pushed == 0 even though vertices were live; a chunk
-    spanning the subsequent discharge reports pushed != 0."""
-    rng = np.random.default_rng(35)
-    g, meta, res0 = _device_instance(rng, n_lo=10, n_hi=20)
-    s, t = 0, meta.n - 1
-    state = pr.preflow(g, meta, res0, s)
-    *_, live, pushed = discharge.fused_discharge(g, meta, state, s, t, k=1)
-    assert int(live) == 1 and int(pushed) == 0
-    *_, live, pushed = discharge.fused_discharge(g, meta, state, s, t, k=8)
-    assert int(pushed) == 1
-
-
-def _count_primitive(jaxpr, name):
-    from repro.analysis import ir
-
-    return ir.count_eqns(jaxpr, lambda e: e.primitive.name == name)
-
-
-def test_fused_k_cycles_issue_exactly_one_pallas_call():
-    """The HLO-level fusion claim: K discharge cycles lower to ONE
-    ``pallas_call`` (vs. the ~10-op XLA chain per cycle in ``vc_step``)."""
-    rng = np.random.default_rng(34)
-    g, meta, res0 = _device_instance(rng)
-    s, t = 0, meta.n - 1
-    state = pr.preflow(g, meta, res0, s)
-    jaxpr = jax.make_jaxpr(
-        lambda st: discharge.fused_discharge(g, meta, st, s, t, k=8))(state)
-    assert _count_primitive(jaxpr.jaxpr, "pallas_call") == 1
-    # and the whole vc_fused chunked loop still launches one kernel per
-    # loop body (the while_loop body traces the same single pallas_call)
-    jaxpr2 = jax.make_jaxpr(
-        lambda st: pr.run_cycles(g, meta, st, s, t, mode="vc_fused",
-                                 max_cycles=32))(state)
-    assert _count_primitive(jaxpr2.jaxpr, "pallas_call") == 1
-
-
-def test_fused_solve_end_to_end(rng):
-    from repro.api import MaxflowProblem, Solver
-    from repro.core.ref_maxflow import dinic_maxflow
-    g = random_graph(rng, n_lo=8, n_hi=20)
-    want = dinic_maxflow(g, 0, g.n - 1)
-    problem = MaxflowProblem(g, 0, g.n - 1)
-    assert Solver(mode="vc_fused").solve(problem).value == want
-    assert Solver(backend="batched",
-                  mode="vc_fused").solve(problem).value == want
 
 
 # -- shared minh_fn hook routing -------------------------------------------

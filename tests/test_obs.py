@@ -29,7 +29,7 @@ from repro.obs import (REGISTRY, TRACER, span, to_jsonable, traced)
 from repro.obs.metrics import MetricsRegistry
 from tests.conftest import random_graph
 
-MODES = ("vc", "tc", "vc_kernel", "vc_fused")
+MODES = ("vc", "tc", "vc_kernel", "vc_kernel_bsearch")
 
 
 @pytest.fixture(autouse=True)
@@ -183,7 +183,7 @@ def test_batched_counter_parity(rng):
         g = random_graph(rng, n_lo=10, n_hi=18)
         insts.append((build_residual(g, "bcsr"), 0, g.n - 1))
     base = None
-    for mode in ("vc", "vc_kernel", "vc_fused"):
+    for mode in ("vc", "vc_kernel", "vc_kernel_bsearch"):
         out = batched.batched_solve_impl(insts, mode=mode, telemetry=True)
         assert (out.pushes + out.relabels == out.active_sum).all()
         cur = (out.maxflows.tolist(), out.pushes.tolist(),
@@ -216,7 +216,7 @@ def test_disabled_telemetry_trace_is_lean(rng):
         census = ir.census_of(jx)
         return census.eqn_count, census.pallas_call_count, str(jx)
 
-    for mode in ("vc", "vc_fused"):
+    for mode in ("vc", "vc_kernel"):
         off_n, off_p, off_s = eqns(mode, False)
         on_n, on_p, _ = eqns(mode, True)
         assert off_n < on_n, (mode, off_n, on_n)

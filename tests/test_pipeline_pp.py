@@ -30,7 +30,7 @@ batch = {
 ref_loss_fn = make_loss_fn(cfg)
 ref_loss, _ = ref_loss_fn(params, batch)
 pp_loss_fn = make_pp_loss(cfg, mesh, stages=2, microbatches=2)
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     pp_loss = jax.jit(pp_loss_fn)(params, batch)
     np.testing.assert_allclose(float(pp_loss), float(ref_loss),
                                rtol=1e-4, atol=1e-4)

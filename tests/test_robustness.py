@@ -214,7 +214,7 @@ def test_retry_limit_zero_demotes_immediately():
 
 def test_ladder_order_and_demote():
     assert LADDER[-1] == HOST_REF
-    assert demote_mode("vc_fused") == "vc_kernel_bsearch"
+    assert demote_mode("vc_kernel_bsearch") == "vc_kernel"
     assert demote_mode("vc") == HOST_REF
     assert demote_mode(HOST_REF) is None
     assert ladder_rank("tc") == ladder_rank("vc")
@@ -224,21 +224,24 @@ def test_ladder_order_and_demote():
 
 def test_bucket_ladder_sticky_ceiling():
     lad = BucketLadder(demote_after=2)
-    assert lad.clamp("vc_fused") == "vc_fused"
-    lad.note_failure("vc_fused")
-    assert lad.clamp("vc_fused") == "vc_fused"  # one strike: transient
-    lad.note_failure("vc_fused")
-    assert lad.clamp("vc_fused") == "vc_kernel_bsearch"  # two: sticky
+    top = "vc_kernel_bsearch"
+    assert lad.clamp(top) == top
+    lad.note_failure(top)
+    assert lad.clamp(top) == top  # one strike: transient
+    lad.note_failure(top)
+    assert lad.clamp(top) == "vc_kernel"  # two: sticky
     assert lad.demotions == 1
     assert lad.clamp("vc") == "vc"  # modes below the ceiling unaffected
     assert lad.clamp(HOST_REF) == HOST_REF
 
 
 def test_mode_demotion_end_to_end():
-    """Persistent vc_fused failures walk the flush down the ladder to a
-    working mode; the sticky ceiling spares later flushes the re-walk."""
-    plan = FaultPlan(seed=0, fail_modes=("vc_fused",), fail_mode_rate=1.0)
-    svc = _svc(faults=plan, mode="vc_fused", retry_limit=1,
+    """Persistent vc_kernel_bsearch failures walk the flush down the
+    ladder to a working mode; the sticky ceiling spares later flushes the
+    re-walk."""
+    plan = FaultPlan(seed=0, fail_modes=("vc_kernel_bsearch",),
+                     fail_mode_rate=1.0)
+    svc = _svc(faults=plan, mode="vc_kernel_bsearch", retry_limit=1,
                demote_after=1, max_batch=2)
     g, s, t = G.random_sparse(40, 160, seed=0)
     fut = svc.submit(g, s, t)
@@ -248,21 +251,21 @@ def test_mode_demotion_end_to_end():
     assert rb["transient_demotions"] >= 1
     assert rb["sticky_demotions"] == 1
     failures_after_first = plan.stats()["mode_failures"]
-    # second flush starts below vc_fused: no new injections possible
+    # second flush starts below vc_kernel_bsearch: no new injections
     g2, s2, t2 = G.random_sparse(40, 160, seed=1)
     fut2 = svc.submit(g2, s2, t2)
     svc.flush()
     assert fut2.result().maxflow == _want(g2, s2, t2)
     assert plan.stats()["mode_failures"] == failures_after_first
     lads = rb["ladders"]
-    assert any(e["ceiling_mode"] != "vc_fused" for e in lads.values())
+    assert any(e["ceiling_mode"] != "vc_kernel_bsearch"
+               for e in lads.values())
 
 
 def test_every_rung_fails_is_typed_terminal():
     plan = FaultPlan(seed=0, fail_mode_rate=1.0,
                      fail_modes=("vc", "tc", "vc_kernel",
-                                 "vc_kernel_bsearch", "vc_fused",
-                                 HOST_REF))
+                                 "vc_kernel_bsearch", HOST_REF))
     svc = _svc(faults=plan, retry_limit=0)
     g, s, t = G.random_sparse(40, 160, seed=0)
     fut = svc.submit(g, s, t)
