@@ -13,6 +13,7 @@ import numpy as np
 
 from repro.core.csr import Graph, ResidualCSR, build_residual
 from repro.graphs.generators import BipartiteProblem
+from repro.obs import span
 
 
 @dataclasses.dataclass(eq=False)
@@ -30,7 +31,8 @@ class _ResidualOwner:
                 raise ValueError(
                     f"problem was built from a prebuilt {built} residual "
                     f"and has no Graph to construct layout {layout!r} from")
-            r = self._residuals[layout] = build_residual(self.graph, layout)
+            with span("solve.residual", layout=layout):
+                r = self._residuals[layout] = build_residual(self.graph, layout)
         return r
 
 

@@ -12,6 +12,7 @@ import numpy as np
 from repro.core import batched
 from repro.core import pushrelabel as pr
 from repro.core.csr import ResidualCSR
+from repro.obs import span
 
 if TYPE_CHECKING:  # pragma: no cover
     from repro.core.mincut import MinCut
@@ -225,12 +226,12 @@ class WarmStartHandle:
             state = pr.PRState(
                 res=self._res, h=np.zeros(self.residual.n, np.int32),
                 e=self._e)
-            self._res = batched.as_state_dtype(
-                pr.convert_preflow_to_flow(
+            with span("solution.phase2", reference=reference):
+                res = pr.convert_preflow_to_flow(
                     self.residual, state, self.s, self.t,
                     reference=reference, use_kernel=self._use_kernel,
-                    interpret=self._interpret),
-                "corrected residual")
+                    interpret=self._interpret)
+            self._res = batched.as_state_dtype(res, "corrected residual")
             e = np.zeros(self.residual.n, batched.STATE_DTYPE)
             e[self.t] = self.maxflow
             self._e = e
@@ -343,9 +344,10 @@ class Solution:
         if self._cut is None:
             from repro.core import mincut
 
-            h, state = self._corrected_state()
-            self._cut = mincut.min_cut(h.residual, state, h.s, h.t,
-                                       corrected=True)
+            with span("solution.min_cut"):
+                h, state = self._corrected_state()
+                self._cut = mincut.min_cut(h.residual, state, h.s, h.t,
+                                           corrected=True)
         return self._cut
 
     def matching(self) -> np.ndarray:

@@ -21,6 +21,7 @@ from repro.api.solution import (Solution, SolveStats, WarmStartHandle,
 from repro.core import batched
 from repro.core import pushrelabel as pr
 from repro.core.csr import ResidualCSR
+from repro.obs import span
 
 _DISTRIBUTED_GUIDANCE = (
     "backend='distributed' needs a multi-device runtime but only one JAX "
@@ -49,11 +50,13 @@ class Solver:
 
     def solve(self, problem) -> Solution:
         opts = self.options
-        if opts.backend == "distributed":
-            return self._solve_distributed(problem)
-        if opts.backend == "batched":
-            return self.solve_many([problem])[0]
-        return self._solve_single(problem, problem.residual(opts.layout))
+        with span("solve", backend=opts.backend, mode=opts.mode):
+            if opts.backend == "distributed":
+                return self._solve_distributed(problem)
+            if opts.backend == "batched":
+                return self.solve_many([problem])[0]
+            return self._solve_single(problem,
+                                      problem.residual(opts.layout))
 
     def _solve_single(self, problem, r: ResidualCSR) -> Solution:
         opts = self.options

@@ -46,6 +46,7 @@ from repro.core import engine
 from repro.core import globalrelabel as gr
 from repro.core import pushrelabel as pr
 from repro.core.csr import ResidualCSR
+from repro.obs import scopes
 from repro.obs import solvercounters as sc
 from typing import NamedTuple
 
@@ -285,43 +286,45 @@ def _kernel_batch_step(bg: BatchedDeviceGraph, meta, state: BatchedPRState,
         act = pr.active_mask(pr.PRState(res=None, h=h, e=e), n, s, t)
         return jnp.nonzero(act, size=n, fill_value=n)[0].astype(jnp.int32)
 
-    avq = jax.vmap(one_avq)(state.h, state.e, bg.s, bg.t)  # (B, n)
-    q_valid = avq < n
+    with jax.named_scope(scopes.COMPACT):
+        avq = jax.vmap(one_avq)(state.h, state.e, bg.s, bg.t)  # (B, n)
+        q_valid = avq < n
     # the shared minh hook (batched form): ONE launch, grid (B, tiles)
-    minh, argarc = kops.min_neighbor_kernel(
-        pr.DeviceGraph(*_rows(bg)), meta, pr.PRState(*state), avq, q_valid,
-        interpret=interpret)
+    with jax.named_scope(scopes.MINH):
+        minh, argarc = kops.min_neighbor_kernel(
+            pr.DeviceGraph(*_rows(bg)), meta, pr.PRState(*state), avq,
+            q_valid, interpret=interpret)
+    with jax.named_scope(scopes.APPLY):
+        if mode == "vc_kernel_bsearch":
+            # run the shared push decision up front to assemble the batch of
+            # push arcs, then resolve every reverse arc in one bsearch launch
+            u_c = jnp.minimum(avq, n - 1)
+            arc_c = jnp.clip(argarc, 0, A - 1)
+            _, do_push = jax.vmap(pr._push_decision)(state.h, u_c, q_valid,
+                                                     minh)
+            push_arc = jnp.where(do_push, arc_c, jnp.int32(A))
+            rev_rows = bcsr_rev_search(push_arc, bg.indptr, bg.heads, bg.tails,
+                                       interpret=interpret)
 
-    if mode == "vc_kernel_bsearch":
-        # run the shared push decision up front to assemble the batch of
-        # push arcs, then resolve every reverse arc in one bsearch launch
-        u_c = jnp.minimum(avq, n - 1)
-        arc_c = jnp.clip(argarc, 0, A - 1)
-        _, do_push = jax.vmap(pr._push_decision)(state.h, u_c, q_valid,
-                                                 minh)
-        push_arc = jnp.where(do_push, arc_c, jnp.int32(A))
-        rev_rows = bcsr_rev_search(push_arc, bg.indptr, bg.heads, bg.tails,
-                                   interpret=interpret)
+            def one_apply(indptr, heads, tails, rev, res, h, e, q, qv, mh, aa,
+                          rr):
+                g = pr.DeviceGraph(indptr, heads, tails, rev)
+                st = pr._decide_apply(g, meta, pr.PRState(res, h, e), q, qv,
+                                      mh, aa, rev_fn=lambda *_: rr)
+                return st.res, st.h, st.e
 
-        def one_apply(indptr, heads, tails, rev, res, h, e, q, qv, mh, aa,
-                      rr):
-            g = pr.DeviceGraph(indptr, heads, tails, rev)
-            st = pr._decide_apply(g, meta, pr.PRState(res, h, e), q, qv,
-                                  mh, aa, rev_fn=lambda *_: rr)
-            return st.res, st.h, st.e
+            res, h, e = jax.vmap(one_apply)(*_rows(bg), *state, avq, q_valid,
+                                            minh, argarc, rev_rows)
+        else:
+            def one_apply(indptr, heads, tails, rev, res, h, e, q, qv, mh, aa):
+                g = pr.DeviceGraph(indptr, heads, tails, rev)
+                st = pr._decide_apply(g, meta, pr.PRState(res, h, e), q, qv,
+                                      mh, aa)
+                return st.res, st.h, st.e
 
-        res, h, e = jax.vmap(one_apply)(*_rows(bg), *state, avq, q_valid,
-                                        minh, argarc, rev_rows)
-    else:
-        def one_apply(indptr, heads, tails, rev, res, h, e, q, qv, mh, aa):
-            g = pr.DeviceGraph(indptr, heads, tails, rev)
-            st = pr._decide_apply(g, meta, pr.PRState(res, h, e), q, qv,
-                                  mh, aa)
-            return st.res, st.h, st.e
-
-        res, h, e = jax.vmap(one_apply)(*_rows(bg), *state, avq, q_valid,
-                                        minh, argarc)
-    return BatchedPRState(res=res, h=h, e=e)
+            res, h, e = jax.vmap(one_apply)(*_rows(bg), *state, avq, q_valid,
+                                            minh, argarc)
+        return BatchedPRState(res=res, h=h, e=e)
 
 
 @functools.partial(jax.jit,
@@ -371,68 +374,71 @@ def batched_run_cycles(bg: BatchedDeviceGraph, meta, state: BatchedPRState,
             "mode 'vc_kernel_bsearch' needs head-sorted (bcsr) segments "
             f"in every packed instance; this batch is {meta.layout!r}")
 
-    def one_nact(h, e, s, t):
-        st = pr.PRState(res=None, h=h, e=e)
-        return jnp.sum(pr.active_mask(st, meta.n, s, t))
+    # everything here but the step phases: the cap, the condition, the
+    # engine's chunk gating and carry, the telemetry counters
+    with jax.named_scope(scopes.LOOP):
+        def one_nact(h, e, s, t):
+            st = pr.PRState(res=None, h=h, e=e)
+            return jnp.sum(pr.active_mask(st, meta.n, s, t))
 
-    vnact = jax.vmap(one_nact)
+        vnact = jax.vmap(one_nact)
 
-    cap = jnp.int32(max_cycles)
-    if budget is not None:
-        cap = jnp.minimum(cap, jnp.asarray(budget, jnp.int32))
+        cap = jnp.int32(max_cycles)
+        if budget is not None:
+            cap = jnp.minimum(cap, jnp.asarray(budget, jnp.int32))
 
-    if mode in ("vc", "tc"):
-        step_fn = pr._make_step(mode)
+        if mode in ("vc", "tc"):
+            step_fn = pr._make_step(mode)
 
-        def one_step(indptr, heads, tails, rev, res, h, e, s, t):
-            g = pr.DeviceGraph(indptr, heads, tails, rev)
-            st = step_fn(g, meta, pr.PRState(res, h, e), s, t)
-            return st.res, st.h, st.e
+            def one_step(indptr, heads, tails, rev, res, h, e, s, t):
+                g = pr.DeviceGraph(indptr, heads, tails, rev)
+                st = step_fn(g, meta, pr.PRState(res, h, e), s, t)
+                return st.res, st.h, st.e
 
-        vstep = jax.vmap(one_step)
+            vstep = jax.vmap(one_step)
 
-        def step(state):
-            return BatchedPRState(*vstep(*_rows(bg), *state, bg.s, bg.t))
-    else:
-        def step(state):
-            return _kernel_batch_step(bg, meta, state, mode, interpret)
+            def step(state):
+                return BatchedPRState(*vstep(*_rows(bg), *state, bg.s, bg.t))
+        else:
+            def step(state):
+                return _kernel_batch_step(bg, meta, state, mode, interpret)
 
-    def cond(carry):
-        nact, cycle, pushed = carry[1], carry[2], carry[4]
-        return (cycle < cap) & jnp.any(nact > 0) & pushed
+        def cond(carry):
+            nact, cycle, pushed = carry[1], carry[2], carry[4]
+            return (cycle < cap) & jnp.any(nact > 0) & pushed
 
-    def body(carry):
-        state, nact, cycle, cycles_per, _ = carry[:5]
-        new_state = step(state)
-        pushed = jnp.any(new_state.e != state.e)  # any excess moved?
-        new_nact = vnact(new_state.h, new_state.e, bg.s, bg.t)
-        out = (new_state, new_nact, cycle + 1,
-               cycles_per + (nact > 0).astype(jnp.int32), pushed)
+        def body(carry):
+            state, nact, cycle, cycles_per, _ = carry[:5]
+            new_state = step(state)
+            pushed = jnp.any(new_state.e != state.e)  # any excess moved?
+            new_nact = vnact(new_state.h, new_state.e, bg.s, bg.t)
+            out = (new_state, new_nact, cycle + 1,
+                   cycles_per + (nact > 0).astype(jnp.int32), pushed)
+            if telemetry:
+                tel = carry[5]
+                # every valid active vertex pushed or relabelled exactly
+                # once; relabels are the h changes
+                relab = sc.count_relabels(state.h, new_state.h)
+                _, fr, _ = sc.cycle_stats(pr.DeviceGraph(*_rows(bg)), meta,
+                                          state, bg.s, bg.t)
+                tel = sc.CycleTelemetry(
+                    pushes=tel.pushes + nact - relab,
+                    relabels=tel.relabels + relab,
+                    active=tel.active + nact, frontier=tel.frontier + fr)
+                out = out + (tel,)
+            return out
+
+        zero = jnp.zeros(bg.batch, jnp.int32)
+        nact0 = vnact(state.h, state.e, bg.s, bg.t)
+        init = (state, nact0, jnp.int32(0), zero, jnp.bool_(True))
         if telemetry:
-            tel = carry[5]
-            # every valid active vertex pushed or relabelled exactly
-            # once; relabels are the h changes
-            relab = sc.count_relabels(state.h, new_state.h)
-            _, fr, _ = sc.cycle_stats(pr.DeviceGraph(*_rows(bg)), meta,
-                                      state, bg.s, bg.t)
-            tel = sc.CycleTelemetry(
-                pushes=tel.pushes + nact - relab,
-                relabels=tel.relabels + relab,
-                active=tel.active + nact, frontier=tel.frontier + fr)
-            out = out + (tel,)
-        return out
-
-    zero = jnp.zeros(bg.batch, jnp.int32)
-    nact0 = vnact(state.h, state.e, bg.s, bg.t)
-    init = (state, nact0, jnp.int32(0), zero, jnp.bool_(True))
-    if telemetry:
-        init = init + (sc.telemetry_init(batch=bg.batch),)
-    out = engine.run_bulk_loop(body, init, cond_fn=cond,
-                               chunk=engine.normalize_chunk(chunk,
-                                                            max_cycles))
-    if telemetry:
-        return out[0], out[3], out[5]
-    return out[0], out[3]
+            init = init + (sc.telemetry_init(batch=bg.batch),)
+        out = engine.run_bulk_loop(body, init, cond_fn=cond,
+                                   chunk=engine.normalize_chunk(chunk,
+                                                                max_cycles))
+        if telemetry:
+            return out[0], out[3], out[5]
+        return out[0], out[3]
 
 
 @functools.partial(jax.jit, static_argnames=("meta", "scan", "minh_fn"))

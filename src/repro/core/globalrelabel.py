@@ -19,6 +19,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.obs import scopes
+
 INF = jnp.int32(2**30)
 
 
@@ -116,14 +118,15 @@ def global_relabel_impl(g, meta, state, s, t, minh_fn=None):
     ``residual_distances_impl``)."""
     from repro.core import pushrelabel as pr
 
-    n = meta.n
-    dist, sweeps = residual_distances_impl(g, meta, state.res, t,
-                                           minh_fn=minh_fn)
-    h = jnp.where(dist < INF, dist, jnp.int32(n)).astype(jnp.int32)
-    h = h.at[s].set(n)
-    new_state = pr.PRState(res=state.res, h=h, e=state.e)
-    nact = jnp.sum(pr.active_mask(new_state, n, s, t))
-    return new_state, nact, sweeps
+    with jax.named_scope(scopes.GLOBAL_RELABEL):
+        n = meta.n
+        dist, sweeps = residual_distances_impl(g, meta, state.res, t,
+                                               minh_fn=minh_fn)
+        h = jnp.where(dist < INF, dist, jnp.int32(n)).astype(jnp.int32)
+        h = h.at[s].set(n)
+        new_state = pr.PRState(res=state.res, h=h, e=state.e)
+        nact = jnp.sum(pr.active_mask(new_state, n, s, t))
+        return new_state, nact, sweeps
 
 
 global_relabel = functools.partial(
@@ -141,15 +144,16 @@ def batched_global_relabel_impl(g, meta, state, s, t, minh_fn=None):
     fixpoint iteration count — the max over instances)."""
     from repro.core import pushrelabel as pr
 
-    n = meta.n
-    B = state.res.shape[0]
-    rows = jnp.arange(B)
-    dist, sweeps = batched_residual_distances_impl(g, meta, state.res, t,
-                                                   minh_fn=minh_fn)
-    h = jnp.where(dist < INF, dist, jnp.int32(n)).astype(jnp.int32)
-    h = h.at[rows, s].set(n)
-    new_state = pr.PRState(res=state.res, h=h, e=state.e)
-    v = jnp.arange(n)
-    act = ((state.e > 0) & (h < n) & (v[None, :] != s[:, None])
-           & (v[None, :] != t[:, None]))
-    return new_state, jnp.sum(act, axis=1), sweeps
+    with jax.named_scope(scopes.GLOBAL_RELABEL):
+        n = meta.n
+        B = state.res.shape[0]
+        rows = jnp.arange(B)
+        dist, sweeps = batched_residual_distances_impl(
+            g, meta, state.res, t, minh_fn=minh_fn)
+        h = jnp.where(dist < INF, dist, jnp.int32(n)).astype(jnp.int32)
+        h = h.at[rows, s].set(n)
+        new_state = pr.PRState(res=state.res, h=h, e=state.e)
+        v = jnp.arange(n)
+        act = ((state.e > 0) & (h < n) & (v[None, :] != s[:, None])
+               & (v[None, :] != t[:, None]))
+        return new_state, jnp.sum(act, axis=1), sweeps

@@ -57,6 +57,7 @@ from repro.core import engine
 from repro.core import globalrelabel as gr
 from repro.core import pushrelabel as pr
 from repro.core.csr import ResidualCSR
+from repro.obs import scopes
 
 
 def inflow(g: pr.DeviceGraph, res0: jax.Array, res: jax.Array) -> jax.Array:
@@ -144,41 +145,43 @@ def phase2_impl(g: pr.DeviceGraph, meta, res0, res, e, s, t,
     (``O(sum deg(stranded))``) for large single instances.  Results are
     bit-for-bit identical either way.
     """
-    n = meta.n
-    v = jnp.arange(n)
+    with jax.named_scope(scopes.PHASE2):
+        n = meta.n
+        v = jnp.arange(n)
 
-    def stranded(e):
-        return jnp.sum(jnp.where((v != s) & (v != t), e, 0))
+        def stranded(e):
+            return jnp.sum(jnp.where((v != s) & (v != t), e, 0))
 
-    def outer_cond(carry):
-        _, e, progressed = carry
-        return (stranded(e) > 0) & progressed
+        def outer_cond(carry):
+            _, e, progressed = carry
+            return (stranded(e) > 0) & progressed
 
-    def outer_body(carry):
-        res, e, _ = carry
-        e_before = e
-        height, _ = flow_heights_impl(g, meta, res0, res, s,
-                                      minh_fn=minh_fn)
+        def outer_body(carry):
+            res, e, _ = carry
+            e_before = e
+            height, _ = flow_heights_impl(g, meta, res0, res, s,
+                                          minh_fn=minh_fn)
 
-        def inner_body(c):
-            res, e, _ = c
-            st = _cancel_step(g, meta, res0, pr.PRState(res, height, e),
-                              s, t, minh_fn, scan)
-            return st.res, st.e, jnp.any(st.e != e)
+            def inner_body(c):
+                res, e, _ = c
+                st = _cancel_step(g, meta, res0, pr.PRState(res, height, e),
+                                  s, t, minh_fn, scan)
+                return st.res, st.e, jnp.any(st.e != e)
 
-        res, e, _ = engine.run_bulk_loop(
-            inner_body, (res, e, jnp.bool_(True)), cond_fn=lambda c: c[2])
-        # no movement under fresh heights => invariant violated: bail out
-        # instead of spinning (the host wrapper turns this into an error)
-        return res, e, jnp.any(e != e_before)
+            res, e, _ = engine.run_bulk_loop(
+                inner_body, (res, e, jnp.bool_(True)), cond_fn=lambda c: c[2])
+            # no movement under fresh heights => invariant violated: bail out
+            # instead of spinning (the host wrapper turns this into an error)
+            return res, e, jnp.any(e != e_before)
 
-    # chunk=1: one outer step is a full [heights -> cancel-to-fixpoint]
-    # pass — scanning speculative passes would be pure gated waste
-    res, e, _ = engine.run_bulk_loop(outer_body, (res, e, jnp.bool_(True)),
-                                     cond_fn=outer_cond, chunk=1)
-    leftover = stranded(e)
-    e = jnp.zeros_like(e).at[t].set(e[t])  # a flow: only the sink holds excess
-    return res, e, leftover
+        # chunk=1: one outer step is a full [heights -> cancel-to-fixpoint]
+        # pass — scanning speculative passes would be pure gated waste
+        res, e, _ = engine.run_bulk_loop(outer_body, (res, e, jnp.bool_(True)),
+                                         cond_fn=outer_cond, chunk=1)
+        leftover = stranded(e)
+        # a flow: only the sink holds excess
+        e = jnp.zeros_like(e).at[t].set(e[t])
+        return res, e, leftover
 
 
 phase2_run = functools.partial(
@@ -275,43 +278,44 @@ def batched_phase2_impl(g: pr.DeviceGraph, meta, res0, res, e, s, t,
     whenever the batch-level outer loop runs.  Returns
     ``(res, e, leftover)`` with per-row ``leftover``.
     """
-    n = meta.n
-    B = res.shape[0]
-    rows = jnp.arange(B)
-    v = jnp.arange(n)
-    inner_m = (v[None, :] != s[:, None]) & (v[None, :] != t[:, None])
+    with jax.named_scope(scopes.PHASE2):
+        n = meta.n
+        B = res.shape[0]
+        rows = jnp.arange(B)
+        v = jnp.arange(n)
+        inner_m = (v[None, :] != s[:, None]) & (v[None, :] != t[:, None])
 
-    def stranded(e):
-        return jnp.sum(jnp.where(inner_m, e, 0), axis=1)
+        def stranded(e):
+            return jnp.sum(jnp.where(inner_m, e, 0), axis=1)
 
-    def outer_cond(carry):
-        _, e, progressed = carry
-        return jnp.any((stranded(e) > 0) & progressed)
+        def outer_cond(carry):
+            _, e, progressed = carry
+            return jnp.any((stranded(e) > 0) & progressed)
 
-    def outer_body(carry):
-        res, e, _ = carry
-        e_before = e
-        height, _ = gr.batched_residual_distances_impl(
-            g, meta, batched_inflow(g, res0, res), s, minh_fn=minh_fn)
+        def outer_body(carry):
+            res, e, _ = carry
+            e_before = e
+            height, _ = gr.batched_residual_distances_impl(
+                g, meta, batched_inflow(g, res0, res), s, minh_fn=minh_fn)
 
-        def inner_body(c):
-            res, e, _ = c
-            res2, e2 = _batched_cancel_step(g, meta, res0, res, height, e,
-                                            s, t, minh_fn, scan)
-            return res2, e2, jnp.any(e2 != e)
+            def inner_body(c):
+                res, e, _ = c
+                res2, e2 = _batched_cancel_step(g, meta, res0, res, height, e,
+                                                s, t, minh_fn, scan)
+                return res2, e2, jnp.any(e2 != e)
+
+            res, e, _ = engine.run_bulk_loop(
+                inner_body, (res, e, jnp.bool_(True)), cond_fn=lambda c: c[2])
+            # a row that moved nothing under fresh heights can never move
+            # again (its state is unchanged): mark it done/stuck
+            return res, e, jnp.any(e != e_before, axis=1)
 
         res, e, _ = engine.run_bulk_loop(
-            inner_body, (res, e, jnp.bool_(True)), cond_fn=lambda c: c[2])
-        # a row that moved nothing under fresh heights can never move
-        # again (its state is unchanged): mark it done/stuck
-        return res, e, jnp.any(e != e_before, axis=1)
-
-    res, e, _ = engine.run_bulk_loop(
-        outer_body, (res, e, jnp.ones(B, bool)), cond_fn=outer_cond,
-        chunk=1)
-    leftover = stranded(e)
-    e = jnp.zeros_like(e).at[rows, t].set(e[rows, t])
-    return res, e, leftover
+            outer_body, (res, e, jnp.ones(B, bool)), cond_fn=outer_cond,
+            chunk=1)
+        leftover = stranded(e)
+        e = jnp.zeros_like(e).at[rows, t].set(e[rows, t])
+        return res, e, leftover
 
 
 def convert_preflow_to_flow_device(r: ResidualCSR, state: pr.PRState,

@@ -38,7 +38,9 @@ import numpy as np
 from repro.core import engine
 from repro.core import globalrelabel
 from repro.core.csr import ResidualCSR
+from repro.obs import scopes
 from repro.obs import solvercounters as sc
+from repro.obs import span
 
 INF = jnp.int32(2**30)
 
@@ -104,29 +106,33 @@ def _flat_frontier_minh(g: DeviceGraph, meta: GraphMeta, state: PRState,
                         avq: jax.Array, q_valid: jax.Array):
     """Flat-frontier segmented min (workload-balanced: O(sum deg(active)))."""
     n, A = meta.n, meta.num_arcs
-    avq_c = jnp.minimum(avq, n - 1)
-    deg = jnp.where(q_valid, g.indptr[avq_c + 1] - g.indptr[avq_c], 0)
-    offs = jnp.cumsum(deg)
-    starts = offs - deg
-    total = offs[-1]
-    pos = jnp.arange(A, dtype=jnp.int32)
-    row = jnp.repeat(jnp.arange(n, dtype=jnp.int32), deg,
-                     total_repeat_length=A)
-    fvalid = pos < total
-    row = jnp.where(fvalid, row, 0)
-    arc = g.indptr[avq_c[row]] + (pos - starts[row])
-    arc = jnp.clip(arc, 0, A - 1)
-    key = jnp.where(fvalid & (state.res[arc] > 0), state.h[g.heads[arc]], INF)
-    minh = jax.ops.segment_min(key, row, num_segments=n,
-                               indices_are_sorted=True)
-    cand = jnp.where(fvalid & (key == minh[row]), arc, jnp.int32(A))
-    argarc = jax.ops.segment_min(cand, row, num_segments=n,
-                                 indices_are_sorted=True)
-    # normalize the no-eligible-arc lanes (inactive row, empty segment —
-    # where segment_min returns its int32-max identity — or all keys INF)
-    # to the one (INF, A) sentinel pair every minh path returns
-    minh = jnp.where(q_valid & (minh < INF), minh, INF)
-    argarc = jnp.where(minh < INF, argarc, jnp.int32(A))
+    with jax.named_scope(scopes.FRONTIER):
+        avq_c = jnp.minimum(avq, n - 1)
+        deg = jnp.where(q_valid, g.indptr[avq_c + 1] - g.indptr[avq_c], 0)
+        offs = jnp.cumsum(deg)
+        starts = offs - deg
+        total = offs[-1]
+        pos = jnp.arange(A, dtype=jnp.int32)
+        row = jnp.repeat(jnp.arange(n, dtype=jnp.int32), deg,
+                         total_repeat_length=A)
+        fvalid = pos < total
+        row = jnp.where(fvalid, row, 0)
+        arc = g.indptr[avq_c[row]] + (pos - starts[row])
+        arc = jnp.clip(arc, 0, A - 1)
+        key = jnp.where(fvalid & (state.res[arc] > 0),
+                        state.h[g.heads[arc]], INF)
+    with jax.named_scope(scopes.MINH):
+        minh = jax.ops.segment_min(key, row, num_segments=n,
+                                   indices_are_sorted=True)
+        cand = jnp.where(fvalid & (key == minh[row]), arc, jnp.int32(A))
+        argarc = jax.ops.segment_min(cand, row, num_segments=n,
+                                     indices_are_sorted=True)
+        # normalize the no-eligible-arc lanes (inactive row, empty
+        # segment — where segment_min returns its int32-max identity — or
+        # all keys INF) to the one (INF, A) sentinel pair every minh path
+        # returns
+        minh = jnp.where(q_valid & (minh < INF), minh, INF)
+        argarc = jnp.where(minh < INF, argarc, jnp.int32(A))
     return minh, argarc
 
 
@@ -202,24 +208,31 @@ def vc_step(g: DeviceGraph, meta: GraphMeta, state: PRState, s: int, t: int,
             rev_fn: Callable | None = None) -> PRState:
     """One vertex-centric iteration (paper Alg. 2)."""
     n = meta.n
-    act = active_mask(state, n, s, t)
-    avq = jnp.nonzero(act, size=n, fill_value=n)[0].astype(jnp.int32)  # AVQ
-    q_valid = avq < n
+    with jax.named_scope(scopes.COMPACT):
+        act = active_mask(state, n, s, t)
+        avq = jnp.nonzero(act, size=n, fill_value=n)[0].astype(jnp.int32)
+        q_valid = avq < n
     if minh_fn is None:
         minh, argarc = _flat_frontier_minh(g, meta, state, avq, q_valid)
     else:
-        minh, argarc = minh_fn(g, meta, state, avq, q_valid)
-    return _decide_apply(g, meta, state, avq, q_valid, minh, argarc, rev_fn)
+        with jax.named_scope(scopes.MINH):
+            minh, argarc = minh_fn(g, meta, state, avq, q_valid)
+    with jax.named_scope(scopes.APPLY):
+        return _decide_apply(g, meta, state, avq, q_valid, minh, argarc,
+                             rev_fn)
 
 
 def tc_step(g: DeviceGraph, meta: GraphMeta, state: PRState, s: int,
             t: int) -> PRState:
     """One thread-centric iteration (paper Alg. 1 inner loop)."""
-    act = active_mask(state, meta.n, s, t)
-    minh, argarc = _tc_scan_minh(g, meta, state, act)
-    minh = jnp.where(act, minh, INF)
-    u = jnp.arange(meta.n, dtype=jnp.int32)
-    return _decide_apply(g, meta, state, u, act, minh, argarc)
+    with jax.named_scope(scopes.COMPACT):
+        act = active_mask(state, meta.n, s, t)
+    with jax.named_scope(scopes.MINH):
+        minh, argarc = _tc_scan_minh(g, meta, state, act)
+        minh = jnp.where(act, minh, INF)
+    with jax.named_scope(scopes.APPLY):
+        u = jnp.arange(meta.n, dtype=jnp.int32)
+        return _decide_apply(g, meta, state, u, act, minh, argarc)
 
 
 #: modes whose hot loops execute the Pallas kernels (the min search, and
@@ -283,48 +296,67 @@ def run_cycles(g: DeviceGraph, meta: GraphMeta, state: PRState, s: int, t: int,
     arrays, fetched by the caller once per call.  ``telemetry=False``
     traces exactly the historical two-result loop (no extra ops).
     """
-    cap = jnp.int32(max_cycles)
-    if budget is not None:
-        cap = jnp.minimum(cap, jnp.asarray(budget, jnp.int32))
+    # everything here but the step phases: the cap, the condition, the
+    # engine's chunk gating and carry, the telemetry counters
+    with jax.named_scope(scopes.LOOP):
+        cap = jnp.int32(max_cycles)
+        if budget is not None:
+            cap = jnp.minimum(cap, jnp.asarray(budget, jnp.int32))
 
-    def cond(carry):
-        state, cycle = carry[0], carry[1]
-        nact = jnp.sum(active_mask(state, meta.n, s, t))
-        return (cycle < cap) & (nact > 0)
+        def cond(carry):
+            state, cycle = carry[0], carry[1]
+            nact = jnp.sum(active_mask(state, meta.n, s, t))
+            return (cycle < cap) & (nact > 0)
 
-    step = _make_step(mode, interpret)
+        step = _make_step(mode, interpret)
 
-    if telemetry:
-        def body(carry):
-            state, cycle, tel = carry
-            nact, fr, md = sc.cycle_stats(g, meta, state, s, t)
-            new = step(g, meta, state, s, t)
-            relab = sc.count_relabels(state.h, new.h)
-            upd = functools.partial(jax.lax.dynamic_update_slice,
-                                    start_indices=(cycle,))
-            tel = sc.CycleTelemetry(
-                pushes=tel.pushes + (nact - relab),
-                relabels=tel.relabels + relab,
-                active=tel.active + nact,
-                frontier=tel.frontier + fr,
-                active_hist=upd(tel.active_hist, nact[None]),
-                frontier_hist=upd(tel.frontier_hist, fr[None]),
-                maxdeg_hist=upd(tel.maxdeg_hist, md[None]))
-            return new, cycle + 1, tel
-    else:
-        def body(carry):
-            state, cycle = carry
-            return step(g, meta, state, s, t), cycle + 1
+        if telemetry:
+            def body(carry):
+                state, cycle, tel = carry
+                nact, fr, md = sc.cycle_stats(g, meta, state, s, t)
+                new = step(g, meta, state, s, t)
+                relab = sc.count_relabels(state.h, new.h)
+                upd = functools.partial(jax.lax.dynamic_update_slice,
+                                        start_indices=(cycle,))
+                tel = sc.CycleTelemetry(
+                    pushes=tel.pushes + (nact - relab),
+                    relabels=tel.relabels + relab,
+                    active=tel.active + nact,
+                    frontier=tel.frontier + fr,
+                    active_hist=upd(tel.active_hist, nact[None]),
+                    frontier_hist=upd(tel.frontier_hist, fr[None]),
+                    maxdeg_hist=upd(tel.maxdeg_hist, md[None]))
+                return new, cycle + 1, tel
+        else:
+            def body(carry):
+                state, cycle = carry
+                return step(g, meta, state, s, t), cycle + 1
 
-    scan_chunk = engine.normalize_chunk(chunk, max_cycles)
-    if telemetry:
-        state, cycles, tel = engine.run_bulk_loop(
-            body, (state, jnp.int32(0), sc.telemetry_init(hist=max_cycles)),
-            cond_fn=cond, chunk=scan_chunk)
-        return state, cycles, tel
-    state, cycles = engine.run_bulk_loop(body, (state, jnp.int32(0)),
-                                         cond_fn=cond, chunk=scan_chunk)
-    return state, cycles
+        scan_chunk = engine.normalize_chunk(chunk, max_cycles)
+        if telemetry:
+            state, cycles, tel = engine.run_bulk_loop(
+                body,
+                (state, jnp.int32(0), sc.telemetry_init(hist=max_cycles)),
+                cond_fn=cond, chunk=scan_chunk)
+            return state, cycles, tel
+        state, cycles = engine.run_bulk_loop(body, (state, jnp.int32(0)),
+                                             cond_fn=cond, chunk=scan_chunk)
+        return state, cycles
+
+
+def solve_programs(n: int, mode: str = "vc", cycle_chunk: int | None = None,
+                   interpret: bool | None = None,
+                   scan_chunk: int | None = None) -> tuple[dict, Callable]:
+    """The static arguments of the programs a solve of an ``n``-vertex
+    residual dispatches: ``(run_cycles keywords, minh_fn)``, the keywords
+    of every ``run_cycles`` round (the round cadence is
+    ``max(32, min(1024, n))`` unless ``cycle_chunk`` pins it) and the
+    ``minh_fn`` its global relabels and phase 2 run with.  ``solve_impl``
+    dispatches with these, and ``repro.obs.scopes`` compiles the same
+    programs from them."""
+    cycles = dict(mode=mode, max_cycles=cycle_chunk or max(32, min(1024, n)),
+                  interpret=interpret, chunk=scan_chunk)
+    return cycles, engine.resolve_minh_fn(mode, interpret)
 
 
 def _empty_hist() -> np.ndarray:
@@ -391,48 +423,48 @@ def solve_impl(r: ResidualCSR, s: int, t: int, mode: str = "vc",
         idle = PRState(res=res0, h=jnp.zeros(n, jnp.int32),
                        e=jnp.zeros(n, jnp.int32))
         return SolveStats(maxflow=0, state=idle, residual=r)
-    gr_minh = None
-    if mode in KERNEL_MODES:
-        from repro.kernels import ops as kops
-
-        gr_minh = kops.min_neighbor_minh_fn(interpret)
-    chunk = cycle_chunk or max(32, min(1024, n))
+    cycle_kw, gr_minh = solve_programs(n, mode, cycle_chunk, interpret,
+                                       scan_chunk)
     state = preflow(g, meta, res0, s)
     # start from exact distance labels (global relabel heuristic)
-    state, _, sweeps = globalrelabel.global_relabel(g, meta, state, s, t,
-                                                    minh_fn=gr_minh)
-    stats = SolveStats(maxflow=0, gr_sweeps=int(sweeps))
+    with span("solve.global_relabel") as sp:
+        state, _, sweeps = globalrelabel.global_relabel(g, meta, state, s, t,
+                                                        minh_fn=gr_minh)
+        sweeps = int(sweeps)
+        sp.set_metadata(sweeps=sweeps)
+    stats = SolveStats(maxflow=0, gr_sweeps=sweeps)
     hists: list[tuple[np.ndarray, np.ndarray, np.ndarray]] = []
     remaining = max_cycles  # None = unbounded; else exact total allowance
     for _ in range(max_rounds):
         budget = None if remaining is None else jnp.int32(remaining)
-        if instrument:
-            state, cycles, tel = run_cycles(g, meta, state, s, t, mode=mode,
-                                            max_cycles=chunk,
-                                            interpret=interpret,
-                                            telemetry=True, budget=budget,
-                                            chunk=scan_chunk)
-            c = int(cycles)
-            stats.pushes += int(tel.pushes)
-            stats.relabels += int(tel.relabels)
-            hists.append((np.asarray(tel.active_hist[:c], np.int64),
-                          np.asarray(tel.frontier_hist[:c], np.int64),
-                          np.asarray(tel.maxdeg_hist[:c], np.int64)))
-        else:
-            state, cycles = run_cycles(g, meta, state, s, t, mode=mode,
-                                       max_cycles=chunk,
-                                       interpret=interpret, budget=budget,
-                                       chunk=scan_chunk)
-            c = int(cycles)
+        with span("solve.cycles") as sp:
+            if instrument:
+                state, cycles, tel = run_cycles(g, meta, state, s, t,
+                                                telemetry=True, budget=budget,
+                                                **cycle_kw)
+                c = int(cycles)
+                stats.pushes += int(tel.pushes)
+                stats.relabels += int(tel.relabels)
+                hists.append((np.asarray(tel.active_hist[:c], np.int64),
+                              np.asarray(tel.frontier_hist[:c], np.int64),
+                              np.asarray(tel.maxdeg_hist[:c], np.int64)))
+            else:
+                state, cycles = run_cycles(g, meta, state, s, t,
+                                           budget=budget, **cycle_kw)
+                c = int(cycles)
+            sp.set_metadata(cycles=c)
         stats.cycles += c
         stats.rounds += 1
         if remaining is not None:
             remaining -= c
-        state, nact, sweeps = globalrelabel.global_relabel(
-            g, meta, state, s, t, minh_fn=gr_minh)
+        with span("solve.global_relabel") as sp:
+            state, nact, sweeps = globalrelabel.global_relabel(
+                g, meta, state, s, t, minh_fn=gr_minh)
+            sweeps, nact = int(sweeps), int(nact)
+            sp.set_metadata(sweeps=sweeps)
         stats.global_relabels += 1
-        stats.gr_sweeps += int(sweeps)
-        if int(nact) == 0:
+        stats.gr_sweeps += sweeps
+        if nact == 0:
             break
         if remaining is not None and remaining <= 0:
             from repro.errors import BudgetExhausted

@@ -1,14 +1,19 @@
-"""Unified telemetry: metrics registry, span tracer, device-side solver
-counters.
+"""Unified telemetry: metrics registry, span tracer, device scopes,
+device-side solver counters.
 
-Three layers, one export surface:
+Four layers, one export surface:
 
 * ``repro.obs.metrics`` — process-global, label-scoped counters /
   gauges / histograms; ``REGISTRY.snapshot()`` is the JSON metrics dump
   every surface (``MaxflowService.telemetry_snapshot()``,
   ``serve_maxflow --metrics-out``, ``BENCH_*.json``) reads from.
-* ``repro.obs.trace`` — nested spans with Chrome ``trace_event`` export
-  (``TRACER.export(path)`` opens in Perfetto); zero-overhead disabled.
+* ``repro.obs.trace`` — nested host spans on the profiler's clock: each
+  is a ``jax.profiler.TraceAnnotation``, and an enabled ``TRACER`` also
+  records it for Chrome ``trace_event`` export (``TRACER.export(path)``
+  opens in Perfetto).
+* ``repro.obs.scopes`` — the ``jax.named_scope`` phases of the solver's
+  device programs (cycle step, global relabel, phase 2) and the map from
+  a compiled program's HLO instructions to them.
 * ``repro.obs.solvercounters`` — int32 push/relabel/active/frontier
   counters folded into the jitted cycle loops so per-cycle workload
   numbers (the paper's Fig. 3 inputs) ride the solve for free and are
@@ -25,12 +30,12 @@ import numpy as np
 
 from repro.obs.metrics import (REGISTRY, Counter, Gauge, Histogram,  # noqa: F401
                                MetricsRegistry, counter, gauge, histogram)
-from repro.obs.trace import TRACER, Tracer, span, traced  # noqa: F401
+from repro.obs.trace import TRACER, Tracer, span  # noqa: F401
 
 __all__ = [
     "REGISTRY", "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "counter", "gauge", "histogram",
-    "TRACER", "Tracer", "span", "traced",
+    "TRACER", "Tracer", "span",
     "to_jsonable",
 ]
 

@@ -1,63 +1,69 @@
-"""Nested span tracing with Chrome ``trace_event`` JSON export.
+"""Nested host spans on the profiler's clock, with Chrome ``trace_event``
+JSON export.
 
-``Tracer`` records begin/end (``ph: "B"``/``"E"``) events for the
-synchronous span tree (flush -> solve -> phase 2) plus complete
-(``ph: "X"``) events for things whose start was recorded elsewhere (a
-request's enqueue -> respond lifecycle).  ``Tracer.export(path)`` writes
-the JSON object form (``{"traceEvents": [...]}``) that
-``chrome://tracing`` and https://ui.perfetto.dev open directly.
+Every ``span()`` enters a ``jax.profiler.TraceAnnotation``: under a
+``jax.profiler`` session (``jax.profiler.trace``, TensorBoard, a
+benchmark's traced run) the span is an event on the ``/host:CPU`` plane,
+above the device ops it dispatched; with no session running it costs
+TraceMe's own "is a session active" check.
 
-Disabled (the default) the tracer is zero-overhead by construction:
-``span()`` returns a shared no-op context manager, ``@traced`` functions
-call straight through, and nothing allocates.  Enable with
-``TRACER.enable()`` (the ``serve_maxflow --trace-out`` flag does).
+``Tracer`` additionally records the spans as Chrome events while enabled
+(``TRACER.enable()``; the ``serve_maxflow --trace-out`` flag does):
+begin/end (``ph: "B"``/``"E"``) pairs for the synchronous span tree
+(flush -> solve -> phase 2) and complete (``ph: "X"``) events for things
+whose start was recorded elsewhere (a request's enqueue -> respond
+lifecycle).  Their ``ts`` is the clock the profiler stamps host events
+with, ``time.time_ns()`` in microseconds, so an exported file lines up
+with a device trace of the same run.  ``Tracer.export(path)`` writes the
+JSON object form (``{"traceEvents": [...]}``) that ``chrome://tracing``
+and https://ui.perfetto.dev open directly.
+
+A count known only inside a span (cycles fetched from the device) is
+added with ``set_metadata(**args)`` on the object the ``with`` binds:
+it lands on the profiler event and on the Chrome ``E`` event.
 """
 from __future__ import annotations
 
-import functools
 import json
 import os
 import threading
 import time
 
-__all__ = ["Tracer", "TRACER", "span", "traced"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["Tracer", "TRACER", "span"]
 
 
 def _now_us() -> float:
-    return time.perf_counter() * 1e6
-
-
-class _NullSpan:
-    """Shared do-nothing context manager for the disabled path."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_SPAN = _NullSpan()
+    """The profiler's host clock, in microseconds."""
+    return time.time_ns() * 1e-3
 
 
 class _Span:
-    """One live ``B``/``E`` pair; re-entrant use is a fresh instance."""
+    """One live span of an enabled tracer: a profiler annotation plus its
+    ``B``/``E`` pair; re-entrant use is a fresh instance."""
 
-    __slots__ = ("_tracer", "_name", "_args")
+    __slots__ = ("_tracer", "_name", "_args", "_end_args", "_ann")
 
     def __init__(self, tracer: Tracer, name: str, args: dict):
         self._tracer = tracer
         self._name = name
         self._args = args
+        self._end_args = None
+        self._ann = TraceAnnotation(name, **args)
+
+    def set_metadata(self, **args) -> None:
+        self._ann.set_metadata(**args)
+        self._end_args = args
 
     def __enter__(self):
+        self._ann.__enter__()
         self._tracer._emit("B", self._name, _now_us(), self._args)
         return self
 
     def __exit__(self, *exc):
-        self._tracer._emit("E", self._name, _now_us())
+        self._tracer._emit("E", self._name, _now_us(), self._end_args)
+        self._ann.__exit__(*exc)
         return False
 
 
@@ -99,28 +105,23 @@ class Tracer:
             self._events.append(ev)
 
     def span(self, name: str, **args):
-        """``with tracer.span("serve.flush", bucket=...):`` — emits a
-        nested ``B``/``E`` pair.  Disabled: the shared no-op manager."""
+        """``with tracer.span("serve.flush", bucket=...):`` — a profiler
+        annotation, and while enabled a nested ``B``/``E`` pair."""
         if not self.enabled:
-            return _NULL_SPAN
+            return TraceAnnotation(name, **args)
         return _Span(self, name, args)
 
     def complete(self, name: str, start_s: float, end_s: float,
                  **args) -> None:
         """A ``ph: "X"`` complete event from ``time.perf_counter()``
         endpoints — for lifecycles whose start predates the span (a
-        request's enqueue happened turns before its flush)."""
+        request's enqueue happened turns before its flush).  The endpoints
+        move to the profiler's clock here."""
         if not self.enabled:
             return
-        self._emit("X", name, start_s * 1e6, args,
+        offset_us = _now_us() - time.perf_counter() * 1e6
+        self._emit("X", name, start_s * 1e6 + offset_us, args,
                    dur_us=max(end_s - start_s, 0.0) * 1e6)
-
-    def instant(self, name: str, **args) -> None:
-        if not self.enabled:
-            return
-        ev_args = dict(args)
-        self._emit("i", name, _now_us(), ev_args)
-        self._events[-1]["s"] = "t"  # instant scope: thread
 
     # -- export -------------------------------------------------------------
 
@@ -143,23 +144,4 @@ TRACER = Tracer()
 
 def span(name: str, **args):
     """Module-level shorthand for ``TRACER.span``."""
-    if not TRACER.enabled:
-        return _NULL_SPAN
-    return _Span(TRACER, name, args)
-
-
-def traced(name: str | None = None):
-    """Decorator form: ``@traced()`` wraps the call in a span named after
-    the function (or ``name``).  Disabled tracer: straight call-through.
-    """
-    def deco(fn):
-        span_name = name or f"{fn.__module__}.{fn.__qualname__}"
-
-        @functools.wraps(fn)
-        def wrapper(*a, **kw):
-            if not TRACER.enabled:
-                return fn(*a, **kw)
-            with _Span(TRACER, span_name, {}):
-                return fn(*a, **kw)
-        return wrapper
-    return deco
+    return TRACER.span(name, **args)

@@ -25,7 +25,7 @@ from repro.core import batched
 from repro.core import pushrelabel as pr
 from repro.core.csr import build_residual
 from repro.graphs import generators as G
-from repro.obs import (REGISTRY, TRACER, span, to_jsonable, traced)
+from repro.obs import REGISTRY, TRACER, scopes, span, to_jsonable
 from repro.obs.metrics import MetricsRegistry
 from tests.conftest import random_graph
 
@@ -91,13 +91,6 @@ def test_trace_disabled_is_inert():
     with span("never", a=1):
         pass
     TRACER.complete("no", 0.0, 1.0)
-    TRACER.instant("no")
-
-    @traced()
-    def f():
-        return 7
-
-    assert f() == 7
     assert len(TRACER) == 0
 
 
@@ -107,12 +100,11 @@ def test_trace_nested_spans_export(tmp_path):
         with span("inner"):
             pass
     TRACER.complete("life", 0.001, 0.003, id="r1")
-    TRACER.instant("mark")
     path = TRACER.export(str(tmp_path / "trace.json"))
     with open(path) as f:
         data = json.load(f)
     evs = data["traceEvents"]
-    assert [e["ph"] for e in evs] == ["B", "B", "E", "E", "X", "i"]
+    assert [e["ph"] for e in evs] == ["B", "B", "E", "E", "X"]
     assert [e["name"] for e in evs[:4]] == ["outer", "inner", "inner",
                                             "outer"]  # properly nested
     assert evs[0]["args"] == {"k": "v"}
@@ -120,6 +112,139 @@ def test_trace_nested_spans_export(tmp_path):
     assert x["dur"] == pytest.approx(2000.0)  # us
     # timestamps monotonic within the span tree
     assert evs[0]["ts"] <= evs[1]["ts"] <= evs[2]["ts"] <= evs[3]["ts"]
+
+
+def test_trace_spans_on_the_profilers_clock(tmp_path):
+    """A span entered under ``jax.profiler.trace`` is an event on the
+    ``/host:CPU`` plane, within 1 ms of the enabled tracer's Chrome
+    ``ts`` for it; a count set inside the span reaches both."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    TRACER.enable()
+    with jax.profiler.trace(str(tmp_path)):
+        with span("obs.clock", k="v") as sp:
+            sp.set_metadata(cycles=7)
+    (path,) = glob.glob(f"{tmp_path}/plugins/profile/*/*.xplane.pb")
+    pd = ProfileData.from_file(path)
+    start = dict(pd.find_plane_with_name("Task Environment").stats)[
+        "profile_start_time"]
+    host = [e for line in pd.find_plane_with_name("/host:CPU").lines
+            for e in line.events if e.name == "obs.clock"]
+    assert len(host) == 1
+    begin, end = [e for e in TRACER.to_dict()["traceEvents"]
+                  if e["name"] == "obs.clock"]
+    assert abs((start + host[0].start_ns) * 1e-3 - begin["ts"]) < 1000.0
+    assert end["args"] == {"cycles": 7}
+    assert dict(host[0].stats).get("cycles") in (7, "7")
+
+
+def test_trace_complete_on_the_profilers_clock():
+    """``complete`` takes ``perf_counter`` endpoints and records them on
+    the profiler's clock (``time.time_ns``)."""
+    import time
+
+    TRACER.enable()
+    now_us = time.time_ns() * 1e-3
+    t = time.perf_counter()
+    TRACER.complete("life", t - 0.5, t)
+    (ev,) = TRACER.to_dict()["traceEvents"]
+    assert abs(ev["ts"] - (now_us - 5e5)) < 1000.0
+    assert ev["dur"] == pytest.approx(5e5)
+
+
+# -- device scopes ------------------------------------------------------------
+
+
+@pytest.mark.parametrize("op_name,phase", [
+    ("jit(run_cycles)/wbpr.cycle/loop/while/body/wbpr.cycle/minh/scatter",
+     "minh"),
+    ("jit(run_cycles)/wbpr.cycle/loop/while/body/select_n", "loop"),
+    ("jit(phase2_impl)/wbpr.phase2/while/body/wbpr.cycle/frontier/gather",
+     "phase2"),
+    ("jit(f)/wbpr.global_relabel/while/wbpr.cycle/apply/add",
+     "global_relabel"),
+    ("jit(f)/wbpr.cycle/minhx/add", None),
+    ("jit(run_cycles)/while/body/add", None),
+])
+def test_phase_of(op_name, phase):
+    """A program scope claims all inside it; else the innermost cycle
+    scope wins."""
+    assert scopes.phase_of(op_name) == phase
+
+
+def _run_computations(hlo_text):
+    """Instruction -> opcode over the computations a program runs as
+    such: its entry and what its control flow calls (the test's own
+    reading of the HLO text)."""
+    import re
+
+    comps, calls, entry, cur = {}, {}, None, None
+    for line in hlo_text.splitlines():
+        head = re.match(r"^(ENTRY )?%?([\w.\-]+) \(.*\{$", line)
+        if head:
+            cur = head.group(2)
+            comps[cur], calls[cur] = {}, []
+            entry = cur if head.group(1) else entry
+            continue
+        inst = re.match(r"^\s+(?:ROOT )?%?([\w.\-]+) = .*? ([\w\-]+)\(",
+                        line)
+        if cur and inst:
+            comps[cur][inst.group(1)] = inst.group(2)
+            calls[cur] += re.findall(
+                r"(?:body|condition|branch_computations=\{|"
+                r"true_computation|false_computation)=?%([\w.\-]+)", line)
+    out, todo = {}, [entry]
+    while todo:
+        c = todo.pop()
+        if c in comps and not set(comps[c]) <= set(out):
+            out.update(comps[c])
+            todo += calls[c]
+    return out
+
+
+def test_op_scopes_cover_the_solve_programs():
+    """Every instruction the cycle loop, the global relabel and phase 2
+    run gets a phase (containers none); the cycle program holds all five
+    step phases, the others their own."""
+    from repro.api import MaxflowProblem, SolverOptions
+
+    g, s, t = G.washington_rlg(16, 3)
+    texts = scopes.solve_hlo(MaxflowProblem(g, s, t), SolverOptions())
+    assert set(texts) == {"jit_run_cycles", "jit_global_relabel_impl",
+                          "jit_phase2_impl"}
+    want = {"jit_run_cycles": {"compact", "frontier", "minh", "apply",
+                               "loop"},
+            "jit_global_relabel_impl": {"global_relabel"},
+            "jit_phase2_impl": {"phase2"}}
+    for prog, text in texts.items():
+        got = scopes.op_scopes(text)
+        insts = _run_computations(text)
+        assert set(got) == set(insts), prog
+        for name, opcode in insts.items():
+            if opcode in scopes.CONTAINERS:
+                assert got[name] is None, (prog, name)
+            else:
+                assert got[name] in scopes.PHASES, (prog, name, opcode)
+        assert set(got.values()) - {None} == want[prog], prog
+
+
+def test_op_scopes_without_scopes_place_nothing():
+    """A program compiled without the scopes maps its containers alone,
+    so a reader finds none of its ops."""
+    import jax
+    import jax.numpy as jnp
+
+    def f(x):
+        return jax.lax.while_loop(lambda c: c[0] < 3,
+                                  lambda c: (c[0] + 1, jnp.cumsum(c[1])),
+                                  (0, x))[1]
+
+    text = jax.jit(f).lower(jnp.arange(8)).compile().as_text()
+    got = scopes.op_scopes(text)
+    assert got and set(got.values()) == {None}
 
 
 # -- to_jsonable --------------------------------------------------------------
