@@ -36,6 +36,7 @@ class SolveStats:
     pushes: int = 0
     relabels: int = 0
     gr_sweeps: int = 0  # Bellman-Ford sweeps across all global relabels
+    frontier_lanes: int = 0  # frontier lanes the executed cycles ran
     # per-cycle series, single backend only (np.int64, length == cycles)
     active_history: np.ndarray | None = None
     frontier_history: np.ndarray | None = None

@@ -76,7 +76,7 @@ class Solver:
             global_relabels=legacy.global_relabels, backend="single",
             mode=opts.mode, layout=r.layout,
             pushes=legacy.pushes, relabels=legacy.relabels,
-            gr_sweeps=legacy.gr_sweeps,
+            gr_sweeps=legacy.gr_sweeps, frontier_lanes=legacy.frontier_lanes,
             active_history=legacy.active_history if opts.telemetry else None,
             frontier_history=(legacy.frontier_history if opts.telemetry
                               else None),
@@ -135,7 +135,9 @@ class Solver:
                 pushes=(int(out.pushes[i]) if out.pushes is not None
                         else 0),
                 relabels=(int(out.relabels[i]) if out.relabels is not None
-                          else 0))
+                          else 0),
+                frontier_lanes=(int(out.frontier_lanes[i])
+                                if out.frontier_lanes is not None else 0))
             sols.append(Solution(p, int(out.maxflows[i]), stats, handle))
         return sols
 

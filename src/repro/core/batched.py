@@ -123,6 +123,7 @@ class BatchedSolveResult:
     relabels: np.ndarray | None = None
     active_sum: np.ndarray | None = None
     frontier_sum: np.ndarray | None = None
+    frontier_lanes: np.ndarray | None = None  # A_pad per live cycle
 
 
 def round_up_pow2(x: int, lo: int = 1) -> int:
@@ -357,7 +358,7 @@ def batched_run_cycles(bg: BatchedDeviceGraph, meta, state: BatchedPRState,
     Pallas tile kernels (one launch per cycle spanning the whole batch).
 
     ``telemetry=True`` (static) folds per-instance ``(B,)`` int32
-    push/relabel/active/frontier totals into the carry
+    push/relabel/active/frontier/lanes totals into the carry
     (``repro.obs.solvercounters``) and returns them as a third element —
     a ``CycleTelemetry`` with ``None`` histories.  ``telemetry=False``
     traces exactly the historical two-result loop.
@@ -424,7 +425,8 @@ def batched_run_cycles(bg: BatchedDeviceGraph, meta, state: BatchedPRState,
                 tel = sc.CycleTelemetry(
                     pushes=tel.pushes + nact - relab,
                     relabels=tel.relabels + relab,
-                    active=tel.active + nact, frontier=tel.frontier + fr)
+                    active=tel.active + nact, frontier=tel.frontier + fr,
+                    lanes=tel.lanes + jnp.where(nact > 0, meta.num_arcs, 0))
                 out = out + (tel,)
             return out
 
@@ -504,8 +506,9 @@ def batched_resolve(bg: BatchedDeviceGraph, meta, state: BatchedPRState,
 
     ``telemetry=True`` runs the cycle loops with the device-side workload
     counters and fills the result's per-instance ``pushes``/``relabels``/
-    ``active_sum``/``frontier_sum`` arrays (int64, accumulated across
-    rounds on the host — one extra fetch per round, never per cycle).
+    ``active_sum``/``frontier_sum``/``frontier_lanes`` arrays (int64,
+    accumulated across rounds on the host — one extra fetch per round,
+    never per cycle).
 
     ``max_cycles`` (optional) is an exact total bulk-synchronous cycle
     budget across rounds — threaded into every ``batched_run_cycles``
@@ -535,7 +538,8 @@ def batched_resolve(bg: BatchedDeviceGraph, meta, state: BatchedPRState,
     state, nact = relabel(state)
     cycles = np.zeros(B, np.int64)
     rounds = np.zeros(B, np.int64)
-    counts = np.zeros((4, B), np.int64)  # pushes, relabels, active, frontier
+    # pushes, relabels, active, frontier, lanes
+    counts = np.zeros((5, B), np.int64)
     grs = 1
     remaining = max_cycles  # None = unbounded; else exact total allowance
     for _ in range(max_rounds):
@@ -550,7 +554,7 @@ def batched_resolve(bg: BatchedDeviceGraph, meta, state: BatchedPRState,
                                                  telemetry=True,
                                                  budget=budget,
                                                  chunk=scan_chunk)
-            counts += np.asarray(tel[:4], np.int64)
+            counts += np.asarray(tel[:5], np.int64)
         else:
             state, cyc = batched_run_cycles(bg, meta, state, mode=mode,
                                             max_cycles=chunk,
@@ -586,7 +590,8 @@ def batched_resolve(bg: BatchedDeviceGraph, meta, state: BatchedPRState,
         pushes=counts[0] if telemetry else None,
         relabels=counts[1] if telemetry else None,
         active_sum=counts[2] if telemetry else None,
-        frontier_sum=counts[3] if telemetry else None)
+        frontier_sum=counts[3] if telemetry else None,
+        frontier_lanes=counts[4] if telemetry else None)
 
 
 def batched_solve_impl(instances: list[tuple[ResidualCSR, int, int]],

@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Callable, NamedTuple
 
 import jax
@@ -103,18 +104,27 @@ def active_mask(state: PRState, n: int, s: int, t: int) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 def _flat_frontier_minh(g: DeviceGraph, meta: GraphMeta, state: PRState,
-                        avq: jax.Array, q_valid: jax.Array):
-    """Flat-frontier segmented min (workload-balanced: O(sum deg(active)))."""
+                        avq: jax.Array, q_valid: jax.Array,
+                        lanes: int | None = None):
+    """Flat-frontier segmented min (workload-balanced: O(sum deg(active))).
+
+    The queue ``avq`` has K = ``len(avq)`` lanes and the flat frontier
+    ``lanes`` lanes (default A): the frontier of the queued vertices must
+    fit, which A always does.  The bucketed step (``vc_bucketed_step``)
+    runs it at the smallest (``lanes``, K) rung that holds the cycle's
+    live work; every other caller runs the padded (A, n) case."""
     n, A = meta.n, meta.num_arcs
+    F = A if lanes is None else lanes
+    K = avq.shape[0]
     with jax.named_scope(scopes.FRONTIER):
         avq_c = jnp.minimum(avq, n - 1)
         deg = jnp.where(q_valid, g.indptr[avq_c + 1] - g.indptr[avq_c], 0)
         offs = jnp.cumsum(deg)
         starts = offs - deg
         total = offs[-1]
-        pos = jnp.arange(A, dtype=jnp.int32)
-        row = jnp.repeat(jnp.arange(n, dtype=jnp.int32), deg,
-                         total_repeat_length=A)
+        pos = jnp.arange(F, dtype=jnp.int32)
+        row = jnp.repeat(jnp.arange(K, dtype=jnp.int32), deg,
+                         total_repeat_length=F)
         fvalid = pos < total
         row = jnp.where(fvalid, row, 0)
         arc = g.indptr[avq_c[row]] + (pos - starts[row])
@@ -122,10 +132,10 @@ def _flat_frontier_minh(g: DeviceGraph, meta: GraphMeta, state: PRState,
         key = jnp.where(fvalid & (state.res[arc] > 0),
                         state.h[g.heads[arc]], INF)
     with jax.named_scope(scopes.MINH):
-        minh = jax.ops.segment_min(key, row, num_segments=n,
+        minh = jax.ops.segment_min(key, row, num_segments=K,
                                    indices_are_sorted=True)
         cand = jnp.where(fvalid & (key == minh[row]), arc, jnp.int32(A))
-        argarc = jax.ops.segment_min(cand, row, num_segments=n,
+        argarc = jax.ops.segment_min(cand, row, num_segments=K,
                                      indices_are_sorted=True)
         # normalize the no-eligible-arc lanes (inactive row, empty
         # segment — where segment_min returns its int32-max identity — or
@@ -206,20 +216,99 @@ def _decide_apply(g: DeviceGraph, meta: GraphMeta, state: PRState,
 def vc_step(g: DeviceGraph, meta: GraphMeta, state: PRState, s: int, t: int,
             minh_fn: Callable | None = None,
             rev_fn: Callable | None = None) -> PRState:
-    """One vertex-centric iteration (paper Alg. 2)."""
+    """One vertex-centric iteration (paper Alg. 2), padded: a queue of n
+    lanes and a frontier of A."""
     n = meta.n
     with jax.named_scope(scopes.COMPACT):
         act = active_mask(state, n, s, t)
         avq = jnp.nonzero(act, size=n, fill_value=n)[0].astype(jnp.int32)
+    return _vc_search_apply(g, meta, state, avq, meta.num_arcs, minh_fn,
+                            rev_fn)
+
+
+def _vc_search_apply(g: DeviceGraph, meta: GraphMeta, state: PRState,
+                     avq: jax.Array, lanes: int,
+                     minh_fn: Callable | None = None,
+                     rev_fn: Callable | None = None) -> PRState:
+    """Min search, then push or relabel, of the queued vertices ``avq``
+    over a frontier of ``lanes``, which must hold all their arcs."""
+    n = meta.n
+    with jax.named_scope(scopes.COMPACT):
         q_valid = avq < n
     if minh_fn is None:
-        minh, argarc = _flat_frontier_minh(g, meta, state, avq, q_valid)
+        minh, argarc = _flat_frontier_minh(g, meta, state, avq, q_valid,
+                                           lanes)
     else:
         with jax.named_scope(scopes.MINH):
             minh, argarc = minh_fn(g, meta, state, avq, q_valid)
     with jax.named_scope(scopes.APPLY):
         return _decide_apply(g, meta, state, avq, q_valid, minh, argarc,
                              rev_fn)
+
+
+#: the frontier ladder (``frontier_ladder``): rungs shrink by this ratio
+#: from A down to ``_LADDER_FLOOR`` lanes, at most ``_LADDER_RUNGS`` of
+#: them, and a rung's queue holds ``_QUEUE_SLACK`` times the vertices its
+#: frontier would at the graph's mean degree
+_LADDER_RATIO = 2 ** 0.5
+_LADDER_FLOOR = 1024
+_LADDER_RUNGS = 16
+_QUEUE_SLACK = 1.5
+
+
+def frontier_ladder(n: int, num_arcs: int) -> tuple[tuple[int, int], ...]:
+    """The (frontier lanes F, queue lanes K) rungs of the bucketed step,
+    smallest first: F shrinks geometrically from A, K follows it through
+    the graph's vertices per arc, both rounded up to 128 lanes.  The top
+    rung is (A, n), the padded step, so every cycle fits one."""
+    rungs = [(num_arcs, n)]
+    f = num_arcs
+    while len(rungs) < _LADDER_RUNGS:
+        f = -(-math.ceil(f / _LADDER_RATIO) // 128) * 128
+        if f < _LADDER_FLOOR:
+            break
+        k = -(-math.ceil(_QUEUE_SLACK * f * n / num_arcs) // 128) * 128
+        rungs.append((f, min(n, k)))
+    return tuple(reversed(rungs))
+
+
+def ladder_rung(ladder: tuple[tuple[int, int], ...], nact: jax.Array,
+                ftotal: jax.Array) -> jax.Array:
+    """Index of the smallest rung of ``ladder`` whose queue holds ``nact``
+    active vertices and whose frontier holds their ``ftotal`` arcs."""
+    lanes = jnp.asarray([f for f, _ in ladder], jnp.int32)
+    queue = jnp.asarray([k for _, k in ladder], jnp.int32)
+    return jnp.maximum(jnp.sum(lanes < ftotal), jnp.sum(queue < nact))
+
+
+def vc_bucketed_step(g: DeviceGraph, meta: GraphMeta, state: PRState,
+                     s: int, t: int) -> PRState:
+    """``vc_step`` at the smallest rung of ``frontier_ladder`` that holds
+    this cycle's active vertices and their arcs, picked on the device by
+    ``lax.switch``: the same state, bit for bit, from the live work
+    instead of A frontier and n queue lanes.  Only for a step that is not
+    vmapped: under ``vmap`` the switch would run every rung."""
+    n = meta.n
+    ladder = frontier_ladder(n, meta.num_arcs)
+    with jax.named_scope(scopes.COMPACT):
+        act = active_mask(state, n, s, t)
+        # the compaction scans n whatever the queue's size, so it runs
+        # once, and each rung takes its queue's prefix
+        avq = jnp.nonzero(act, size=n, fill_value=n)[0].astype(jnp.int32)
+    if len(ladder) == 1:
+        return _vc_search_apply(g, meta, state, avq, meta.num_arcs)
+    with jax.named_scope(scopes.COMPACT):
+        deg = g.indptr[1:] - g.indptr[:-1]
+        rung = ladder_rung(ladder, jnp.sum(act),
+                           jnp.sum(jnp.where(act, deg, 0)))
+
+    def branch(lanes, queue, state, avq):
+        with jax.named_scope(scopes.COMPACT):
+            avq = avq[:queue]
+        return _vc_search_apply(g, meta, state, avq, lanes)
+
+    branches = [functools.partial(branch, f, k) for f, k in ladder]
+    return jax.lax.switch(rung, branches, state, avq)
 
 
 def tc_step(g: DeviceGraph, meta: GraphMeta, state: PRState, s: int,
@@ -291,10 +380,15 @@ def run_cycles(g: DeviceGraph, meta: GraphMeta, state: PRState, s: int, t: int,
 
     ``telemetry=True`` (static) folds the workload counters of
     ``repro.obs.solvercounters`` into the loop carry and returns a third
-    element, a ``CycleTelemetry`` with push/relabel/active/frontier
+    element, a ``CycleTelemetry`` with push/relabel/active/frontier/lanes
     totals plus per-cycle active/frontier/maxdeg histories — all device
     arrays, fetched by the caller once per call.  ``telemetry=False``
     traces exactly the historical two-result loop (no extra ops).
+
+    Mode ``'vc'`` runs ``vc_bucketed_step``: each cycle at the smallest
+    rung of ``frontier_ladder`` that holds its live work.  The other
+    modes, and the vmapped step of ``repro.core.batched``, keep the
+    padded (A, n) step.
     """
     # everything here but the step phases: the cap, the condition, the
     # engine's chunk gating and carry, the telemetry counters
@@ -308,7 +402,13 @@ def run_cycles(g: DeviceGraph, meta: GraphMeta, state: PRState, s: int, t: int,
             nact = jnp.sum(active_mask(state, meta.n, s, t))
             return (cycle < cap) & (nact > 0)
 
-        step = _make_step(mode, interpret)
+        # the one unbatched path: 'vc' runs at the live work's rung
+        if mode == "vc":
+            step = vc_bucketed_step
+            ladder = frontier_ladder(meta.n, meta.num_arcs)
+        else:
+            step = _make_step(mode, interpret)
+            ladder = ((meta.num_arcs, meta.n),)
 
         if telemetry:
             def body(carry):
@@ -316,6 +416,8 @@ def run_cycles(g: DeviceGraph, meta: GraphMeta, state: PRState, s: int, t: int,
                 nact, fr, md = sc.cycle_stats(g, meta, state, s, t)
                 new = step(g, meta, state, s, t)
                 relab = sc.count_relabels(state.h, new.h)
+                lanes = jnp.asarray([f for f, _ in ladder], jnp.int32)[
+                    ladder_rung(ladder, nact, fr)]
                 upd = functools.partial(jax.lax.dynamic_update_slice,
                                         start_indices=(cycle,))
                 tel = sc.CycleTelemetry(
@@ -323,6 +425,7 @@ def run_cycles(g: DeviceGraph, meta: GraphMeta, state: PRState, s: int, t: int,
                     relabels=tel.relabels + relab,
                     active=tel.active + nact,
                     frontier=tel.frontier + fr,
+                    lanes=tel.lanes + lanes,
                     active_hist=upd(tel.active_hist, nact[None]),
                     frontier_hist=upd(tel.frontier_hist, fr[None]),
                     maxdeg_hist=upd(tel.maxdeg_hist, md[None]))
@@ -374,6 +477,7 @@ class SolveStats:
     # int32 per dispatch, accumulated here in Python ints
     pushes: int = 0
     relabels: int = 0
+    frontier_lanes: int = 0  # frontier lanes the executed cycles ran
     # per-cycle device-counter series (telemetry solves only; empty
     # otherwise): active vertices, frontier arcs, max active degree —
     # one entry per push-relabel cycle, fetched once per round
@@ -445,6 +549,7 @@ def solve_impl(r: ResidualCSR, s: int, t: int, mode: str = "vc",
                 c = int(cycles)
                 stats.pushes += int(tel.pushes)
                 stats.relabels += int(tel.relabels)
+                stats.frontier_lanes += int(tel.lanes)
                 hists.append((np.asarray(tel.active_hist[:c], np.int64),
                               np.asarray(tel.frontier_hist[:c], np.int64),
                               np.asarray(tel.maxdeg_hist[:c], np.int64)))

@@ -20,15 +20,19 @@ exactly one push or one relabel per bulk-synchronous cycle):
   count, pushes never touch ``h``);
 * ``frontier`` — per-cycle sum of ``deg(u)`` over active ``u``: the flat
   arc frontier the vertex-centric approach scans;
+* ``lanes``    — per-cycle frontier lanes the step ran: the rung of
+  ``pushrelabel.frontier_ladder`` that the single-instance ``vc`` loop
+  picked, A (the padded frontier) in every other driver and mode, so
+  ``frontier / lanes`` is the share of frontier lanes that did work;
 * ``*_hist``   — the per-cycle series of the three quantities above plus
   the per-cycle max active degree (the thread-centric serialisation
   term in the paper's Eq. 1), single-instance drivers only.
 
 Overflow contract: counters are **int32 on device** like every other
 state array (see the dtype contract in README).  Within one dispatch the
-largest cell is ``frontier <= max_cycles * A``; drivers accumulate
-across dispatches on the host in int64, so only a single dispatch
-exceeding 2**31 scanned arcs can wrap — rechunk (lower
+largest cells are ``frontier <= lanes <= max_cycles * A``; drivers
+accumulate across dispatches on the host in int64, so only a single
+dispatch exceeding 2**31 frontier lanes can wrap — rechunk (lower
 ``global_relabel_cadence``) before that point.
 """
 from __future__ import annotations
@@ -54,6 +58,7 @@ class CycleTelemetry(NamedTuple):
     relabels: Any
     active: Any
     frontier: Any
+    lanes: Any
     active_hist: Any = None
     frontier_hist: Any = None
     maxdeg_hist: Any = None
@@ -72,7 +77,7 @@ def telemetry_init(batch: int | None = None,
             raise ValueError("per-cycle histories are single-instance only")
         hists = tuple(jnp.zeros(hist, jnp.int32) for _ in range(3))
     return CycleTelemetry(pushes=zero, relabels=zero, active=zero,
-                          frontier=zero, active_hist=hists[0],
+                          frontier=zero, lanes=zero, active_hist=hists[0],
                           frontier_hist=hists[1], maxdeg_hist=hists[2])
 
 

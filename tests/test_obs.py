@@ -194,8 +194,11 @@ def _run_computations(hlo_text):
         if cur and inst:
             comps[cur][inst.group(1)] = inst.group(2)
             calls[cur] += re.findall(
-                r"(?:body|condition|branch_computations=\{|"
-                r"true_computation|false_computation)=?%([\w.\-]+)", line)
+                r"(?:body|condition|true_computation|false_computation)"
+                r"=%([\w.\-]+)", line)
+            for group in re.findall(r"branch_computations=\{([^}]*)\}",
+                                    line):
+                calls[cur] += re.findall(r"%([\w.\-]+)", group)
     out, todo = {}, [entry]
     while todo:
         c = todo.pop()
@@ -229,6 +232,30 @@ def test_op_scopes_cover_the_solve_programs():
             else:
                 assert got[name] in scopes.PHASES, (prog, name, opcode)
         assert set(got.values()) - {None} == want[prog], prog
+
+
+def test_op_scopes_cover_the_bucketed_cycle_program():
+    """The ``vc`` cycle loop of a graph with several frontier rungs runs
+    its step in the branches of a ``conditional``: every instruction of
+    it still gets a phase, and all five step phases are there."""
+    from repro.api import MaxflowProblem, SolverOptions
+
+    g, s, t = G.washington_rlg(128, 8)
+    r = MaxflowProblem(g, s, t).residual("bcsr")
+    assert len(pr.frontier_ladder(r.n, r.num_arcs)) > 1
+    text = scopes.solve_hlo(MaxflowProblem(g, s, t),
+                            SolverOptions())["jit_run_cycles"]
+    assert " conditional(" in text
+    got = scopes.op_scopes(text)
+    insts = _run_computations(text)
+    assert set(got) == set(insts)
+    for name, opcode in insts.items():
+        if opcode in scopes.CONTAINERS:
+            assert got[name] is None, name
+        else:
+            assert got[name] in scopes.PHASES, (name, opcode)
+    assert set(got.values()) - {None} == {"compact", "frontier", "minh",
+                                          "apply", "loop"}
 
 
 def test_op_scopes_without_scopes_place_nothing():
@@ -348,6 +375,34 @@ def test_disabled_telemetry_trace_is_lean(rng):
         assert off_p == on_p, (mode, off_p, on_p)
         # retrace determinism: the disabled path is stable
         assert eqns(mode, False)[2] == off_s
+
+
+def test_frontier_lanes_counter():
+    """The lanes the executed cycles ran bound the frontier they scanned
+    and are bounded by the padded A per cycle; the bucketed ``vc`` loop
+    runs fewer, the batched driver exactly A_pad per live cycle."""
+    from repro.api import MaxflowProblem, Solver, SolverOptions
+
+    g, s, t = G.washington_rlg(128, 8)
+    problem = MaxflowProblem(g, s, t)
+    A = problem.residual("bcsr").num_arcs
+    st = Solver(SolverOptions(telemetry=True)).solve(problem).stats
+    frontier = int(st.frontier_history.sum())
+    assert 0 < frontier <= st.frontier_lanes < st.cycles * A
+    for mode in ("tc", "vc_kernel"):
+        pad = Solver(SolverOptions(mode=mode, telemetry=True)).solve(
+            problem).stats
+        assert pad.frontier_lanes == pad.cycles * A, mode
+    off = Solver().solve(problem).stats
+    assert off.frontier_lanes == 0
+    insts = []
+    for seed in range(3):
+        g, s, t = G.washington_rlg(32, 4, seed=seed)
+        insts.append((build_residual(g, "bcsr"), s, t))
+    out = batched.batched_solve_impl(insts, mode="vc", telemetry=True)
+    a_pad = out.state.res.shape[1]
+    assert (out.frontier_lanes == a_pad * out.cycles).all()
+    assert (out.frontier_sum <= out.frontier_lanes).all()
 
 
 def test_api_telemetry_stats():

@@ -93,3 +93,22 @@ def test_batched_cycle_loop_compiles_at_serving_bucket(one_chip, mode):
         bg, meta, state, mode=mode, max_cycles=128,
         interpret=False).compile()
     _check(compiled, kernel=mode != "vc")
+
+
+def test_bucketed_cycle_loop_compiles(one_chip):
+    """The unbatched ``vc`` loop, its step switched over the frontier
+    ladder's rungs (a ``conditional`` of several branches)."""
+    n, a = 2_050, 11_776
+    assert len(pr.frontier_ladder(n, a)) > 4
+    g = pr.DeviceGraph(indptr=_spec(one_chip, (n + 1,)),
+                       heads=_spec(one_chip, (a,)),
+                       tails=_spec(one_chip, (a,)),
+                       rev=_spec(one_chip, (a,)))
+    state = pr.PRState(res=_spec(one_chip, (a,)), h=_spec(one_chip, (n,)),
+                       e=_spec(one_chip, (n,)))
+    meta = pr.GraphMeta(n=n, num_arcs=a, deg_max=32, layout="bcsr")
+    compiled = pr.run_cycles.lower(g, meta, state, n - 2, n - 1, mode="vc",
+                                   max_cycles=1024,
+                                   budget=_spec(one_chip, ())).compile()
+    assert " conditional(" in compiled.as_text()
+    _check(compiled, kernel=False)
