@@ -99,7 +99,8 @@ class WarmStartHandle:
     """
 
     __slots__ = ("residual", "s", "t", "_res", "_e", "_corrected",
-                 "_corrector", "_use_kernel", "_interpret", "__weakref__")
+                 "_corrector", "_use_kernel", "_interpret", "phase2_stats",
+                 "__weakref__")
 
     def __init__(self, residual: ResidualCSR, s: int, t: int,
                  res: np.ndarray, e: np.ndarray, corrected: bool = False,
@@ -125,6 +126,10 @@ class WarmStartHandle:
         # the correction of a whole flushed microbatch until any one entry
         # first needs it.
         self._corrector = corrector
+        # phase2.Phase2Stats of this handle's own device phase 2, once
+        # arrays() ran it; None before, and for a handle corrected
+        # elsewhere (a batch dispatch) or by the host reference
+        self.phase2_stats = None
 
     @property
     def corrected(self) -> bool:
@@ -227,11 +232,14 @@ class WarmStartHandle:
             state = pr.PRState(
                 res=self._res, h=np.zeros(self.residual.n, np.int32),
                 e=self._e)
-            with span("solution.phase2", reference=reference):
-                res = pr.convert_preflow_to_flow(
+            with span("solution.phase2", reference=reference) as sp:
+                res, stats = pr.convert_preflow_to_flow_stats(
                     self.residual, state, self.s, self.t,
                     reference=reference, use_kernel=self._use_kernel,
                     interpret=self._interpret)
+                if stats is not None:
+                    sp.set_metadata(passes=stats.passes, steps=stats.steps)
+            self.phase2_stats = stats
             self._res = batched.as_state_dtype(res, "corrected residual")
             e = np.zeros(self.residual.n, batched.STATE_DTYPE)
             e[self.t] = self.maxflow
@@ -329,6 +337,16 @@ class Solution:
         return h, pr.PRState(res=res, h=np.zeros(h.residual.n, np.int32),
                              e=e)
 
+    @property
+    def phase2_stats(self):
+        """``phase2.Phase2Stats`` (height passes, cancel steps) of the
+        phase 2 this solution's views ran, or None before a view needed
+        it, on a backend that keeps no state, and where the correction
+        ran elsewhere (batched dispatch, host reference).  Zero counts
+        mean no excess was stranded."""
+        return None if self.warm_start is None \
+            else self.warm_start.phase2_stats
+
     def flows(self) -> np.ndarray:
         """Net flow per coalesced edge pair (phase-2 corrected): entry i
         is the flow carried u->v by ``residual.pair_arc[i]``."""
@@ -361,9 +379,12 @@ class Solution:
                 raise TypeError(
                     "matching() is only defined for MatchingProblem "
                     f"solutions, not {type(self.problem).__name__}")
-            h, state = self._corrected_state()
-            self._matching = bipartite.extract_matching(
-                self.problem.bipartite, h.residual, state, corrected=True)
+            with span("solution.matching") as sp:
+                h, state = self._corrected_state()
+                self._matching = bipartite.extract_matching(
+                    self.problem.bipartite, h.residual, state,
+                    corrected=True)
+                sp.set_metadata(pairs=len(self._matching))
         return self._matching
 
     def __repr__(self) -> str:

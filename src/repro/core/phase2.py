@@ -47,7 +47,7 @@ oracle and escape hatch.
 from __future__ import annotations
 
 import functools
-from typing import Callable
+from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -127,14 +127,25 @@ def _cancel_step(g: pr.DeviceGraph, meta, res0, state: pr.PRState, s, t,
     return pr.PRState(res=res, h=height, e=e)
 
 
+class Phase2Stats(NamedTuple):
+    """Iteration counts of one phase 2: ``passes`` height recomputations
+    (outer passes) and ``steps`` cancellation steps summed over them,
+    each pass's last, no-movement step included.  int32 on the device,
+    Python ints once fetched."""
+
+    passes: Any
+    steps: Any
+
+
 def phase2_impl(g: pr.DeviceGraph, meta, res0, res, e, s, t,
                 minh_fn: Callable | None = None, scan: bool = False):
     """Drain all stranded excess at once; device-side, vmap-compatible.
 
-    Returns ``(res, e, leftover)``: the corrected residual (a genuine
-    flow when ``leftover == 0``), the cleaned excess (zero everywhere but
-    ``e[t] == maxflow``), and the excess that could not be drained
-    (non-zero only if the input was not a valid preflow — callers raise).
+    Returns ``(res, e, leftover, stats)``: the corrected residual (a
+    genuine flow when ``leftover == 0``), the cleaned excess (zero
+    everywhere but ``e[t] == maxflow``), the excess that could not be
+    drained (non-zero only if the input was not a valid preflow —
+    callers raise), and the ``Phase2Stats`` counters of the loops.
     ``meta`` must be static; ``s``/``t`` may be traced scalars.
 
     ``scan=True`` (static) selects cancellation arcs with the
@@ -153,35 +164,38 @@ def phase2_impl(g: pr.DeviceGraph, meta, res0, res, e, s, t,
             return jnp.sum(jnp.where((v != s) & (v != t), e, 0))
 
         def outer_cond(carry):
-            _, e, progressed = carry
+            _, e, progressed, _, _ = carry
             return (stranded(e) > 0) & progressed
 
         def outer_body(carry):
-            res, e, _ = carry
+            res, e, _, passes, steps = carry
             e_before = e
             height, _ = flow_heights_impl(g, meta, res0, res, s,
                                           minh_fn=minh_fn)
 
             def inner_body(c):
-                res, e, _ = c
+                res, e, _, steps = c
                 st = _cancel_step(g, meta, res0, pr.PRState(res, height, e),
                                   s, t, minh_fn, scan)
-                return st.res, st.e, jnp.any(st.e != e)
+                return st.res, st.e, jnp.any(st.e != e), steps + 1
 
-            res, e, _ = engine.run_bulk_loop(
-                inner_body, (res, e, jnp.bool_(True)), cond_fn=lambda c: c[2])
+            res, e, _, steps = engine.run_bulk_loop(
+                inner_body, (res, e, jnp.bool_(True), steps),
+                cond_fn=lambda c: c[2])
             # no movement under fresh heights => invariant violated: bail out
             # instead of spinning (the host wrapper turns this into an error)
-            return res, e, jnp.any(e != e_before)
+            return res, e, jnp.any(e != e_before), passes + 1, steps
 
         # chunk=1: one outer step is a full [heights -> cancel-to-fixpoint]
         # pass — scanning speculative passes would be pure gated waste
-        res, e, _ = engine.run_bulk_loop(outer_body, (res, e, jnp.bool_(True)),
-                                         cond_fn=outer_cond, chunk=1)
+        zero = jnp.int32(0)
+        res, e, _, passes, steps = engine.run_bulk_loop(
+            outer_body, (res, e, jnp.bool_(True), zero, zero),
+            cond_fn=outer_cond, chunk=1)
         leftover = stranded(e)
         # a flow: only the sink holds excess
         e = jnp.zeros_like(e).at[t].set(e[t])
-        return res, e, leftover
+        return res, e, leftover, Phase2Stats(passes, steps)
 
 
 phase2_run = functools.partial(
@@ -276,7 +290,9 @@ def batched_phase2_impl(g: pr.DeviceGraph, meta, res0, res, e, s, t,
     ``phase2_impl`` produces: each row's trajectory depends only on its
     own arrays, and a stalled row's heights recompute to the same values
     whenever the batch-level outer loop runs.  Returns
-    ``(res, e, leftover)`` with per-row ``leftover``.
+    ``(res, e, leftover)`` with per-row ``leftover``, and no
+    ``Phase2Stats``: the loops are shared, so their counts belong to the
+    batch, not to a row.
     """
     with jax.named_scope(scopes.PHASE2):
         n = meta.n
@@ -321,21 +337,23 @@ def batched_phase2_impl(g: pr.DeviceGraph, meta, res0, res, e, s, t,
 def convert_preflow_to_flow_device(r: ResidualCSR, state: pr.PRState,
                                    s: int, t: int,
                                    minh_fn: Callable | None = None
-                                   ) -> np.ndarray:
+                                   ) -> tuple[np.ndarray, Phase2Stats]:
     """Host entry point for a single instance: run the device phase 2 and
     return the corrected ``res`` (int64 numpy, matching the host
-    reference's convention).  States with no stranded excess are returned
-    untouched without a device dispatch.  ``minh_fn`` executes the
-    cancellation-arc selection on the Pallas tile kernel (results are
-    bit-for-bit identical — both selectors pick the smallest arc index
-    attaining the minimum height)."""
+    reference's convention) with the loops' ``Phase2Stats``.  States with
+    no stranded excess are returned untouched without a device dispatch,
+    with zero counts.  ``minh_fn`` executes the cancellation-arc
+    selection on the Pallas tile kernel (results are bit-for-bit
+    identical — both selectors pick the smallest arc index attaining the
+    minimum height)."""
     e = np.asarray(state.e)
     inner = np.ones(r.n, bool)
     inner[[s, t]] = False
     if not (e[inner] > 0).any():  # already a genuine flow
-        return np.asarray(state.res, np.int64).copy()  # lint-ok: int64-state-cast
+        return (np.asarray(state.res, np.int64).copy(),  # lint-ok: int64-state-cast
+                Phase2Stats(0, 0))
     g, meta, res0 = pr.to_device(r)
-    res, _, leftover = phase2_run(
+    res, _, leftover, stats = phase2_run(
         g, meta, res0, jnp.asarray(state.res, jnp.int32),
         jnp.asarray(e, jnp.int32), jnp.int32(s), jnp.int32(t),
         minh_fn=minh_fn)
@@ -344,4 +362,5 @@ def convert_preflow_to_flow_device(r: ResidualCSR, state: pr.PRState,
             f"phase 2 could not drain {int(leftover)} units of excess back "
             "to the source — the state is not a valid preflow for this "
             "graph (excess must be flow-connected to s)")
-    return np.asarray(res, np.int64)  # lint-ok: int64-state-cast
+    return (np.asarray(res, np.int64),  # lint-ok: int64-state-cast
+            Phase2Stats(int(stats.passes), int(stats.steps)))

@@ -608,6 +608,17 @@ def convert_preflow_to_flow(r: ResidualCSR, state: PRState, s: int,
     the kernel solve modes use).  ``reference=True`` runs the original
     host-side per-excess-vertex BFS: the test oracle and escape hatch.
     """
+    return convert_preflow_to_flow_stats(r, state, s, t, reference,
+                                         use_kernel, interpret)[0]
+
+
+def convert_preflow_to_flow_stats(r: ResidualCSR, state: PRState, s: int,
+                                  t: int, reference: bool = False,
+                                  use_kernel: bool = False,
+                                  interpret: bool | None = None):
+    """``convert_preflow_to_flow`` with the device loops' counters:
+    ``(res, phase2.Phase2Stats)``, or ``(res, None)`` from the host
+    reference, which has no such loops."""
     if not reference:
         from repro.core import phase2
 
@@ -618,7 +629,7 @@ def convert_preflow_to_flow(r: ResidualCSR, state: PRState, s: int,
             minh_fn = kops.min_neighbor_minh_fn(interpret)
         return phase2.convert_preflow_to_flow_device(r, state, s, t,
                                                      minh_fn=minh_fn)
-    return _convert_preflow_to_flow_host(r, state, s, t)
+    return _convert_preflow_to_flow_host(r, state, s, t), None
 
 
 def _convert_preflow_to_flow_host(r: ResidualCSR, state: PRState, s: int,

@@ -1,4 +1,5 @@
 from repro.graphs.generators import (  # noqa: F401
+    bipartite_powerlaw,
     bipartite_random,
     genrmf,
     grid_road,

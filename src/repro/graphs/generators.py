@@ -16,6 +16,8 @@ uses are replaced by generator-matched stand-ins at CPU-feasible scale:
 * ``random_sparse`` — Erdős–Rényi-style sparse digraph.
 * ``bipartite_random`` — KONECT stand-in: L/R sets with power-law left
   degrees, plus super-source/super-sink, unit capacities (paper Table 2).
+* ``bipartite_powerlaw`` — KONECT affiliation-network stand-in: power-law
+  degrees on both sides, every vertex in at least one membership.
 
 All return ``(Graph, s, t)`` (or ``BipartiteProblem``) with int capacities.
 """
@@ -178,6 +180,56 @@ def bipartite_random(n_left: int, n_right: int, avg_deg: float = 4.0,
         for v in rng.choice(n_right, size=d, replace=False):
             edges.append((u, n_left + int(v)))
     lr = np.array(sorted(set(map(tuple, edges))), np.int64)
+    s, t = n_left + n_right, n_left + n_right + 1
+    se = np.stack([np.full(n_left, s, np.int64), np.arange(n_left)], 1)
+    te = np.stack([np.arange(n_left, n_left + n_right),
+                   np.full(n_right, t, np.int64)], 1)
+    all_e = np.concatenate([lr, se, te])
+    caps = np.ones(len(all_e), np.int64)
+    return BipartiteProblem(
+        graph=Graph(n_left + n_right + 2, all_e, caps), s=s, t=t,
+        n_left=n_left, n_right=n_right, lr_edges=lr)
+
+
+def bipartite_powerlaw(n_left: int, n_right: int, n_edges: int,
+                       left_exp: float = 0.5, right_exp: float = 0.8,
+                       seed: int = 0) -> BipartiteProblem:
+    """Affiliation graph with heavy-tailed degrees on both sides (KONECT
+    ``youtube-groupmemberships`` shape: users x groups, paper Table 2).
+
+    Each side weighs its vertices by rank, ``(rank + 1) ** -exp``, the
+    ranks under a seeded permutation so hubs land on random ids.  Every
+    vertex first gets one membership, its other end drawn by the other
+    side's weights (a vertex exists in the source only through its
+    memberships); the rest are drawn with both ends by weight, duplicates
+    dropped in draw order, until ``n_edges`` distinct memberships exist.
+    Vertices, ``s``, ``t`` and capacities (all 1) are laid out as in
+    ``bipartite_random``.  Vectorised: one draw per batch of memberships.
+    """
+    if not n_left + n_right <= n_edges <= n_left * n_right:
+        raise ValueError(f"{n_edges} memberships cannot give each of "
+                         f"{n_left} x {n_right} vertices one")
+    rng = _rng(seed)
+    wl = (np.arange(n_left) + 1.0) ** -left_exp
+    wr = (np.arange(n_right) + 1.0) ** -right_exp
+    p_left = wl[rng.permutation(n_left)] / wl.sum()
+    p_right = wr[rng.permutation(n_right)] / wr.sum()
+    u = np.concatenate([np.arange(n_left),
+                        rng.choice(n_left, size=n_right, p=p_left)])
+    v = np.concatenate([rng.choice(n_right, size=n_left, p=p_right),
+                        np.arange(n_right)])
+    keys = u * n_right + v
+    while True:
+        _, first = np.unique(keys, return_index=True)
+        keys = keys[np.sort(first)]
+        if keys.size >= n_edges:
+            break
+        k = n_edges - keys.size
+        more = (rng.choice(n_left, size=k, p=p_left) * n_right
+                + rng.choice(n_right, size=k, p=p_right))
+        keys = np.concatenate([keys, more])
+    lu, lv = np.divmod(np.sort(keys[:n_edges]), n_right)
+    lr = np.stack([lu, n_left + lv], 1).astype(np.int64)
     s, t = n_left + n_right, n_left + n_right + 1
     se = np.stack([np.full(n_left, s, np.int64), np.arange(n_left)], 1)
     te = np.stack([np.arange(n_left, n_left + n_right),

@@ -419,8 +419,8 @@ def _reroute_impl(g, meta, res0, res, b, e, s, t,
     # outbound arc of t is value regained by its returning deficit)
     e2 = jnp.maximum(b, 0).at[t].set(e[t] + b[t]).at[s].set(0)
     e2 = e2.astype(jnp.int32)
-    res, e3, excess_left = phase2.phase2_impl(g, meta, res0, res, e2, s, t,
-                                              minh_fn=minh_fn)
+    res, e3, excess_left, _ = phase2.phase2_impl(g, meta, res0, res, e2, s,
+                                                 t, minh_fn=minh_fn)
     return res, e3, deficit_left, excess_left
 
 

@@ -215,7 +215,7 @@ def _vmapped_phase2_reference(bg, meta, res0, state):
 
     def one(indptr, heads, tails, rev, r0, res, h, e, s, t):
         g = pr.DeviceGraph(indptr, heads, tails, rev)
-        return p2.phase2_impl(g, meta, r0, res, e, s, t)
+        return p2.phase2_impl(g, meta, r0, res, e, s, t)[:3]
 
     return jax.vmap(one)(bg.indptr, bg.heads, bg.tails, bg.rev, res0,
                          *state, bg.s, bg.t)

@@ -156,7 +156,7 @@ def test_batched_phase2_matches_single_device(rng):
     raw_res = np.asarray(out.state.res)
     raw_e = np.asarray(out.state.e)
     for i, (r, s, t) in enumerate(insts):
-        single = phase2.convert_preflow_to_flow_device(
+        single, _ = phase2.convert_preflow_to_flow_device(
             r, pr.PRState(res=raw_res[i, : r.num_arcs],
                           h=np.zeros(r.n, np.int32),
                           e=raw_e[i, : r.n]), s, t)
