@@ -369,8 +369,8 @@ def iter_surfaces(modes: tuple[str, ...] | None = None) -> Iterator[Surface]:
             suffix = "/kernel" if kernel else ""
             # [heights-to-fixpoint -> cancel-to-fixpoint] under a chunk=1
             # outer loop: 3 whiles, 2 scanned bodies; the kernel hook
-            # fires once per height sweep + once per cancel selection
-            launches = 2 if kernel else 0
+            # fires once per height sweep (the cancellation selects no arc)
+            launches = 1 if kernel else 0
             yield Surface(
                 name=f"phase2/{kind}{suffix}",
                 family="phase2",
@@ -386,8 +386,9 @@ def iter_surfaces(modes: tuple[str, ...] | None = None) -> Iterator[Surface]:
     # -- streaming: the pooled decrease-reroute drain -------------------
     for kernel in (False, True):
         suffix = "/kernel" if kernel else ""
-        # deficit drain + excess drain, each a phase2-shaped loop nest
-        launches = 4 if kernel else 0
+        # deficit drain (height sweep + arc selection) + excess drain
+        # (phase 2: height sweep only), each a phase2-shaped loop nest
+        launches = 3 if kernel else 0
         yield Surface(
             name=f"streaming/drain_prepared{suffix}",
             family="streaming",

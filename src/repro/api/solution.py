@@ -238,7 +238,7 @@ class WarmStartHandle:
                     reference=reference, use_kernel=self._use_kernel,
                     interpret=self._interpret)
                 if stats is not None:
-                    sp.set_metadata(passes=stats.passes, steps=stats.steps)
+                    sp.set_metadata(**stats._asdict())
             self.phase2_stats = stats
             self._res = batched.as_state_dtype(res, "corrected residual")
             e = np.zeros(self.residual.n, batched.STATE_DTYPE)

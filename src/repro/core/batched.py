@@ -443,10 +443,9 @@ def batched_run_cycles(bg: BatchedDeviceGraph, meta, state: BatchedPRState,
         return out[0], out[3]
 
 
-@functools.partial(jax.jit, static_argnames=("meta", "scan", "minh_fn"))
+@functools.partial(jax.jit, static_argnames=("meta", "minh_fn"))
 def batched_phase2(bg: BatchedDeviceGraph, meta, res0,
-                   state: BatchedPRState, scan: bool = False,
-                   minh_fn=None):
+                   state: BatchedPRState, minh_fn=None):
     """Device phase 2 (preflow -> flow) over the whole batch: one dispatch
     cancels every instance's stranded excess back to its source.
 
@@ -454,20 +453,18 @@ def batched_phase2(bg: BatchedDeviceGraph, meta, res0,
     ``pack_instances``.  Returns ``(corrected state, leftover)`` where
     ``leftover[b]`` is instance b's undrainable excess — zero for every
     valid preflow (callers raise otherwise).  Padded and trivial lanes
-    carry no excess and are no-ops.  ``scan=True`` uses the compile-lean
-    thread-centric arc selector (see ``phase2.phase2_impl``; bit-for-bit
-    identical results) — ``meta.deg_max`` must then be a true bound.
+    carry no excess and are no-ops.
 
-    The height sweeps and (``scan=False``) cancellation selections run at
-    batch level (``phase2.batched_phase2_impl``): a kernel ``minh_fn``
-    executes each as ONE batch-grid ``tile_min_neighbor`` launch instead
-    of vmapped XLA — results bit-for-bit identical either way.
+    The height sweeps run at batch level (``phase2.batched_phase2_impl``):
+    a kernel ``minh_fn`` executes each as ONE batch-grid
+    ``tile_min_neighbor`` launch instead of vmapped XLA — results
+    bit-for-bit identical either way.
     """
     from repro.core import phase2 as p2
 
     res, e, leftover = p2.batched_phase2_impl(
         pr.DeviceGraph(*_rows(bg)), meta, res0, state.res, state.e,
-        bg.s, bg.t, minh_fn=minh_fn, scan=scan)
+        bg.s, bg.t, minh_fn=minh_fn)
     return BatchedPRState(res=res, h=state.h, e=e), leftover
 
 

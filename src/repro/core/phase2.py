@@ -18,15 +18,15 @@ formulation, built from the same primitives as phase 1:
   ``globalrelabel.residual_distances`` on the pseudo-residual
   ``fin[a] = flow(rev[a])`` (an arc is traversable v<-w iff w currently
   sends flow to v), swept to fixpoint with segmented mins;
-* **cancellation**: every stranded vertex selects its minimum-height
-  inbound flow arc with the same flat-frontier segmented min/argmin the
-  vertex-centric push uses (``pushrelabel._flat_frontier_minh``, or any
-  drop-in ``minh_fn`` such as the Pallas tile kernel
-  ``repro.kernels.ops.min_neighbor_kernel``), and cancels
-  ``min(e, fin)`` units on it.  Arc ownership by the selecting vertex
-  makes the bulk-synchronous apply conflict-free: within a coalesced
-  pair only one direction can carry positive flow, so no two vertices
-  ever pick partner arcs of each other.
+* **cancellation**: every stranded vertex returns its excess along all
+  of its height-decreasing inbound flow arcs in one step, filling them
+  in arc order up to its excess (a segmented exclusive prefix of
+  ``fin``) — dense work over the arcs, with no frontier and no arc
+  selection, so a vertex holding k units over k unit arcs drains in one
+  step, not k.  Arc ownership by the draining vertex makes the
+  bulk-synchronous apply conflict-free: within a coalesced pair only
+  one direction can carry positive flow, so no arc and its partner are
+  ever both drained.
 
 Cancellations are restricted to *strictly height-decreasing* arcs, so
 excess can never cycle under a fixed height assignment; when the inner
@@ -78,67 +78,60 @@ def flow_heights_impl(g: pr.DeviceGraph, meta, res0, res, s,
                                       minh_fn=minh_fn)
 
 
-def _cancel_step(g: pr.DeviceGraph, meta, res0, state: pr.PRState, s, t,
-                 minh_fn: Callable | None = None,
-                 scan: bool = False) -> pr.PRState:
-    """One bulk-synchronous cancellation: every stranded vertex returns
-    ``min(e, fin)`` units along its minimum-height inbound flow arc,
-    provided that arc is strictly height-decreasing.  ``state.h`` holds
-    the flow heights (distance from s).
+def _drain_step(g: pr.DeviceGraph, meta, res0, res, height, e, s, t):
+    """One bulk-synchronous cancellation, dense over the arcs: every
+    stranded vertex ``u`` returns its excess along ALL of its strictly
+    height-decreasing inbound flow arcs at once, filling them in arc
+    order (``height`` holds the flow heights, distance from s).
 
-    Both selectors pick the *smallest arc index attaining the minimum
-    height*, so their results are bit-for-bit identical; they differ only
-    in execution shape (see ``phase2_impl``).
+    On each arc ``a`` of ``u``'s segment the eligible weight is
+    ``w[a] = fin[a]`` (clamped to ``e[u]``, which changes no ``d``) and
+    ``u`` cancels ``d[a] = clip(e[u] - excl[a], 0, w[a])``, ``excl`` being
+    the exclusive prefix of ``w`` within the segment.  Within a coalesced
+    pair only one direction carries positive flow, so ``a`` and
+    ``rev[a]`` are never both eligible and the dense apply has no
+    conflicts.  Returns ``(res, e, cancels)``: ``cancels`` counts the
+    arcs with ``d > 0``.
     """
-    n, A = meta.n, meta.num_arcs
-    res, height, e = state
+    n = meta.n
     v = jnp.arange(n)
     strand = (e > 0) & (v != s) & (v != t)
     fin = inflow(g, res0, res)
-    # the phase-1 min-height machinery verbatim: res := inbound flow,
-    # h := flow heights -> (min height of a flow-sending neighbour, arc)
-    pseudo = pr.PRState(res=fin, h=height, e=e)
-    if scan:
-        u_c, q_valid = v, strand
-        minh, argarc = pr._tc_scan_minh(g, meta, pseudo, strand)
-    else:
-        avq = jnp.nonzero(strand, size=n, fill_value=n)[0].astype(jnp.int32)
-        q_valid = avq < n
-        u_c = jnp.minimum(avq, n - 1)
-        if minh_fn is None:
-            minh, argarc = pr._flat_frontier_minh(g, meta, pseudo, avq,
-                                                  q_valid)
-        else:
-            minh, argarc = minh_fn(g, meta, pseudo, avq, q_valid)
-    arc_c = jnp.clip(argarc, 0, A - 1)
-    do = q_valid & (minh < height[u_c])  # strictly toward the source
-    d = jnp.where(do, jnp.minimum(e[u_c], fin[arc_c]), 0).astype(jnp.int32)
-
-    # cancel d on the inbound arc rev[arc_c]:  res[rev[arc]] += d undoes
-    # the flow, res[arc] -= d restores its partner.  arc_c lies in the
-    # selecting vertex's own segment, so the scattered indices are
-    # distinct across the batch of stranded vertices.
-    drop = jnp.int32(A)
-    res = res.at[jnp.where(do, arc_c, drop)].add(-d, mode="drop")
-    res = res.at[jnp.where(do, g.rev[arc_c], drop)].add(d, mode="drop")
-    vdrop = jnp.int32(n)
-    e = e.at[jnp.where(do, u_c, vdrop)].add(-d, mode="drop")
-    e = e.at[jnp.where(do, g.heads[arc_c], vdrop)].add(d, mode="drop")
-    return pr.PRState(res=res, h=height, e=e)
+    e_u = jnp.where(strand, e, 0)[g.tails]
+    elig = (e_u > 0) & (fin > 0) & (height[g.heads] < height[g.tails])
+    w = jnp.where(elig, jnp.minimum(fin, e_u), 0)
+    # exclusive prefix within each segment as the difference of one
+    # global int32 cumsum at the segment start: the global sum wraps past
+    # 2**31 on large stranded totals, but the difference is exact modulo
+    # 2**32, so exact while a segment's clamped prefix fits int32
+    x = jnp.cumsum(w) - w
+    start = jnp.take(x, g.indptr[:-1], mode="clip")
+    excl = x - start[g.tails]
+    d = jnp.clip(e_u - excl, 0, w)
+    # cancel d on rev[a]: res[a] -= d restores the partner, res[rev[a]]
+    # += d undoes the flow; u loses sum d, each head gains its share
+    back = d[g.rev]
+    res = res - d + back
+    e = e - jax.ops.segment_sum(d - back, g.tails, num_segments=n,
+                                indices_are_sorted=True)
+    return res, e, jnp.sum(d > 0, dtype=jnp.int32)
 
 
 class Phase2Stats(NamedTuple):
     """Iteration counts of one phase 2: ``passes`` height recomputations
-    (outer passes) and ``steps`` cancellation steps summed over them,
-    each pass's last, no-movement step included.  int32 on the device,
-    Python ints once fetched."""
+    (outer passes), ``steps`` cancellation steps summed over them, each
+    pass's last, no-movement step included, and ``cancels`` the arcs
+    that cancelled flow, summed over the steps (``cancels / steps`` is
+    how many arcs a step drains).  int32 on the device, Python ints once
+    fetched."""
 
     passes: Any
     steps: Any
+    cancels: Any
 
 
 def phase2_impl(g: pr.DeviceGraph, meta, res0, res, e, s, t,
-                minh_fn: Callable | None = None, scan: bool = False):
+                minh_fn: Callable | None = None):
     """Drain all stranded excess at once; device-side, vmap-compatible.
 
     Returns ``(res, e, leftover, stats)``: the corrected residual (a
@@ -147,14 +140,8 @@ def phase2_impl(g: pr.DeviceGraph, meta, res0, res, e, s, t,
     drained (non-zero only if the input was not a valid preflow —
     callers raise), and the ``Phase2Stats`` counters of the loops.
     ``meta`` must be static; ``s``/``t`` may be traced scalars.
-
-    ``scan=True`` (static) selects cancellation arcs with the
-    thread-centric masked scan (``O(n * deg_max)`` work, but roughly half
-    the compiled-program size and per-iteration cost of the flat
-    frontier on small padded shapes — the serving correction pool's
-    regime); the default flat frontier is workload-balanced
-    (``O(sum deg(stranded))``) for large single instances.  Results are
-    bit-for-bit identical either way.
+    ``minh_fn`` runs the height sweeps on the Pallas tile kernel; the
+    cancellation selects no arc, so it has no hook.
     """
     with jax.named_scope(scopes.PHASE2):
         n = meta.n
@@ -164,42 +151,45 @@ def phase2_impl(g: pr.DeviceGraph, meta, res0, res, e, s, t,
             return jnp.sum(jnp.where((v != s) & (v != t), e, 0))
 
         def outer_cond(carry):
-            _, e, progressed, _, _ = carry
+            _, e, progressed, _ = carry
             return (stranded(e) > 0) & progressed
 
         def outer_body(carry):
-            res, e, _, passes, steps = carry
+            res, e, _, stats = carry
             e_before = e
             height, _ = flow_heights_impl(g, meta, res0, res, s,
                                           minh_fn=minh_fn)
 
             def inner_body(c):
-                res, e, _, steps = c
-                st = _cancel_step(g, meta, res0, pr.PRState(res, height, e),
-                                  s, t, minh_fn, scan)
-                return st.res, st.e, jnp.any(st.e != e), steps + 1
+                res, e, _, steps, cancels = c
+                res2, e2, k = _drain_step(g, meta, res0, res, height, e,
+                                          s, t)
+                return res2, e2, jnp.any(e2 != e), steps + 1, cancels + k
 
-            res, e, _, steps = engine.run_bulk_loop(
-                inner_body, (res, e, jnp.bool_(True), steps),
+            res, e, _, steps, cancels = engine.run_bulk_loop(
+                inner_body, (res, e, jnp.bool_(True), stats.steps,
+                             stats.cancels),
                 cond_fn=lambda c: c[2])
             # no movement under fresh heights => invariant violated: bail out
             # instead of spinning (the host wrapper turns this into an error)
-            return res, e, jnp.any(e != e_before), passes + 1, steps
+            return (res, e, jnp.any(e != e_before),
+                    Phase2Stats(stats.passes + 1, steps, cancels))
 
         # chunk=1: one outer step is a full [heights -> cancel-to-fixpoint]
         # pass — scanning speculative passes would be pure gated waste
         zero = jnp.int32(0)
-        res, e, _, passes, steps = engine.run_bulk_loop(
-            outer_body, (res, e, jnp.bool_(True), zero, zero),
+        res, e, _, stats = engine.run_bulk_loop(
+            outer_body, (res, e, jnp.bool_(True),
+                         Phase2Stats(zero, zero, zero)),
             cond_fn=outer_cond, chunk=1)
         leftover = stranded(e)
         # a flow: only the sink holds excess
         e = jnp.zeros_like(e).at[t].set(e[t])
-        return res, e, leftover, Phase2Stats(passes, steps)
+        return res, e, leftover, stats
 
 
 phase2_run = functools.partial(
-    jax.jit, static_argnames=("meta", "minh_fn", "scan"))(phase2_impl)
+    jax.jit, static_argnames=("meta", "minh_fn"))(phase2_impl)
 
 
 # ---------------------------------------------------------------------------
@@ -211,79 +201,25 @@ def batched_inflow(g: pr.DeviceGraph, res0, res):
     return jnp.take_along_axis(res0 - res, g.rev, axis=1)
 
 
-def _batched_cancel_step(g: pr.DeviceGraph, meta, res0, res, height, e,
-                         s, t, minh_fn: Callable | None = None,
-                         scan: bool = False):
-    """Batch-level ``_cancel_step``: one bulk-synchronous cancellation for
-    every instance at once.  Under a kernel ``minh_fn`` the selection is
-    ONE ``tile_min_neighbor`` launch with grid ``(B, tiles)``; otherwise
-    the per-row selectors are vmapped (bit-for-bit the same choices —
-    all paths pick the smallest arc index attaining the minimum)."""
-    n, A = meta.n, meta.num_arcs
-    B = res.shape[0]
-    v = jnp.arange(n, dtype=jnp.int32)
-    strand = ((e > 0) & (v[None, :] != s[:, None])
-              & (v[None, :] != t[:, None]))
-    fin = batched_inflow(g, res0, res)
-    if scan:
-        u_c = jnp.broadcast_to(v, (B, n))
-        q_valid = strand
+def _batched_drain_step(g: pr.DeviceGraph, meta, res0, res, height, e,
+                        s, t):
+    """Batch-level ``_drain_step``: the same rule, one row per vmapped
+    lane, so every row moves exactly what the per-instance step moves."""
 
-        def one_scan(indptr, heads, tails, rev, fin_r, h_r, e_r, act_r):
-            gr_ = pr.DeviceGraph(indptr, heads, tails, rev)
-            return pr._tc_scan_minh(gr_, meta, pr.PRState(fin_r, h_r, e_r),
-                                    act_r)
+    def one(indptr, heads, tails, rev, r0, res_r, h_r, e_r, s_r, t_r):
+        gr_ = pr.DeviceGraph(indptr, heads, tails, rev)
+        return _drain_step(gr_, meta, r0, res_r, h_r, e_r, s_r, t_r)[:2]
 
-        minh, argarc = jax.vmap(one_scan)(g.indptr, g.heads, g.tails,
-                                          g.rev, fin, height, e, strand)
-    else:
-        avq = jax.vmap(
-            lambda m: jnp.nonzero(m, size=n,
-                                  fill_value=n)[0].astype(jnp.int32))(strand)
-        q_valid = avq < n
-        u_c = jnp.minimum(avq, n - 1)
-        pseudo = pr.PRState(res=fin, h=height, e=e)
-        if minh_fn is None:
-            def one_flat(indptr, heads, tails, rev, fin_r, h_r, e_r, q, qv):
-                gr_ = pr.DeviceGraph(indptr, heads, tails, rev)
-                return pr._flat_frontier_minh(
-                    gr_, meta, pr.PRState(fin_r, h_r, e_r), q, qv)
-
-            minh, argarc = jax.vmap(one_flat)(g.indptr, g.heads, g.tails,
-                                              g.rev, fin, height, e, avq,
-                                              q_valid)
-        else:
-            minh, argarc = minh_fn(g, meta, pseudo, avq, q_valid)
-    arc_c = jnp.clip(argarc, 0, A - 1)
-    hh = jnp.take_along_axis(height, u_c, axis=1)
-    do = q_valid & (minh < hh)  # strictly toward the source
-    d = jnp.where(do, jnp.minimum(jnp.take_along_axis(e, u_c, axis=1),
-                                  jnp.take_along_axis(fin, arc_c, axis=1)),
-                  0).astype(jnp.int32)
-
-    def one_apply(res_r, e_r, do_r, arc_r, d_r, u_r, heads_r, rev_r):
-        drop = jnp.int32(A)
-        res_r = res_r.at[jnp.where(do_r, arc_r, drop)].add(-d_r,
-                                                           mode="drop")
-        res_r = res_r.at[jnp.where(do_r, rev_r[arc_r], drop)].add(
-            d_r, mode="drop")
-        vdrop = jnp.int32(n)
-        e_r = e_r.at[jnp.where(do_r, u_r, vdrop)].add(-d_r, mode="drop")
-        e_r = e_r.at[jnp.where(do_r, heads_r[arc_r], vdrop)].add(
-            d_r, mode="drop")
-        return res_r, e_r
-
-    res, e = jax.vmap(one_apply)(res, e, do, arc_c, d, u_c, g.heads, g.rev)
-    return res, e
+    return jax.vmap(one)(g.indptr, g.heads, g.tails, g.rev, res0, res,
+                         height, e, s, t)
 
 
 def batched_phase2_impl(g: pr.DeviceGraph, meta, res0, res, e, s, t,
-                        minh_fn: Callable | None = None,
-                        scan: bool = False):
+                        minh_fn: Callable | None = None):
     """Batch-level :func:`phase2_impl`: drain every instance's stranded
-    excess with shared [heights -> cancel-to-fixpoint] loops — the height
-    sweeps and (``scan=False``) cancellation selections each execute as
-    ONE batch-grid launch per step under a kernel ``minh_fn``.
+    excess with shared [heights -> cancel-to-fixpoint] loops — each
+    height sweep executes as ONE batch-grid launch per step under a
+    kernel ``minh_fn``.
 
     Rows that finish (or stall) earlier are fixpoints of both loops, so
     the result is bit-for-bit what vmapping the per-instance
@@ -316,8 +252,8 @@ def batched_phase2_impl(g: pr.DeviceGraph, meta, res0, res, e, s, t,
 
             def inner_body(c):
                 res, e, _ = c
-                res2, e2 = _batched_cancel_step(g, meta, res0, res, height, e,
-                                                s, t, minh_fn, scan)
+                res2, e2 = _batched_drain_step(g, meta, res0, res, height,
+                                               e, s, t)
                 return res2, e2, jnp.any(e2 != e)
 
             res, e, _ = engine.run_bulk_loop(
@@ -342,16 +278,14 @@ def convert_preflow_to_flow_device(r: ResidualCSR, state: pr.PRState,
     return the corrected ``res`` (int64 numpy, matching the host
     reference's convention) with the loops' ``Phase2Stats``.  States with
     no stranded excess are returned untouched without a device dispatch,
-    with zero counts.  ``minh_fn`` executes the cancellation-arc
-    selection on the Pallas tile kernel (results are bit-for-bit
-    identical — both selectors pick the smallest arc index attaining the
-    minimum height)."""
+    with zero counts.  ``minh_fn`` executes the height sweeps on the
+    Pallas tile kernel (results are bit-for-bit identical)."""
     e = np.asarray(state.e)
     inner = np.ones(r.n, bool)
     inner[[s, t]] = False
     if not (e[inner] > 0).any():  # already a genuine flow
         return (np.asarray(state.res, np.int64).copy(),  # lint-ok: int64-state-cast
-                Phase2Stats(0, 0))
+                Phase2Stats(0, 0, 0))
     g, meta, res0 = pr.to_device(r)
     res, _, leftover, stats = phase2_run(
         g, meta, res0, jnp.asarray(state.res, jnp.int32),
@@ -363,4 +297,4 @@ def convert_preflow_to_flow_device(r: ResidualCSR, state: pr.PRState,
             "to the source — the state is not a valid preflow for this "
             "graph (excess must be flow-connected to s)")
     return (np.asarray(res, np.int64),  # lint-ok: int64-state-cast
-            Phase2Stats(int(stats.passes), int(stats.steps)))
+            Phase2Stats(*(int(x) for x in stats)))

@@ -130,9 +130,9 @@ class ServiceConfig:
     executable_entries: int = 256
     pad_full_batch: bool = True  # one executable per bucket (see queueing)
     mode_trials: int = 1  # clean samples per candidate before pinning
-    # pooled phase-2 sweeps: None resolves by mode (a fixed kernel mode
-    # corrects on the batch-grid tile kernel; 'auto'/'vc'/'tc' keep the
-    # compile-lean XLA scan selector), an explicit bool overrides
+    # pooled phase-2 height sweeps: None resolves by mode (a fixed kernel
+    # mode sweeps on the batch-grid tile kernel; 'auto'/'vc'/'tc' keep
+    # XLA's segment_min), an explicit bool overrides
     phase2_kernel: bool | None = None
     # fold device-side workload counters (pushes/relabels/active/frontier)
     # into every solve dispatch.  False compiles the exact pre-telemetry
@@ -839,8 +839,8 @@ class MaxflowService:
         out-of-band, or admitted after an eviction reset) still fits; a
         service that never flushed lazily initialises the base from the
         group itself.  ``ServiceConfig.resolve_phase2_kernel`` decides
-        whether the pooled sweeps run on the batch-grid tile kernel or
-        the compile-lean XLA scan selector (identical results).
+        whether the pooled height sweeps run on the batch-grid tile
+        kernel or on XLA's ``segment_min`` (identical results).
         """
         t0 = time.perf_counter()
         if self.config.validate_handles:
@@ -903,7 +903,7 @@ class MaxflowService:
                     minh_fn=kops.min_neighbor_minh_fn(None))
             else:
                 corrected, leftover = batched.batched_phase2(
-                    bg, meta, res0, state, scan=True)
+                    bg, meta, res0, state)
             cres = np.asarray(corrected.res)
             ce = np.asarray(corrected.e)
             batched.check_phase2_leftover(leftover)

@@ -7,10 +7,11 @@ arc, which leaves a pseudo-flow with a signed per-vertex imbalance
 ``b``: ``+o`` of excess at ``u`` (units it was forwarding that no longer
 fit) and ``-o`` of deficit at ``v`` (units it was passing on that no
 longer arrive).  Both imbalances are drained on-device with the same
-height-bounded bulk-synchronous cancellation the phase-2 preflow->flow
-conversion uses (``repro.core.phase2``), built on the flat-frontier
-segmented min with the shared ``minh_fn`` hook — kernel modes run the
-reroute on the Pallas tile kernel unchanged:
+height-bounded bulk-synchronous cancellation as the phase-2
+preflow->flow conversion (``repro.core.phase2``); the deficit drain
+selects one arc a vertex a step with the flat-frontier segmented min
+behind the shared ``minh_fn`` hook — kernel modes run the reroute's
+sweeps and selections on the Pallas tile kernel unchanged:
 
 * **deficit first**, along *outbound* flow arcs toward the multi-sink
   set ``{t} ∪ {vertices with excess}``.  Heights are the exact distance
@@ -333,11 +334,11 @@ def _deficit_cancel_step(g, meta, res0, res, height, b, s, t,
                          minh_fn: Callable | None = None):
     """One bulk-synchronous deficit cancellation: every deficit vertex
     retires ``min(-b, flow)`` units of its minimum-height *outbound* flow
-    arc, provided that arc steps strictly toward the sink set.  The exact
-    mirror of ``phase2._cancel_step`` (which drains excess along inbound
-    flow arcs): arc ownership by the selecting vertex keeps the scatter
-    conflict-free — within a coalesced pair only one direction can carry
-    positive flow."""
+    arc, provided that arc steps strictly toward the sink set.  The
+    one-arc-a-step counterpart of ``phase2._drain_step`` (which drains
+    excess along all its inbound flow arcs at once): arc ownership by the
+    selecting vertex keeps the scatter conflict-free — within a coalesced
+    pair only one direction can carry positive flow."""
     n, A = meta.n, meta.num_arcs
     v = jnp.arange(n)
     strand = (b < 0) & (v != s) & (v != t)
@@ -435,11 +436,10 @@ _reroute_run = functools.partial(
 def _batched_deficit_cancel_step(g, meta, res0, res, height, b, s, t,
                                  minh_fn: Callable | None = None):
     """Batch-level :func:`_deficit_cancel_step` over stacked ``(B, ...)``
-    rows — the exact mirror of ``phase2._batched_cancel_step`` with
-    outbound flow (``fout = res0 - res``) as the pseudo-residual and the
-    negative imbalance as the excess.  Under a kernel ``minh_fn`` the
-    selection is ONE batch-grid launch; otherwise the per-row flat
-    frontier is vmapped (same choices bit-for-bit)."""
+    rows, with outbound flow (``fout = res0 - res``) as the
+    pseudo-residual and the negative imbalance as the excess.  Under a
+    kernel ``minh_fn`` the selection is ONE batch-grid launch; otherwise
+    the per-row flat frontier is vmapped (same choices bit-for-bit)."""
     n, A = meta.n, meta.num_arcs
     v = jnp.arange(n, dtype=jnp.int32)
     strand = ((b < 0) & (v[None, :] != s[:, None])

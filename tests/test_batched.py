@@ -256,17 +256,18 @@ def test_batched_global_relabel_matches_vmapped(layout, use_kernel, rng):
 @pytest.mark.parametrize("selector", ["flat", "scan", "kernel"])
 def test_batched_phase2_matches_vmapped(layout, selector, rng):
     """The batch-level phase 2 equals the vmapped per-instance
-    decomposition bit-for-bit across selectors (flat XLA, thread-centric
-    scan, batch-grid kernel), padded dummy lanes included."""
+    decomposition bit-for-bit, padded dummy lanes included: with XLA
+    height sweeps ('flat'), on the preflow of the thread-centric scan
+    mode ('scan': other stranded excess, the same drain), and with the
+    height sweeps on the batch-grid kernel ('kernel')."""
     bg, meta, res0, triv = _packed_with_padding(rng, layout)
     state = batched.batched_preflow(bg, meta, res0)
-    out = batched.batched_resolve(bg, meta, state, trivial=triv)
+    out = batched.batched_resolve(bg, meta, state, trivial=triv,
+                                  mode="tc" if selector == "scan" else "vc")
     want_res, want_e, want_left = _vmapped_phase2_reference(
         bg, meta, res0, out.state)
     kw = {}
-    if selector == "scan":
-        kw["scan"] = True
-    elif selector == "kernel":
+    if selector == "kernel":
         from repro.kernels import ops as kops
         kw["minh_fn"] = kops.min_neighbor_minh_fn(None)
     got, left = batched.batched_phase2(bg, meta, res0, out.state, **kw)
@@ -279,8 +280,8 @@ def test_batched_phase2_matches_vmapped(layout, selector, rng):
 def test_batched_sweeps_one_pallas_call_per_step(rng):
     """The jaxpr-level contract: under the kernel hook the pooled sweeps
     lower to exactly ONE batch-grid ``pallas_call`` per sweep step —
-    one in the global-relabel loop body, two for phase 2 (height sweep +
-    cancellation selection) — and to zero without it."""
+    one in the global-relabel loop body, one for phase 2 (its height
+    sweep; the cancellation selects no arc) — and to zero without it."""
     from repro.analysis import ir
     from repro.kernels import ops as kops
 
@@ -300,7 +301,7 @@ def test_batched_sweeps_one_pallas_call_per_step(rng):
         lambda st: batched.batched_phase2(bg, meta, res0, st)) == 0
     assert pallas_calls(
         lambda st: batched.batched_phase2(
-            bg, meta, res0, st, minh_fn=hook)) == 2
+            bg, meta, res0, st, minh_fn=hook)) == 1
 
 
 @pytest.mark.parametrize("mode,layout", [

@@ -170,21 +170,108 @@ def test_batched_phase2_matches_single_device(rng):
 
 
 def test_scan_selector_bit_for_bit(rng):
-    """The compile-lean thread-centric selector (``scan=True``, used by
-    the serving correction pool) must produce exactly the flat-frontier
-    result: both pick the smallest arc index attaining the minimum
-    height, so the corrections are bit-for-bit identical."""
+    """The cancellation selects no arc any more, so the serving pool's
+    correction (which once asked for the thread-centric scan selector)
+    runs the same drain as every other caller.  On preflows of the
+    thread-centric scan mode ('tc'), the pooled correction equals the
+    single-instance device path bit-for-bit, row by row, and both are
+    flows of the solver's value."""
     graphs = [_random_messy_graph(rng, n_lo=5, n_hi=16) for _ in range(4)]
     insts = [(build_residual(g, "bcsr"), 0, g.n - 1) for g in graphs]
     bg, meta, res0, trivial = batched.pack_instances(insts)
     state = batched.batched_preflow(bg, meta, res0)
-    out = batched.batched_resolve(bg, meta, state, trivial=trivial)
-    flat, l1 = batched.batched_phase2(bg, meta, res0, out.state, scan=False)
-    scan, l2 = batched.batched_phase2(bg, meta, res0, out.state, scan=True)
-    batched.check_phase2_leftover(l1)
-    batched.check_phase2_leftover(l2)
-    np.testing.assert_array_equal(np.asarray(flat.res), np.asarray(scan.res))
-    np.testing.assert_array_equal(np.asarray(flat.e), np.asarray(scan.e))
+    out = batched.batched_resolve(bg, meta, state, trivial=trivial,
+                                  mode="tc")
+    pooled, left = batched.batched_phase2(bg, meta, res0, out.state)
+    batched.check_phase2_leftover(left)
+    raw_res = np.asarray(out.state.res)
+    raw_e = np.asarray(out.state.e)
+    for i, (r, s, t) in enumerate(insts):
+        single, _ = phase2.convert_preflow_to_flow_device(
+            r, pr.PRState(res=raw_res[i, : r.num_arcs],
+                          h=np.zeros(r.n, np.int32),
+                          e=raw_e[i, : r.n]), s, t)
+        np.testing.assert_array_equal(
+            np.asarray(pooled.res)[i, : r.num_arcs], single)
+        _assert_valid_flow(r, single, s, t, int(out.maxflows[i]))
+
+
+def _hand_preflow(r, flows, excess):
+    """A preflow state set by hand: ``flows`` maps (u, v) to the flow on
+    the arc u->v, ``excess`` maps vertices to their excess."""
+    res = np.asarray(r.res0, np.int64).copy()
+    for (u, v), f in flows.items():
+        a = batched.find_arc(r, u, v)
+        res[a] -= f
+        res[r.rev[a]] += f
+    e = np.zeros(r.n, np.int64)
+    for v, x in excess.items():
+        e[v] = x
+    return pr.PRState(res=batched.as_state_dtype(res),
+                      h=np.zeros(r.n, np.int32),
+                      e=batched.as_state_dtype(e))
+
+
+def _hub(k, caps):
+    """s -> a_i -> h -> t with ``caps[i]`` on both arcs of a_i and a unit
+    arc h -> t: s = 0, a_i = 1 + i, h = k + 1, t = k + 2."""
+    s, h, t = 0, k + 1, k + 2
+    edges = [(s, 1 + i) for i in range(k)] + [(1 + i, h) for i in range(k)]
+    edges.append((h, t))
+    cap = np.array(list(caps) * 2 + [1], np.int64)
+    return Graph(k + 3, np.array(edges, np.int64), cap), s, h, t
+
+
+@pytest.mark.parametrize("layout", ["bcsr", "rcsr"])
+def test_hub_drains_in_one_step(layout):
+    """A hub holding k - 1 stranded units over k unit inbound arcs drains
+    in one pass of depth + 1 steps (heights 2: hub -> users -> s, and
+    the step that finds nothing to move), not one unit a step, and the
+    result is a flow of value 1."""
+    k = 40
+    g, s, h, t = _hub(k, [1] * k)
+    r = build_residual(g, layout)
+    flows = {(s, 1 + i): 1 for i in range(k)}
+    flows.update({(1 + i, h): 1 for i in range(k)})
+    flows[(h, t)] = 1
+    state = _hand_preflow(r, flows, {h: k - 1, t: 1})
+    res, stats = phase2.convert_preflow_to_flow_device(r, state, s, t)
+    _assert_valid_flow(r, res, s, t, 1)
+    assert stats.passes == 1
+    assert stats.steps <= 2 + 1
+    # k - 1 units cross one user arc each, then one source arc each
+    assert stats.cancels == 2 * (k - 1)
+
+
+def test_large_capacities_drain_exactly():
+    """Capacities near 2**30: each hub holds nearly 2**31 units, so the
+    global prefix sum of the drain wraps past int32 several times over,
+    and the per-segment difference still returns every unit exactly."""
+    hubs, k = 3, 2
+    caps = [2**30 - 1, 2**30 - 2]
+    # hubs side by side behind one source and one sink
+    n = 2 + hubs * (k + 1)
+    s, t = 0, n - 1
+    edges, cap, flows, excess = [], [], {}, {t: 0}
+    for j in range(hubs):
+        base = 1 + j * (k + 1)
+        hub = base + k
+        for i in range(k):
+            edges += [(s, base + i), (base + i, hub)]
+            cap += [caps[i], caps[i]]
+            flows[(s, base + i)] = flows[(base + i, hub)] = caps[i]
+        edges.append((hub, t))
+        cap.append(1)
+        flows[(hub, t)] = 1
+        excess[hub] = sum(caps) - 1
+        excess[t] += 1
+    g = Graph(n, np.array(edges, np.int64), np.array(cap, np.int64))
+    r = build_residual(g, "bcsr")
+    state = _hand_preflow(r, flows, excess)
+    assert sum(excess.values()) - hubs > 2**32  # the global sum wraps
+    res, stats = phase2.convert_preflow_to_flow_device(r, state, s, t)
+    _assert_valid_flow(r, res, s, t, hubs)
+    assert stats.passes == 1 and stats.steps <= 3
 
 
 def test_batched_phase2_flags_invalid_lane():

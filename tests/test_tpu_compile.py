@@ -112,3 +112,22 @@ def test_bucketed_cycle_loop_compiles(one_chip):
                                    budget=_spec(one_chip, ())).compile()
     assert " conditional(" in compiled.as_text()
     _check(compiled, kernel=False)
+
+
+def test_phase2_compiles_at_real_size(one_chip):
+    """Phase 2 at the real single-instance size: the dense drain's
+    cumsum, gathers and sorted segment sum over every arc, under the
+    height sweeps' loops."""
+    from repro.core import phase2
+
+    g = pr.DeviceGraph(indptr=_spec(one_chip, (N_REAL + 1,)),
+                       heads=_spec(one_chip, (A_REAL,)),
+                       tails=_spec(one_chip, (A_REAL,)),
+                       rev=_spec(one_chip, (A_REAL,)))
+    meta = pr.GraphMeta(n=N_REAL, num_arcs=A_REAL, deg_max=32,
+                        layout="bcsr")
+    compiled = phase2.phase2_run.lower(
+        g, meta, _spec(one_chip, (A_REAL,)), _spec(one_chip, (A_REAL,)),
+        _spec(one_chip, (N_REAL,)), _spec(one_chip, ()),
+        _spec(one_chip, ())).compile()
+    _check(compiled, kernel=False)
