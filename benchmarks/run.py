@@ -73,20 +73,6 @@ def main() -> None:
                    f"{row['reduction']:.0f},adj_over_csr")
 
     print()
-    print("=" * 72)
-    print("Roofline — from multi-pod dry-run artifacts (if present)")
-    print("=" * 72)
-    try:
-        from benchmarks import roofline
-        rows = roofline.run()
-        for r in rows:
-            csv.append(f"roofline/{r['arch']}/{r['shape']},"
-                       f"{max(r['t_compute_s'], r['t_memory_s'], r['t_collective_s'])*1e6:.1f},"
-                       f"dom={r['dominant']};frac={r.get('roofline_fraction', 0):.3f}")
-    except Exception as e:  # dry-run artifacts may not exist yet
-        print(f"(roofline skipped: {e})")
-
-    print()
     print("name,us_per_call,derived")
     for line in csv:
         print(line)

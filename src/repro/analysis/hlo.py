@@ -13,12 +13,11 @@ derived from the result shape and the replica-group size::
     all-to-all         out * (g-1)/g
     collective-permute out
 
-Formerly ``repro.launch.hlo_analysis`` (that module now re-exports from
-here).  One behavioural fix over the historical parser: an op line whose
-``replica_groups`` cannot be parsed used to silently assume a group size
-of 2 — *undercounting* wire bytes for any larger group.  It now raises
-:class:`ReplicaGroupParseError` carrying the unmatched line; pass
-``strict=False`` to keep the old floor and get a warning instead.
+An op line whose ``replica_groups`` cannot be parsed raises
+:class:`ReplicaGroupParseError` carrying the unmatched line, since
+assuming a group size of 2 would *undercount* wire bytes for any larger
+group; pass ``strict=False`` to keep that floor and get a warning
+instead.
 """
 from __future__ import annotations
 

@@ -14,8 +14,7 @@ Rule catalogue (scopes are repo-relative directory prefixes):
     ``core/engine.py`` — every bulk-synchronous loop must run on the
     sweep engine so the ScanChunkShape contract stays provable.
     (``fori_loop`` is allowed: it has no carry-pytree surface and the
-    engine deliberately does not wrap it.  The training/models seed
-    scaffolding is out of scope — it is not solver code.)
+    engine deliberately does not wrap it.)
 ``interpret-literal``
     no hardcoded ``interpret=True`` anywhere in ``src/repro`` — backend
     resolution belongs to ``kernels.runtime.resolve_interpret``.

@@ -1,5 +1,4 @@
-"""Fault tolerance runtime: injected-fault plans for the serving tier,
-plus the training-loop checkpoint/restart watchdog.
+"""Fault tolerance runtime: injected-fault plans for the serving tier.
 
 **Serving chaos harness** — :class:`FaultPlan` is a seed-deterministic
 fault injector ``MaxflowService`` accepts (``MaxflowService(cfg,
@@ -18,25 +17,13 @@ Queue floods are a *workload* shape, not a fault: use
 ``repro.serving.workload.synthesize(process="flood")``.  Every injection
 is counted (``stats()``) so chaos tests can assert the planned faults
 actually fired.
-
-**Training loop** — BSP steps are deterministic, so the recovery contract
-is simple: on any step failure (device loss, preemption, injected fault)
--> restore the latest committed checkpoint (params, optimizer,
-data-pipeline state) and replay.  ``run_loop`` is the single-process
-embodiment; on a real cluster the same loop runs under a process-restart
-supervisor and ``restore`` picks up the shared filesystem checkpoint.
-Straggler mitigation: per-step wall times feed an EWMA; steps slower than
-``straggler_factor`` x EWMA are counted and surfaced.
 """
 from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Callable
 
 import numpy as np
-
-from repro.checkpoint import checkpoint as C
 
 
 class InjectedFault(RuntimeError):
@@ -152,69 +139,3 @@ class FaultPlan:
     def stats(self) -> dict:
         """JSON-clean injection counts (what actually fired)."""
         return dict(self.injected)
-
-
-#: training-loop section below ------------------------------------------------
-
-
-@dataclasses.dataclass
-class LoopReport:
-    steps_done: int = 0
-    restarts: int = 0
-    straggler_steps: int = 0
-    last_loss: float = float("nan")
-    step_times: list = dataclasses.field(default_factory=list)
-
-
-def run_loop(*, ckpt_dir: str, total_steps: int, make_state: Callable,
-             step_fn: Callable, pipeline, ckpt_every: int = 20,
-             max_restarts: int = 5, straggler_factor: float = 3.0,
-             fault_hook: Callable | None = None) -> LoopReport:
-    """Run ``total_steps`` of training with checkpoint/restart.
-
-    make_state() -> (params, opt_state) freshly initialised.
-    step_fn(params, opt_state, batch) -> (params, opt_state, metrics).
-    fault_hook(step) may raise to inject failures (tests).
-    """
-    report = LoopReport()
-    restarts = 0
-    while True:
-        try:
-            tree, extra = C.restore(ckpt_dir)
-            if tree is None:
-                params, opt_state = make_state()
-                start = 0
-            else:
-                params, opt_state = tree["params"], tree["opt_state"]
-                pipeline.load_state_dict(extra["pipeline"])
-                start = int(extra["step"])
-            ewma = None
-            for step in range(start, total_steps):
-                t0 = time.time()
-                if fault_hook is not None:
-                    fault_hook(step)
-                batch = pipeline.next()
-                params, opt_state, metrics = step_fn(params, opt_state,
-                                                     batch)
-                dt = time.time() - t0
-                report.step_times.append(dt)
-                ewma = dt if ewma is None else 0.9 * ewma + 0.1 * dt
-                if dt > straggler_factor * ewma:
-                    report.straggler_steps += 1
-                report.steps_done = step + 1
-                report.last_loss = float(metrics["loss"])
-                if (step + 1) % ckpt_every == 0 or step + 1 == total_steps:
-                    C.save(ckpt_dir, step + 1,
-                           {"params": params, "opt_state": opt_state},
-                           extra={"step": step + 1,
-                                  "pipeline": pipeline.state_dict()})
-                    C.prune(ckpt_dir)
-            return report
-        except KeyboardInterrupt:
-            raise
-        except Exception:
-            restarts += 1
-            report.restarts = restarts
-            if restarts > max_restarts:
-                raise
-            # fall through: restore from latest checkpoint and replay

@@ -3,17 +3,8 @@
 Max-flow serving (the WBPR paper's workload): ``MaxflowService`` —
 shape-bucketed microbatching over the batched solver core with result
 caching and warm-started re-solves.  See ``repro.serving.maxflow_service``.
-
-LM serving (scaffolding): prefill/decode steps + cache constructors.  The
-cache machinery (contiguous KV, SWA ring buffers, Mamba/RWKV states,
-cross-attention KV) lives with the model definition in
-``repro.models.transformer``; this package re-exports that surface too.
 """
-from repro.models.transformer import (cache_shape_tree, cache_specs,  # noqa
-                                      cache_zeros)
 from repro.serving.maxflow_service import (MaxflowResult,  # noqa: F401
                                            MaxflowService, ServiceConfig)
 from repro.serving.policy import (BucketModePolicy,  # noqa: F401
                                   candidate_modes)
-from repro.training.train_step import (make_decode_step,  # noqa
-                                       make_prefill_step)

@@ -323,7 +323,7 @@ def test_lint_allows_loop_shell_in_engine_and_out_of_scope(tmp_path):
             return jax.lax.scan(lambda c, _: (c, None), x, None, length=2)
     """
     assert _lint_src(tmp_path, "src/repro/core/engine.py", body) == []
-    assert _lint_src(tmp_path, "src/repro/models/foo.py", body) == []
+    assert _lint_src(tmp_path, "src/repro/graphs/foo.py", body) == []
 
 
 def test_lint_flags_hardcoded_interpret_true(tmp_path):
@@ -413,6 +413,23 @@ def test_repo_tree_is_lint_clean():
 # ---------------------------------------------------------------------------
 # the HLO backend
 # ---------------------------------------------------------------------------
+
+
+def test_hlo_collective_parsing():
+    text = """
+  %all-gather = f32[64,32]{1,0} all-gather(%x), replica_groups=[4,2]<=[8]T(1,0), dimensions={0}
+  %all-reduce.1 = bf16[16,8]{1,0} all-reduce(%y), replica_groups=[2,4]<=[8]T(1,0)
+  %rs = f32[8]{0} reduce-scatter(%z), replica_groups={{0,1,2,3}}
+  %cp = f32[128]{0} collective-permute(%w), source_target_pairs={{0,1}}
+"""  # noqa: E501
+    out = collective_bytes(text)
+    assert out["counts"] == {"all-gather": 1, "all-reduce": 1,
+                             "reduce-scatter": 1, "collective-permute": 1}
+    ag = 64 * 32 * 4 * (2 - 1) / 2
+    ar = 2 * 16 * 8 * 2 * (4 - 1) / 4
+    rs = 8 * 4 * (4 - 1)
+    cp = 128 * 4
+    assert abs(out["total_bytes"] - (ag + ar + rs + cp)) < 1e-6
 
 
 def test_hlo_strict_raises_on_malformed_replica_groups():

@@ -17,7 +17,7 @@ from repro.core import pushrelabel as pr
 from repro.core.csr import Graph, build_residual
 from repro.core.ref_maxflow import dinic_maxflow
 from repro.graphs import generators as G
-from tests.conftest import random_graph
+from tests.conftest import FAMILIES, assert_certified, family_instance, random_graph
 
 
 # -- Solver.solve == legacy solve -------------------------------------------
@@ -82,6 +82,17 @@ def test_solve_many_trivial_and_views(rng):
     # views work on batched solutions too
     cut = sols[1].min_cut()
     assert cut.value == sols[1].value
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_solve_many_certified(family):
+    """One batched dispatch over three seeds of a family: every returned
+    solution carries a certified flow and cut."""
+    instances = [family_instance(family, seed) for seed in (1, 2, 3)]
+    sols = Solver().solve_many([MaxflowProblem(*i) for i in instances])
+    assert all(s.stats.backend == "batched" for s in sols)
+    for sol, (g, s, t) in zip(sols, instances):
+        assert_certified(sol, g, s, t)
 
 
 def test_solve_many_accepts_kernel_modes(rng):

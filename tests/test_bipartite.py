@@ -4,7 +4,7 @@ matching validity, the König cut and phase 2's counters, on
 import numpy as np
 import pytest
 
-from repro.api import MatchingProblem, Solver
+from repro.api import MatchingProblem, Solver, SolverOptions
 from repro.core.csr import Graph
 from repro.core.ref_maxflow import dinic_maxflow, ref_matching
 from repro.graphs.generators import (BipartiteProblem, bipartite_powerlaw,
@@ -73,16 +73,20 @@ def _stranded(sol) -> np.ndarray:
     return e
 
 
+@pytest.mark.parametrize("mode", ["vc", "tc"])
+@pytest.mark.parametrize("layout", ["rcsr", "bcsr"])
 @pytest.mark.parametrize("shape", sorted(POWERLAW) + ["identity"])
-def test_matching_agrees_with_reference(shape):
+def test_matching_agrees_with_reference(shape, layout, mode):
     """Value, pairs, the König cut and the phase-2 counters of a
-    ``MatchingProblem`` solve, against ``ref_matching``."""
+    ``MatchingProblem`` solve, against ``ref_matching``, in every layout
+    and step mode."""
     bp = _identity() if shape == "identity" else \
         bipartite_powerlaw(*POWERLAW[shape][:5], seed=POWERLAW[shape][5])
     if shape == "hub":
         deg = np.bincount(bp.lr_edges[:, 1])
         assert deg.max() > len(bp.lr_edges) // 2
-    sol = Solver().solve(MatchingProblem(bp))
+    sol = Solver(SolverOptions(mode=mode, layout=layout)).solve(
+        MatchingProblem(bp))
     stranded = _stranded(sol)
     assert sol.phase2_stats is None  # no view has needed phase 2 yet
     want = ref_matching(bp.lr_edges, bp.n_left, bp.n_right)

@@ -8,8 +8,8 @@ from repro.api import MaxflowProblem, Solver, SolverOptions
 from repro.core import pushrelabel as pr
 from repro.core.csr import Graph, build_residual
 from repro.core.ref_maxflow import dinic_maxflow
-from repro.graphs import generators as G
-from tests.conftest import random_graph
+from tests.conftest import (FAMILIES, assert_certified, family_instance,
+                            random_graph)
 
 
 def _solve(g, s, t, mode="vc", layout="bcsr"):
@@ -26,22 +26,14 @@ def test_random_graphs_match_oracle(layout, mode, rng):
         assert _solve(g, 0, g.n - 1, mode=mode, layout=layout).value == want
 
 
-@pytest.mark.parametrize("gen,args", [
-    (G.washington_rlg, (6, 8)),
-    (G.genrmf, (3, 4)),
-    (G.grid_road, (8, 8)),
-])
-def test_generator_graphs(gen, args):
-    g, s, t = gen(*args, seed=11)
-    want = dinic_maxflow(g, s, t)
-    for layout in ("rcsr", "bcsr"):
-        assert _solve(g, s, t, layout=layout).value == want
-
-
-def test_powerlaw_multiterminal():
-    g, s, t = G.powerlaw(250, 3, seed=5)
-    want = dinic_maxflow(g, s, t)
-    assert _solve(g, s, t).value == want
+@pytest.mark.parametrize("mode", ["vc", "tc"])
+@pytest.mark.parametrize("layout", ["rcsr", "bcsr"])
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_certified_solution(family, layout, mode):
+    """Value, flow and cut of one solve, each against the graph: the
+    certificate every chip benchmark cell checks."""
+    g, s, t = family_instance(family)
+    assert_certified(_solve(g, s, t, mode=mode, layout=layout), g, s, t)
 
 
 def test_flow_conservation_and_cut(rng):

@@ -1,10 +1,7 @@
-"""Min-cut extraction + elastic checkpoint rescaling."""
-import jax
-from repro import compat
+"""Min-cut extraction."""
 import numpy as np
-import pytest
 
-from repro.core.csr import Graph, build_residual
+from repro.core.csr import build_residual
 from repro.core.mincut import solve_min_cut
 from repro.core.ref_maxflow import dinic_maxflow
 from tests.conftest import random_graph
@@ -44,27 +41,3 @@ def test_mincut_is_actually_minimal(rng):
         reach = new
     assert not reach[r.n - 1]
 
-
-def test_elastic_rescale_roundtrip(tmp_path):
-    from repro.checkpoint import checkpoint as C
-    from repro.configs.registry import get_smoke_config
-    from repro.models import transformer as T
-    from repro.runtime.elastic import rescale_checkpoint
-    from repro.training import optimizer as O
-
-    cfg = get_smoke_config("qwen3-4b")
-    params = T.init_params(cfg, jax.random.PRNGKey(0))
-    opt = O.make_optimizer("adamw")
-    C.save(tmp_path, 7, {"params": params, "opt_state": opt.init(params)},
-           extra={"step": 7, "pipeline": {"step": 7, "seed": 0}})
-    new_mesh = compat.make_mesh((1, 1), ("data", "model"))
-    p2, o2, extra = rescale_checkpoint(tmp_path, cfg, new_mesh)
-    assert extra["step"] == 7
-    a = jax.tree.leaves(params)[0]
-    b = jax.tree.leaves(p2)[0]
-    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    # leaves got placed with the new mesh's shardings
-    assert any(x.sharding.mesh.shape == {"data": 1, "model": 1}
-               for x in jax.tree.leaves(p2)
-               if hasattr(x, "sharding")
-               and hasattr(x.sharding, "mesh"))

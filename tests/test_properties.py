@@ -1,91 +1,6 @@
 """System-invariant property tests (hypothesis)."""
-import dataclasses
-
-import jax
-import jax.numpy as jnp
 import numpy as np
-import pytest
 from hypothesis import given, settings, strategies as st
-
-from repro.configs.registry import get_smoke_config
-from repro.models import layers as L
-
-
-@settings(max_examples=10, deadline=None)
-@given(st.integers(0, 10**6), st.sampled_from([8, 16, 24]),
-       st.sampled_from([None, 4]))
-def test_attention_causality(seed, s, window):
-    """Output at position i must not depend on tokens > i."""
-    rng = np.random.default_rng(seed)
-    q = jnp.asarray(rng.standard_normal((1, s, 2, 8)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((1, s, 2, 8)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((1, s, 2, 8)), jnp.float32)
-    out = L.attn_naive(q, k, v, causal=True, window=window)
-    i = s // 2
-    k2 = k.at[:, i + 1:].set(99.0)
-    v2 = v.at[:, i + 1:].set(-99.0)
-    out2 = L.attn_naive(q, k2, v2, causal=True, window=window)
-    np.testing.assert_allclose(np.asarray(out[:, :i + 1]),
-                               np.asarray(out2[:, :i + 1]),
-                               rtol=1e-5, atol=1e-5)
-
-
-@pytest.mark.slow  # LM scaffolding: CI's -m slow step covers it
-@settings(max_examples=8, deadline=None)
-@given(st.integers(0, 10**6))
-def test_flash_equals_naive_property(seed):
-    rng = np.random.default_rng(seed)
-    s = int(rng.integers(8, 48))
-    h = int(rng.choice([2, 4]))
-    kv = int(rng.choice([1, 2]))
-    q = jnp.asarray(rng.standard_normal((2, s, h, 8)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((2, s, kv, 8)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((2, s, kv, 8)), jnp.float32)
-    a = L.attn_naive(q, k, v, causal=True)
-    b = L.flash_attention(q, k, v, causal=True, chunk=8)
-    np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                               rtol=3e-5, atol=3e-5)
-
-
-@settings(max_examples=6, deadline=None)
-@given(st.integers(0, 10**6))
-def test_moe_combine_weights_convex(seed):
-    """Ample-capacity MoE output is a convex combination of expert outputs:
-    scaling all expert outputs by c scales the MoE output by c."""
-    from repro.models import moe as MOE
-    cfg = dataclasses.replace(get_smoke_config("mixtral-8x7b"),
-                              capacity_factor=16.0)
-    key = jax.random.PRNGKey(seed)
-    specs = MOE.moe_specs(cfg)
-    leaves, tdef = jax.tree.flatten(
-        specs, is_leaf=lambda x: isinstance(x, L.PSpec))
-    keys = jax.random.split(key, len(leaves))
-    p = jax.tree.unflatten(tdef, [
-        L.init_param(k_, ps, jnp.float32) for k_, ps in zip(keys, leaves)])
-    x = jax.random.normal(key, (1, 8, cfg.d_model), jnp.float32)
-    y1, _ = MOE.moe_ffn(cfg, p, x)
-    p2 = dict(p, w_down=p["w_down"] * 2.0)
-    y2, _ = MOE.moe_ffn(cfg, p2, x)
-    np.testing.assert_allclose(np.asarray(y2), 2 * np.asarray(y1),
-                               rtol=1e-4, atol=1e-4)
-
-
-@settings(max_examples=6, deadline=None)
-@given(st.integers(0, 10**6))
-def test_rope_relative_property(seed):
-    """RoPE scores depend only on relative positions: shifting all
-    positions by a constant leaves q.k scores unchanged."""
-    rng = np.random.default_rng(seed)
-    q = jnp.asarray(rng.standard_normal((1, 6, 2, 16)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((1, 6, 2, 16)), jnp.float32)
-    pos = jnp.arange(6)[None]
-    def scores(off):
-        qr = L.rope(q, pos + off, 10000.0)
-        kr = L.rope(k, pos + off, 10000.0)
-        return jnp.einsum("bqhd,bkhd->bhqk", qr, kr)
-    np.testing.assert_allclose(np.asarray(scores(0)),
-                               np.asarray(scores(17)),
-                               rtol=2e-4, atol=2e-4)
 
 
 @settings(max_examples=10, deadline=None)
