@@ -26,9 +26,13 @@ class SolverOptions:
         multi-instance core — also what ``Solver.solve_many`` uses), or
         ``distributed`` (shard_map over all local devices).
     ``global_relabel_cadence``
-        Push-relabel cycles between global relabels (the legacy
+        The most push-relabel cycles between global relabels (the legacy
         ``cycle_chunk``).  ``None`` picks the auto heuristic
-        ``max(32, min(1024, n))``.
+        ``max(32, min(1024, n))``.  On the single backend this is a cap:
+        a round also ends once the sink's excess has not risen for
+        ``max(32, 2 * sweeps)`` cycles, ``sweeps`` being the depth the
+        round's opening global relabel found.  The batched backend runs
+        every round to the cadence.
     ``max_cycles``
         Total push-relabel cycle budget; the solve raises ``RuntimeError``
         if it has not converged within it.  ``None`` means the legacy
@@ -115,13 +119,16 @@ class SolverOptions:
     # -- mapping onto the legacy driver knobs -------------------------------
 
     def cycle_chunk(self, n: int) -> int:
-        """Cycles per device dispatch between global relabels."""
+        """The most cycles per device dispatch between global relabels;
+        a single-backend round ends sooner when the sink's excess
+        stalls (see ``global_relabel_cadence``)."""
         if self.global_relabel_cadence is not None:
             return self.global_relabel_cadence
         return max(32, min(1024, n))
 
     def max_rounds(self, n: int) -> int:
-        """[cycles -> global relabel] rounds implied by ``max_cycles``."""
+        """[cycles -> global relabel] rounds implied by ``max_cycles`` on
+        the batched backend, whose rounds run to the cadence."""
         if self.max_cycles is None:
             return 100000
         return max(1, -(-self.max_cycles // self.cycle_chunk(n)))

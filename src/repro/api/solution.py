@@ -24,6 +24,9 @@ class SolveStats:
 
     cycles: int = 0  # push-relabel iterations spent
     rounds: int = 0  # [cycles -> global relabel] rounds
+    # rounds ended because the sink's excess stopped rising (single
+    # backend; the batched loops keep their fixed cadence)
+    stalled_rounds: int = 0
     global_relabels: int = 0
     backend: str = "single"
     mode: str = "vc"

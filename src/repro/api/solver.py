@@ -60,12 +60,14 @@ class Solver:
 
     def _solve_single(self, problem, r: ResidualCSR) -> Solution:
         opts = self.options
+        # no max_rounds: a round ends early once e[t] stalls, so rounds
+        # may outnumber max_cycles / cadence; the exact max_cycles budget
+        # bounds them (every round runs at least one cycle)
         legacy = pr.solve_impl(
             r, problem.s, problem.t, mode=opts.mode,
             cycle_chunk=opts.global_relabel_cadence,
-            max_rounds=opts.max_rounds(r.n), interpret=opts.interpret,
-            instrument=opts.telemetry, max_cycles=opts.max_cycles,
-            scan_chunk=opts.scan_chunk)
+            interpret=opts.interpret, instrument=opts.telemetry,
+            max_cycles=opts.max_cycles, scan_chunk=opts.scan_chunk)
         handle = WarmStartHandle(
             r, problem.s, problem.t,
             np.asarray(legacy.state.res), np.asarray(legacy.state.e),
@@ -77,6 +79,7 @@ class Solver:
             mode=opts.mode, layout=r.layout,
             pushes=legacy.pushes, relabels=legacy.relabels,
             gr_sweeps=legacy.gr_sweeps, frontier_lanes=legacy.frontier_lanes,
+            stalled_rounds=legacy.stalled_rounds,
             active_history=legacy.active_history if opts.telemetry else None,
             frontier_history=(legacy.frontier_history if opts.telemetry
                               else None),

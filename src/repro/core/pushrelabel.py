@@ -363,7 +363,8 @@ def _make_step(mode: str, interpret: bool | None = None) -> Callable:
 def run_cycles(g: DeviceGraph, meta: GraphMeta, state: PRState, s: int, t: int,
                mode: str = "vc", max_cycles: int = 256,
                interpret: bool | None = None, telemetry: bool = False,
-               budget: jax.Array | None = None, chunk: int | None = None):
+               budget: jax.Array | None = None, chunk: int | None = None,
+               patience: jax.Array | None = None):
     """Paper Alg. 1 step 1: up to ``max_cycles`` push-relabel iterations with
     the AVQ-empty early exit (paper §3.3), run through the shared sweep
     engine (``repro.core.engine``): an outer ``while_loop`` over
@@ -377,6 +378,14 @@ def run_cycles(g: DeviceGraph, meta: GraphMeta, state: PRState, s: int, t: int,
     ``solve_impl`` passes its remaining ``max_cycles`` allowance here so
     the total is honored exactly even when it is not a multiple of the
     per-dispatch chunk.
+
+    ``patience`` (traced, optional) also ends the loop once the sink's
+    excess ``e[t]`` has not risen for ``patience`` consecutive cycles
+    (``e[t]`` never falls: t is never active); the carry then counts the
+    cycles since it last rose.  The results are the same either way: a
+    loop that stops before its cap with active vertices left stalled
+    (``any_active``).  ``patience=None`` traces exactly the loop without
+    the rule.
 
     ``telemetry=True`` (static) folds the workload counters of
     ``repro.obs.solvercounters`` into the loop carry and returns a third
@@ -397,7 +406,7 @@ def run_cycles(g: DeviceGraph, meta: GraphMeta, state: PRState, s: int, t: int,
         if budget is not None:
             cap = jnp.minimum(cap, jnp.asarray(budget, jnp.int32))
 
-        def cond(carry):
+        def live(carry):
             state, cycle = carry[0], carry[1]
             nact = jnp.sum(active_mask(state, meta.n, s, t))
             return (cycle < cap) & (nact > 0)
@@ -435,16 +444,36 @@ def run_cycles(g: DeviceGraph, meta: GraphMeta, state: PRState, s: int, t: int,
                 state, cycle = carry
                 return step(g, meta, state, s, t), cycle + 1
 
-        scan_chunk = engine.normalize_chunk(chunk, max_cycles)
+        carry = (state, jnp.int32(0))
         if telemetry:
-            state, cycles, tel = engine.run_bulk_loop(
-                body,
-                (state, jnp.int32(0), sc.telemetry_init(hist=max_cycles)),
-                cond_fn=cond, chunk=scan_chunk)
-            return state, cycles, tel
-        state, cycles = engine.run_bulk_loop(body, (state, jnp.int32(0)),
-                                             cond_fn=cond, chunk=scan_chunk)
-        return state, cycles
+            carry += (sc.telemetry_init(hist=max_cycles),)
+        cond = live
+        if patience is not None:
+            # the last carry element counts the cycles since e[t] rose
+            patience = jnp.asarray(patience, jnp.int32)
+            cycle_body = body
+
+            def body(carry):
+                new = cycle_body(carry[:-1])
+                rose = new[0].e[t] > carry[0].e[t]
+                return new + (jnp.where(rose, 0, carry[-1] + 1),)
+
+            def cond(carry):
+                return live(carry) & (carry[-1] < patience)
+
+            carry += (jnp.int32(0),)
+
+        scan_chunk = engine.normalize_chunk(chunk, max_cycles)
+        carry = engine.run_bulk_loop(body, carry, cond_fn=cond,
+                                     chunk=scan_chunk)
+        return carry if patience is None else carry[:-1]
+
+
+@functools.partial(jax.jit, static_argnames=("n", "s", "t"))
+def any_active(state: PRState, n: int, s: int, t: int) -> jax.Array:
+    """Whether any vertex is active: a ``run_cycles`` loop that stopped
+    before its cap with active vertices left was ended by ``patience``."""
+    return jnp.any(active_mask(state, n, s, t))
 
 
 def solve_programs(n: int, mode: str = "vc", cycle_chunk: int | None = None,
@@ -462,6 +491,15 @@ def solve_programs(n: int, mode: str = "vc", cycle_chunk: int | None = None,
     return cycles, engine.resolve_minh_fn(mode, interpret)
 
 
+def round_patience(sweeps: int) -> int:
+    """The cycles a round waits for the sink's excess to rise before it
+    ends early: ``max(32, 2 * sweeps)``, ``sweeps`` being the count of
+    the global relabel that started the round.  Excess needs about as
+    many cycles as the graph is deep to first reach t, so the window
+    grows with the depth that relabel found."""
+    return max(32, 2 * sweeps)
+
+
 def _empty_hist() -> np.ndarray:
     return np.zeros(0, np.int64)
 
@@ -473,6 +511,7 @@ class SolveStats:
     cycles: int = 0
     global_relabels: int = 0
     gr_sweeps: int = 0  # Bellman-Ford sweep total across global relabels
+    stalled_rounds: int = 0  # rounds ended by a stalled e[t], not the cap
     # device-counter workload totals (telemetry solves; 0 otherwise) —
     # int32 per dispatch, accumulated here in Python ints
     pushes: int = 0
@@ -512,6 +551,12 @@ def solve_impl(r: ResidualCSR, s: int, t: int, mode: str = "vc",
     ``scan_chunk`` sets the engine's scanned steps-per-chunk
     (``repro.core.engine.DEFAULT_CHUNK`` when ``None``).
 
+    The round cadence (``cycle_chunk``, else ``max(32, min(1024, n))``)
+    is a cap: a round also ends once the sink's excess has not risen for
+    ``round_patience(sweeps)`` cycles (``run_cycles``' ``patience``),
+    and goes to the global relabel that follows every round.  Such
+    rounds are counted in ``SolveStats.stalled_rounds``.
+
     ``instrument=True`` enables the device-side telemetry counters
     (``repro.obs.solvercounters``): the returned stats carry exact
     push/relabel totals and per-cycle active/frontier/maxdeg histories,
@@ -541,11 +586,12 @@ def solve_impl(r: ResidualCSR, s: int, t: int, mode: str = "vc",
     remaining = max_cycles  # None = unbounded; else exact total allowance
     for _ in range(max_rounds):
         budget = None if remaining is None else jnp.int32(remaining)
-        with span("solve.cycles") as sp:
+        patience = round_patience(sweeps)
+        with span("solve.cycles", patience=patience) as sp:
             if instrument:
-                state, cycles, tel = run_cycles(g, meta, state, s, t,
-                                                telemetry=True, budget=budget,
-                                                **cycle_kw)
+                state, cycles, tel = run_cycles(
+                    g, meta, state, s, t, telemetry=True, budget=budget,
+                    patience=jnp.int32(patience), **cycle_kw)
                 c = int(cycles)
                 stats.pushes += int(tel.pushes)
                 stats.relabels += int(tel.relabels)
@@ -554,12 +600,19 @@ def solve_impl(r: ResidualCSR, s: int, t: int, mode: str = "vc",
                               np.asarray(tel.frontier_hist[:c], np.int64),
                               np.asarray(tel.maxdeg_hist[:c], np.int64)))
             else:
-                state, cycles = run_cycles(g, meta, state, s, t,
-                                           budget=budget, **cycle_kw)
+                state, cycles = run_cycles(
+                    g, meta, state, s, t, budget=budget,
+                    patience=jnp.int32(patience), **cycle_kw)
                 c = int(cycles)
-            sp.set_metadata(cycles=c)
+            # the loop stops at its cap, on an empty queue or on a stall
+            cap = cycle_kw["max_cycles"]
+            if remaining is not None:
+                cap = min(cap, remaining)
+            stalled = int(c < cap and bool(any_active(state, n, s, t)))
+            sp.set_metadata(cycles=c, stalled=stalled)
         stats.cycles += c
         stats.rounds += 1
+        stats.stalled_rounds += stalled
         if remaining is not None:
             remaining -= c
         with span("solve.global_relabel") as sp:

@@ -205,8 +205,9 @@ def solve_hlo(problem, options=None) -> dict[str, str]:
     ``Solver(options).solve(problem)`` and its ``flows()`` run, keyed by
     jit name: ``jit_run_cycles``, ``jit_global_relabel_impl`` and
     ``jit_phase2_impl``.  Their static arguments come from
-    ``pushrelabel.solve_programs``, as the solve's own do.  Compiles each
-    (a persistent-cache hit where the cache holds it)."""
+    ``pushrelabel.solve_programs``, as the solve's own do, and
+    ``run_cycles`` takes a traced ``patience`` as every round does.
+    Compiles each (a persistent-cache hit where the cache holds it)."""
     import jax.numpy as jnp
 
     from repro.api import SolverOptions
@@ -229,7 +230,7 @@ def solve_hlo(problem, options=None) -> dict[str, str]:
     lowered = {
         "jit_run_cycles": pr.run_cycles.lower(
             g, meta, state, s, t, telemetry=opts.telemetry, budget=budget,
-            **cycle_kw),
+            patience=jnp.int32(pr.round_patience(0)), **cycle_kw),
         "jit_global_relabel_impl": globalrelabel.global_relabel.lower(
             g, meta, state, s, t, minh_fn=minh_fn),
         "jit_phase2_impl": phase2.phase2_run.lower(

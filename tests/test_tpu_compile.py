@@ -95,21 +95,35 @@ def test_batched_cycle_loop_compiles_at_serving_bucket(one_chip, mode):
     _check(compiled, kernel=mode != "vc")
 
 
+def _bucketed_loop(sharding, **kw):
+    """``run_cycles(mode="vc")`` compiled for the chip at a size whose
+    frontier ladder has several rungs."""
+    n, a = 2_050, 11_776
+    assert len(pr.frontier_ladder(n, a)) > 4
+    g = pr.DeviceGraph(indptr=_spec(sharding, (n + 1,)),
+                       heads=_spec(sharding, (a,)),
+                       tails=_spec(sharding, (a,)),
+                       rev=_spec(sharding, (a,)))
+    state = pr.PRState(res=_spec(sharding, (a,)), h=_spec(sharding, (n,)),
+                       e=_spec(sharding, (n,)))
+    meta = pr.GraphMeta(n=n, num_arcs=a, deg_max=32, layout="bcsr")
+    return pr.run_cycles.lower(g, meta, state, n - 2, n - 1, mode="vc",
+                               max_cycles=1024, budget=_spec(sharding, ()),
+                               **kw).compile()
+
+
 def test_bucketed_cycle_loop_compiles(one_chip):
     """The unbatched ``vc`` loop, its step switched over the frontier
     ladder's rungs (a ``conditional`` of several branches)."""
-    n, a = 2_050, 11_776
-    assert len(pr.frontier_ladder(n, a)) > 4
-    g = pr.DeviceGraph(indptr=_spec(one_chip, (n + 1,)),
-                       heads=_spec(one_chip, (a,)),
-                       tails=_spec(one_chip, (a,)),
-                       rev=_spec(one_chip, (a,)))
-    state = pr.PRState(res=_spec(one_chip, (a,)), h=_spec(one_chip, (n,)),
-                       e=_spec(one_chip, (n,)))
-    meta = pr.GraphMeta(n=n, num_arcs=a, deg_max=32, layout="bcsr")
-    compiled = pr.run_cycles.lower(g, meta, state, n - 2, n - 1, mode="vc",
-                                   max_cycles=1024,
-                                   budget=_spec(one_chip, ())).compile()
+    compiled = _bucketed_loop(one_chip)
+    assert " conditional(" in compiled.as_text()
+    _check(compiled, kernel=False)
+
+
+def test_bucketed_cycle_loop_with_patience_compiles(one_chip):
+    """The loop as a solve's round dispatches it: the stall counter in
+    the carry and a traced ``patience`` in the condition."""
+    compiled = _bucketed_loop(one_chip, patience=_spec(one_chip, ()))
     assert " conditional(" in compiled.as_text()
     _check(compiled, kernel=False)
 
